@@ -32,7 +32,7 @@ This module removes both:
 Usage::
 
     import apex_tpu.cache
-    apex_tpu.cache.enable("~/.cache/apex_tpu_xla")   # once, at startup
+    apex_tpu.cache.enable()      # once, at startup; see resolve_dir
 
     pipe = runtime.StepPipeline(step_fn, k, ...)
     pipe.warmup(state, window)          # AOT: compile before step 0
@@ -47,61 +47,64 @@ from typing import Any, Optional, Tuple
 
 import jax
 
-__all__ = ["enable", "is_enabled", "cache_dir", "abstractify",
-           "signature", "warmup"]
+__all__ = ["enable", "resolve_dir", "is_enabled", "cache_dir",
+           "abstractify", "signature", "warmup"]
 
 _STATE = {"dir": None}
 
+#: set from outside to place the cache (jax reads it into
+#: ``jax_compilation_cache_dir`` at import); always wins over code
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+#: where the cache goes otherwise: a fixed path inside the checkout (the
+#: directory is part of the cache key, so a path made from a temp dir,
+#: a uid, a pid or the time would never hit)
+_DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
-def enable(path: str, *,
+
+def resolve_dir(path: Optional[str] = None) -> str:
+    """THE decision of where the persistent compilation cache lives:
+    ``$JAX_COMPILATION_CACHE_DIR`` when set, else an explicit ``path``,
+    else ``<checkout>/.jax_cache``.  Everything that wants a cache
+    (``bench.py``, ``chip_smoke.py``, the examples) goes through
+    :func:`enable`, which asks here."""
+    env = os.environ.get(ENV_VAR)
+    return os.path.abspath(os.path.expanduser(
+        env or path or _DEFAULT_DIR))
+
+
+def enable(path: Optional[str] = None, *,
            min_entry_size_bytes: int = -1,
            min_compile_time_secs: float = 0.0) -> str:
-    """Enable jax's persistent compilation cache at ``path``.
+    """Enable jax's persistent compilation cache at
+    :func:`resolve_dir(path) <resolve_dir>`.
 
     Creates the directory, points ``jax_compilation_cache_dir`` at it
-    and drops the size/compile-time floors (both default to "cache
-    everything": a train-step executable is always worth keeping; the
-    defaults exist to keep tiny one-off programs out of shared caches).
-    Falls back to the legacy ``initialize_cache`` API on old jax.
-    Idempotent; returns the resolved directory.
+    (a no-op when the environment variable already did) and drops the
+    size/compile-time floors (both default to "cache everything": a
+    train-step executable is always worth keeping; the defaults exist
+    to keep tiny one-off programs out of shared caches).  Idempotent;
+    returns the resolved directory.
     """
-    path = os.path.abspath(os.path.expanduser(path))
+    path = resolve_dir(path)
     os.makedirs(path, exist_ok=True)
-    try:
+    if jax.config.jax_compilation_cache_dir != path:
         jax.config.update("jax_compilation_cache_dir", path)
-        if _STATE["dir"] not in (None, path):
-            # The backend binds its store on first use; re-pointing the
-            # config alone would silently keep writing to the old dir.
-            try:
-                from jax._src import compilation_cache as _cci
-                _cci.reset_cache()
-            except Exception:                    # pragma: no cover
-                pass
-    except AttributeError:                       # pragma: no cover
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.initialize_cache(path)
-    # Cache-everything floors; individually best-effort (older jaxlibs
-    # lack one or both knobs, and the defaults there already cache
-    # training-sized programs).
-    for name, val in (
-            ("jax_persistent_cache_min_entry_size_bytes",
-             min_entry_size_bytes),
-            ("jax_persistent_cache_min_compile_time_secs",
-             min_compile_time_secs)):
-        try:
-            jax.config.update(name, val)
-        except (AttributeError, ValueError):     # pragma: no cover
-            pass
+        # The backend binds its store on first use; re-pointing the
+        # config alone would silently keep writing to the old dir.
+        from jax._src import compilation_cache as _cci
+        _cci.reset_cache()
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                      min_entry_size_bytes)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      min_compile_time_secs)
     # The kernel autotuner's per-device config cache (ISSUE 14) lives
     # beside the compiled-executable store: one cache directory holds
     # both halves of warm start — programs AND the block configs the
     # programs were built with.
-    try:
-        from .tune import store as _tune_store
-        _tune_store.set_default_dir(path)
-    except Exception:                            # pragma: no cover
-        pass
+    from .tune import store as _tune_store
+    _tune_store.set_default_dir(path)
     _STATE["dir"] = path
     return path
 
@@ -134,11 +137,8 @@ def abstractify(tree):
             # exactly what the real call gets.
             sharding = getattr(leaf, "sharding", None)
             if sharding is not None and getattr(leaf, "committed", False):
-                try:
-                    return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
-                                                sharding=sharding)
-                except TypeError:                # pragma: no cover
-                    pass                         # old jax: no kwarg
+                return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                            sharding=sharding)
             return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
         return leaf
     return jax.tree_util.tree_map(one, tree)

@@ -122,8 +122,7 @@ class GPT(nn.Module):
         # Checked at trace time — JAX gather clamps out-of-range indices,
         # so an oversized (global) sequence would silently reuse the last
         # position embedding instead of erroring.
-        from ..parallel.distributed import _axis_size
-        sp = 1 if self.sp_axis is None else _axis_size(self.sp_axis)
+        sp = 1 if self.sp_axis is None else jax.lax.axis_size(self.sp_axis)
         if not self.decode and sp * t > self.max_len:
             raise ValueError(
                 f"global sequence {sp} shard(s) x {t} tokens = {sp * t} "

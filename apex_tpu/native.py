@@ -12,6 +12,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 from typing import List, Optional, Sequence
 
@@ -33,7 +34,16 @@ def _build() -> Optional[str]:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         return _SO
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        # The numpy tier is a supported install, but dropping to it must
+        # not be silent: say once (``_load`` runs the build once per
+        # process) what the compiler said.
+        detail = getattr(e, "stderr", None)
+        if isinstance(detail, bytes):
+            detail = detail.decode(errors="replace")
+        print(f"apex_tpu.native: build of {src} failed "
+              f"({type(e).__name__}: {e}); using the numpy tier\n"
+              f"{(detail or '').strip()}", file=sys.stderr)
         return None
 
 

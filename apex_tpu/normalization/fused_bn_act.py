@@ -44,6 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..pallas_compat import align_vma as _align_vma
+from ..pallas_compat import match_vma as _match_vma
 from ..pallas_compat import sds_with_vma as _sds
 from ..tune import space as _space
 from ..tune.dispatch import kernel_config as _tuned_config
@@ -308,7 +309,11 @@ def _epilogue_bwd(relu, use_pallas, interpret, row_block, res, g):
             jnp.asarray(scale).dtype)
         d_bias = jnp.reshape(d_bias, jnp.shape(bias)).astype(
             jnp.asarray(bias).dtype)
-    return dx, d_mean, d_invstd, d_scale, d_bias, dz
+    # Per-channel operands are usually replicated over a data axis the
+    # activations are sharded on: their column sums are per-shard here
+    # and must arrive summed (see pallas_compat.match_vma).
+    return tuple(_match_vma(ct, p) for ct, p in zip(
+        (dx, d_mean, d_invstd, d_scale, d_bias, dz), res))
 
 
 _epilogue.defvjp(_epilogue_fwd, _epilogue_bwd)

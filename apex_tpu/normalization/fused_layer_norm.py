@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..pallas_compat import align_vma as _align_vma
+from ..pallas_compat import match_vma as _match_vma
 from ..pallas_compat import sds_with_vma as _sds
 from ..tune import space as _space
 from ..tune.dispatch import kernel_config as _tuned_config
@@ -245,6 +247,8 @@ def _pallas_fwd(x2d, weight, bias, eps, interpret=False, row_block=None):
     b = bias if has_bias else jnp.zeros((n2,), x2d.dtype)
     kernel = functools.partial(_fwd_kernel, eps=eps, affine=affine,
                                has_bias=has_bias)
+    # replicated weight/bias next to sharded rows under shard_map
+    operands = _align_vma(x2d, w, b)
     out, mean, invvar = pl.pallas_call(
         kernel,
         grid=grid,
@@ -259,12 +263,12 @@ def _pallas_fwd(x2d, weight, bias, eps, interpret=False, row_block=None):
             pl.BlockSpec((rows, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            _sds((n1, n2), x2d.dtype, x2d),
-            _sds((n1, 1), jnp.float32, x2d),
-            _sds((n1, 1), jnp.float32, x2d),
+            _sds((n1, n2), x2d.dtype, *operands),
+            _sds((n1, 1), jnp.float32, *operands),
+            _sds((n1, 1), jnp.float32, *operands),
         ],
         interpret=interpret,
-    )(x2d, w, b)
+    )(*operands)
     return out, mean[:, 0], invvar[:, 0]
 
 
@@ -277,6 +281,7 @@ def _pallas_bwd_input(g2d, x2d, mean, invvar, weight, interpret=False,
     affine = weight is not None
     w = weight if affine else jnp.zeros((n2,), x2d.dtype)
     kernel = functools.partial(_bwd_kernel, affine=affine)
+    operands = _align_vma(g2d, x2d, mean[:, None], invvar[:, None], w)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -288,9 +293,9 @@ def _pallas_bwd_input(g2d, x2d, mean, invvar, weight, interpret=False,
             pl.BlockSpec((n2,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((rows, n2), lambda i: (i, 0)),
-        out_shape=_sds((n1, n2), x2d.dtype, x2d, g2d),
+        out_shape=_sds((n1, n2), x2d.dtype, *operands),
         interpret=interpret,
-    )(g2d, x2d, mean[:, None], invvar[:, None], w)
+    )(*operands)
 
 
 # -- public functional API with custom VJP ------------------------------------
@@ -331,7 +336,11 @@ def _layer_norm_bwd(eps, use_pallas, interpret, row_block, res, g):
         db = jnp.sum(g.astype(jnp.float32), axis=0).astype(bias.dtype)
     else:
         db = None
-    return dx, dw, db
+    # weight/bias are usually replicated over a data axis the rows are
+    # sharded on: their column sums are per-shard here and must arrive
+    # summed (see pallas_compat.match_vma).
+    return (_match_vma(dx, x2d), _match_vma(dw, weight),
+            _match_vma(db, bias))
 
 
 _layer_norm.defvjp(_layer_norm_fwd, _layer_norm_bwd)

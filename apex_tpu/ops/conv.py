@@ -47,7 +47,8 @@ Contract (the repo kernel contract, ISSUE 7/14):
   with the hard-coded defaults as the zero-cost fallback.  Block
   partitioning never reorders a single output element's tap/K reduction,
   so tuned configs match the default BITWISE (``exact=True``).
-* Shapes the kernel cannot serve — grouped/depthwise convs, blocks that
+* Shapes the kernel cannot serve — grouped/depthwise convs, strided
+  convs on the compiled path (:func:`_mosaic_accepts`), blocks that
   cannot fit scoped VMEM (e.g. the C=3 stem conv, whose lane-padded
   image block alone overflows), sub-crossover sizes — fall back to XLA
   per call site; :class:`PallasConv` counts them in
@@ -65,6 +66,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..pallas_compat import align_vma as _align_vma
+from ..pallas_compat import match_vma as _match_vma
+from ..pallas_compat import mxu_dot as _mxu_dot
 from ..pallas_compat import sds_with_vma as _sds
 from ..tune import space as _space
 from ..tune.dispatch import kernel_config as _tuned_config
@@ -278,10 +281,8 @@ def _fwd_kernel(x_ref, w_ref, mean_ref, invstd_ref, s_ref, b_ref, z_ref,
         for ikw in range(kw):        # strided slices + MXU matmuls
             xs = x_ref[0, pl.ds(row0 + ikh * dh, span), :, :]
             xs = xs[::sh, ikw * dw: ikw * dw + (ow - 1) * sw + 1: sw, :]
-            acc = acc + jax.lax.dot_general(
-                xs.reshape(boh * ow, c), w_ref[ikh, ikw],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            acc = acc + _mxu_dot(xs.reshape(boh * ow, c),
+                                 w_ref[ikh, ikw], ((1,), (0,)))
     res = acc.astype(out_ref.dtype)
     if want_preact:
         out_refs[1][0] = res.reshape(boh, ow, bo)
@@ -409,9 +410,7 @@ def _wgrad_kernel(x_ref, g_ref, dw_ref, *, kh, kw, sh, sw, dh, dw, oh, ow):
         for ikw in range(kw):
             xs = xv[ikh * dh: ikh * dh + (oh - 1) * sh + 1: sh,
                     ikw * dw: ikw * dw + (ow - 1) * sw + 1: sw, :]
-            t = jax.lax.dot_general(
-                xs.reshape(oh * ow, c), g2, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            t = _mxu_dot(xs.reshape(oh * ow, c), g2, ((0,), (0,)))
             dw_ref[ikh * kw + ikw] = dw_ref[ikh * kw + ikw] + t
 
 
@@ -510,13 +509,31 @@ def _conv_bwd(groups, relu, stride, padding, dilation, use_pallas,
                         blocks, interpret) if pallas_dx else jdx)
     dw = (_pallas_wgrad(x, dy, stride, padding, dilation, w.shape,
                         blocks, interpret, w.dtype) if pallas_dw else jdw)
-    return dx.astype(x.dtype), dw, d_mean, d_invstd, d_scale, d_bias, dz
+    # The weight and the per-channel epilogue operands are usually
+    # replicated over a data axis the activations are sharded on: the
+    # kernels' dw and the column sums are per-shard here and must
+    # arrive summed (see pallas_compat.match_vma; jax.vjp of the XLA
+    # conv already returns its dw summed, for which this is a no-op).
+    return tuple(_match_vma(ct, p) for ct, p in zip(
+        (dx.astype(x.dtype), dw, d_mean, d_invstd, d_scale, d_bias, dz),
+        res))
 
 
 _conv.defvjp(_conv_fwd, _conv_bwd)
 
 
 # -- dispatch + public op -----------------------------------------------------
+
+def _mosaic_accepts(stride) -> bool:
+    """What the Mosaic compiler takes of this kernel family (measured on
+    the v5e, jax 0.9.0 / libtpu 0.0.34, PR 21): every stride-1 ResNet-50
+    site compiles and matches the reference; a stride-2 tap is a strided
+    *value* slice (``xs[::sh, a:b:sw, :]``), which the Pallas TPU
+    lowering turns into a gather and refuses — "NotImplementedError:
+    Only 2D gather is supported".  Strided sites therefore take the XLA
+    path on the chip (the interpreter still runs them, for the tests)."""
+    return tuple(stride) == (1, 1)
+
 
 def _dispatch_pallas(impl: Optional[str], n_out: int, fits: bool) -> bool:
     if impl not in (None, "pallas", "jnp"):
@@ -598,7 +615,7 @@ def conv2d(x, w, *, stride=(1, 1), padding="SAME", dilation=(1, 1),
             z = z.astype(dt)
     isz = jnp.dtype(dt).itemsize
     capable = groups == 1
-    fits = capable and _fwd_fits(
+    fits = capable and _mosaic_accepts(stride) and _fwd_fits(
         h, w_in, padding, cin, o, kh, kw, *stride, *dilation,
         block_m or _DEFAULT_BLOCK_M, block_n or _DEFAULT_BLOCK_N, isz,
         z is not None, epilogue)
@@ -628,9 +645,12 @@ _FALLBACK_REASONS: Dict[str, int] = {}
 
 def conv_dispatch_stats() -> Dict[str, Any]:
     """Trace-time :class:`PallasConv` dispatch counters: how many conv
-    call sites routed to the Pallas kernel vs fell back to XLA, and why
-    (``groups`` / ``rank`` / ``vmem`` / ``small``).  Counts accumulate
-    per trace (init, apply, and grad traces each count their sites)."""
+    call sites traced the Pallas kernel vs fell back to XLA, and why
+    (``groups`` / ``rank`` / ``backend`` / ``stride`` / ``vmem`` /
+    ``small``).  A site counts as pallas only when the kernel IS what
+    was traced — off the TPU every site is a ``backend`` fallback.
+    Counts accumulate per trace (init, apply, and grad traces each count
+    their sites)."""
     return {"pallas_sites": _DISPATCH_COUNTS["pallas"],
             "fallback_sites": _DISPATCH_COUNTS["fallback"],
             "fallback_reasons": dict(_FALLBACK_REASONS)}
@@ -670,12 +690,17 @@ def publish_conv_counters(registry) -> Dict[str, int]:
 
 def _site_reason(x_shape, w_shape, padding, stride, dilation,
                  groups: int, isz: int) -> Optional[str]:
-    """Why this call site cannot use the kernel on ANY backend (None =
-    pallas-capable; the TPU-vs-CPU gate stays inside :func:`conv2d`)."""
+    """Why this call site does not trace the kernel (None = it does):
+    the same gates, in the same order, as :func:`conv2d`'s automatic
+    dispatch."""
     if len(x_shape) != 4:
         return "rank"
     if groups != 1:
         return "groups"
+    if not _use_pallas():
+        return "backend"
+    if not _mosaic_accepts(stride):
+        return "stride"
     n, h, w_in, cin = x_shape
     kh, kw, _, o = w_shape
     oh, ow = _out_hw(h, w_in, padding, kh, kw, *stride, *dilation)
@@ -696,8 +721,9 @@ class PallasConv(nn.Module):
     the ResNet ``conv_cls=`` hook changes no checkpoint or init — with
     the flag off (``conv_cls=None`` → ``nn.Conv``) the model is
     bit-identical to before.  Call sites the kernel cannot serve
-    (grouped/depthwise, VMEM-overflow like the C=3 stem, sub-crossover
-    sizes) fall back to the XLA conv per site and are counted in
+    (grouped/depthwise, strided, VMEM-overflow like the C=3 stem,
+    sub-crossover sizes, any backend but the TPU) run exactly the XLA
+    conv ``nn.Conv`` runs, in the operands' dtype, and are counted in
     :func:`conv_dispatch_stats`.  ``precision`` is accepted for
     signature parity but ignored (the kernel always accumulates fp32).
     """
@@ -745,8 +771,10 @@ class PallasConv(nn.Module):
             _DISPATCH_COUNTS["fallback"] += 1
             _FALLBACK_REASONS[reason] = _FALLBACK_REASONS.get(reason,
                                                               0) + 1
-            y = _raw_conv(x, kernel, stride, padding, dilation, groups,
-                          jnp.result_type(x, kernel))
+            y = jax.lax.conv_general_dilated(
+                x, kernel, window_strides=stride, padding=padding,
+                rhs_dilation=dilation, dimension_numbers=_DN_NHWC,
+                feature_group_count=groups)
         if bias is not None:
             y = y + jnp.reshape(bias, (1, 1, 1, -1))
         return y

@@ -14,8 +14,7 @@ import flax.linen as nn
 import jax
 
 from .distributed import (DistributedDataParallel, Reducer,  # noqa: F401
-                          reduce_gradients, broadcast_params,
-                          import_shard_map)
+                          reduce_gradients, broadcast_params)
 from .sync_batchnorm import (SyncBatchNorm, welford_parallel,  # noqa: F401
                              adopt_batchnorm_stats)
 from .LARC import LARC, larc_transform, larc_gradients       # noqa: F401

@@ -56,46 +56,12 @@ def _note_collective(op: str, axis_names, tree_bytes: int, n: int,
             names = (axis_names if isinstance(axis_names, (tuple, list))
                      else (axis_names,))
             for a in names:
-                participants *= int(_axis_size(a))
+                participants *= lax.axis_size(a)
         except Exception:
             participants = None
         rec.note_collective(op, axis_names, tree_bytes, n,
                             dtype=str(dtype) if dtype is not None else None,
                             participants=participants)
-
-
-def _axis_size(axis_name) -> int:
-    """``lax.axis_size`` with a fallback for jaxlibs that predate it:
-    ``psum(1, axis)`` of a Python int constant-folds to the axis size at
-    trace time (no collective is emitted)."""
-    try:
-        return lax.axis_size(axis_name)
-    except AttributeError:
-        return lax.psum(1, axis_name)
-
-
-def import_shard_map():
-    """Version-portable ``shard_map``: the top-level export on jax >= 0.6,
-    else a compat wrapper over the experimental home that accepts (and
-    drops) the new ``check_vma`` kwarg and pins ``check_rep=False`` —
-    the legacy rep checker mis-infers scan-carry replication under
-    K-step device loops; the vma tracking that replaced it copes."""
-    try:                                # jax >= 0.6
-        from jax import shard_map
-        return shard_map
-    except ImportError:                 # older jax: experimental home
-        import functools
-
-        from jax.experimental.shard_map import shard_map as _legacy
-
-        def _compat(f=None, **kw):
-            kw.pop("check_vma", None)
-            kw["check_rep"] = False
-            if f is None:               # decorator form: shard_map(mesh=...)
-                return functools.partial(_compat, **kw)
-            return _legacy(f, **kw)
-
-        return _compat
 
 
 def _is_float(x):
@@ -168,7 +134,7 @@ def _group_psum_butterfly(x, axis_name: str, groups, k: int):
 
 
 def _group_psum_gather_mask(x, axis_name: str, groups):
-    world = _axis_size(axis_name)
+    world = lax.axis_size(axis_name)
     import numpy as _np
     from ..amp._amp_state import maybe_print
     # O(world x |tensor|) on the wire — fine for a handful of hosts,
@@ -227,7 +193,7 @@ def reduce_gradients(grads,
         raise ValueError("axis_index_groups requires a single axis name")
     full_world = 1
     for a in axis_names:
-        full_world *= _axis_size(a)
+        full_world *= lax.axis_size(a)
     explicit_world = world_size is not None
     if world_size is None:
         world_size = full_world
@@ -236,30 +202,15 @@ def reduce_gradients(grads,
 
     _vma_tracking = vma_tracking_live(axis_names[0])
 
-    def _already_reduced(g) -> bool:
-        """shard_map autodiff inserts the psum itself when differentiating
-        w.r.t. replicated params (the transpose of the implicit broadcast),
-        so such grads arrive already *summed* over the axis.  They carry an
-        empty varying-manual-axes (vma) set; axis-varying grads (per-shard
-        values, e.g. under pmap-style code) still need the collective."""
-        if not _vma_tracking:
-            return False
-        try:
-            vma = jax.typeof(g).vma
-        except AttributeError:
-            return False
-        return not any(a in vma for a in axis_names)
-
     def _axes_still_varying(g):
-        """Mesh axes this grad still varies over (needs explicit psum);
-        axes absent from the vma set were already summed by shard_map's
-        implicit-broadcast transpose."""
+        """Mesh axes this grad still varies over (needs explicit psum).
+        Axes absent from the vma set were already *summed*: shard_map
+        autodiff inserts the psum itself when differentiating w.r.t.
+        replicated params (the transpose of the implicit broadcast), and
+        the custom-VJP ops do the same (``pallas_compat.match_vma``)."""
         if not _vma_tracking:
             return axis_names
-        try:
-            vma = jax.typeof(g).vma
-        except AttributeError:
-            return axis_names
+        vma = jax.typeof(g).vma
         return tuple(a for a in axis_names if a in vma)
 
     # Telemetry collector: per-leaf (or per-bucket) psum bytes summed at

@@ -15,7 +15,7 @@ standard switch behavior) and reported via the aux outputs.  The router
 gate is applied on the combine side so gradients flow into the router.
 
 Call inside ``shard_map``; one expert per ``ep`` rank (``n_experts ==
-_axis_size(axis_name)``).
+lax.axis_size(axis_name)``).
 """
 
 from __future__ import annotations
@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .distributed import _axis_size
 
 
 class MoEAux(NamedTuple):
@@ -62,7 +61,7 @@ def moe_layer(x, router_w, expert_fn: Callable, expert_params, *,
 
     Returns ``(y [T, d], MoEAux)``.
     """
-    n_experts = _axis_size(axis_name)
+    n_experts = lax.axis_size(axis_name)
     if router_w.shape[-1] != n_experts:
         raise ValueError(
             f"router_w has {router_w.shape[-1]} expert columns but the "

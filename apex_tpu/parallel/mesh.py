@@ -84,7 +84,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..multi_tensor.buckets import (BucketStore, Packed, cached_store,
                                     padded_shard_len)
-from .distributed import _note_collective, import_shard_map
+from .distributed import _note_collective
 from .zero import Zero1State, _shard_one
 
 __all__ = ["MeshPlan", "MeshTrainStep", "make_mesh_train_step",
@@ -246,9 +246,9 @@ class MeshPlan:
             lambda x: jax.device_put(x, sh), window)
 
     def shard_map(self, fn, in_specs, out_specs):
-        """``shard_map`` over this plan's mesh (version-portable)."""
-        return import_shard_map()(fn, mesh=self.mesh, in_specs=in_specs,
-                                  out_specs=out_specs)
+        """``shard_map`` over this plan's mesh."""
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs)
 
     # -- ledger --------------------------------------------------------------
     def state_bytes(self, tree) -> dict:

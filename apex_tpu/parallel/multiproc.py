@@ -18,8 +18,14 @@ TPU analog spawns one worker per host).  Two layers live here
   :class:`~apex_tpu.parallel.mesh.MeshPlan` built from it is the
   per-process view of one global mesh.
 * :func:`main` — the local spawner (``python -m
-  apex_tpu.parallel.multiproc --nproc N train.py ...``): one worker per
-  host entry with the env above set, rank>0 stdout to ``TPU_<i>.log``.
+  apex_tpu.parallel.multiproc --nproc N train.py ...``): N workers on
+  THIS machine with the env above set, rank>0 stdout to
+  ``TPU_<i>.log``.  It emulates N hosts on the CPU backend and refuses
+  ``N > 1`` anywhere else: a chip belongs to one process at a time and
+  nothing here binds a worker to its own chips, so on one TPU host the
+  supported shape is ONE process driving all local chips (run the
+  script directly); on a pod, the scheduler starts one process per host
+  with the env above.
 
 :func:`process_identity` / :func:`is_coordinator` are the single
 source of process identity for the rest of the stack —
@@ -185,6 +191,16 @@ def main(argv=None):
                         default=int(os.environ.get("WORLD_SIZE", "1")))
     parser.add_argument("--coordinator", type=str, default="127.0.0.1:12355")
     args, rest = parser.parse_known_args(argv)
+    if args.nproc > 1 \
+            and os.environ.get("JAX_PLATFORMS", "").lower() != "cpu":
+        parser.error(
+            f"--nproc {args.nproc} starts {args.nproc} processes on this "
+            f"machine and nothing binds each to its own chips: on a TPU "
+            f"host every one of them would ask for all the chips, and a "
+            f"chip belongs to one process.  One process drives all local "
+            f"chips — run the script directly.  Local multi-process runs "
+            f"emulate hosts on the CPU backend only: set "
+            f"JAX_PLATFORMS=cpu.")
 
     workers = []
     for rank in range(args.nproc):

@@ -42,11 +42,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .distributed import _axis_size
 
 
 def _rotate(x, axis_name: str):
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 
@@ -69,7 +68,7 @@ def spmd_pipeline(stage_fn: Callable, stage_params, x, *,
 
     Returns ``[batch, ...]`` outputs, replicated over the pp axis.
     """
-    n_stages = _axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     params_i = jax.tree_util.tree_map(
         lambda p: jnp.squeeze(p, axis=0) if p.shape[0] == 1 else p,
@@ -87,13 +86,7 @@ def spmd_pipeline(stage_fn: Callable, stage_params, x, *,
     # initializers as axis-varying so the carry type is stable under
     # shard_map's vma checking.
     def _pvary(v):
-        try:
-            return lax.pcast(v, (axis_name,), to="varying")
-        except (AttributeError, TypeError):  # older jax spelling
-            try:
-                return lax.pvary(v, (axis_name,))
-            except AttributeError:   # pre-vma jax: nothing to mark
-                return v
+        return lax.pcast(v, (axis_name,), to="varying")
     buf0 = _pvary(jnp.zeros_like(micro[0]))
     out0 = _pvary(jnp.zeros_like(micro))
 
@@ -163,7 +156,7 @@ def spmd_pipeline_interleaved(stage_fn: Callable, stage_params, x, *,
     rank executes exactly one microbatch-chunk per tick — no collisions,
     ``m*v + p - 1`` ticks total, activations rotating one hop per tick.
     """
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     r = lax.axis_index(axis_name)
     leaves = jax.tree_util.tree_leaves(stage_params)
     v = int(leaves[0].shape[0])
@@ -203,13 +196,7 @@ def spmd_pipeline_interleaved(stage_fn: Callable, stage_params, x, *,
     ticks = m * v + p - 1
 
     def _pvary(val):
-        try:
-            return lax.pcast(val, (axis_name,), to="varying")
-        except (AttributeError, TypeError):
-            try:
-                return lax.pvary(val, (axis_name,))
-            except AttributeError:   # pre-vma jax: nothing to mark
-                return val
+        return lax.pcast(val, (axis_name,), to="varying")
 
     buf0 = _pvary(jnp.zeros_like(micro[0]))
     out0 = _pvary(jnp.zeros_like(micro))

@@ -34,7 +34,6 @@ from jax import lax
 
 from ..ops.attention import (attention_block_update, _init_carry,
                              finalize_attention, blockwise_attention)
-from .distributed import _axis_size
 
 
 def ring_attention(q, k, v, axis_name: str, *,
@@ -47,7 +46,7 @@ def ring_attention(q, k, v, axis_name: str, *,
     is split contiguously over ``axis_name`` in rank order.  Returns the
     local output shard [B, T/n, H, D].
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, t_local, h, d = q.shape
     if sm_scale is None:
@@ -119,7 +118,7 @@ def _ring_flash_fwd_impl(q, k, v, axis_name, causal, sm_scale, block_q,
     merged across ring steps by logsumexp weights.  Head-major in/out."""
     from ..ops.flash_attention import _flash_fwd_pallas
 
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, h, t_local, d = q.shape
     q_off = idx * t_local
@@ -158,7 +157,7 @@ def _ring_flash_bwd_impl(q, k, v, out, lse, do, axis_name, causal, sm_scale,
     that rotate WITH their kv shard and arrive home after the full cycle."""
     from ..ops.flash_attention import _flash_bwd_pallas
 
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, h, t_local, d = q.shape
     q_off = idx * t_local
@@ -272,7 +271,7 @@ def ulysses_attention(q, k, v, axis_name: str, *,
     blockwise attention over the FULL sequence → all_to_all back.
     Requires ``H % n == 0``.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     h = q.shape[2]
     if h % n != 0:
         raise ValueError(f"num_heads {h} not divisible by axis size {n}")

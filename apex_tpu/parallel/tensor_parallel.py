@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .distributed import _axis_size
 
 
 def _axis_index(axis_name):
@@ -120,7 +119,7 @@ def tp_self_attention(x, wqkv_local, wo_local, num_heads_local: int,
 def shard_column(w, axis_name: str, n: Optional[int] = None):
     """Slice a replicated ``[d_in, d_out]`` weight to this shard's
     column-parallel ``[d_in, d_out/n]`` piece (inside shard_map)."""
-    n = n or _axis_size(axis_name)
+    n = n or lax.axis_size(axis_name)
     if w.shape[-1] % n:
         raise ValueError(
             f"column-parallel split needs d_out {w.shape[-1]} divisible by "
@@ -133,7 +132,7 @@ def shard_column(w, axis_name: str, n: Optional[int] = None):
 def shard_row(w, axis_name: str, n: Optional[int] = None):
     """Slice a replicated ``[d_in, d_out]`` weight to this shard's
     row-parallel ``[d_in/n, d_out]`` piece (inside shard_map)."""
-    n = n or _axis_size(axis_name)
+    n = n or lax.axis_size(axis_name)
     if w.shape[0] % n:
         raise ValueError(
             f"row-parallel split needs d_in {w.shape[0]} divisible by "

@@ -48,7 +48,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .distributed import _axis_size
 
 try:        # jax>=0.8: Varying->Invariant gather for the vma type system;
     from jax._src.lax.parallel import (     # not yet re-exported publicly
@@ -126,7 +125,7 @@ def _shard_one(flat_p, flat_g, state_inner, tx, n, idx, num_shards,
     bucket.  ``denom`` overrides the mean divisor (the full data-replica
     count — ``n`` times the pre-axes' sizes); default ``n``, the single-
     axis zero1 contract."""
-    from .distributed import _axis_size, _note_collective
+    from .distributed import _note_collective
 
     chunk0 = -(-flat_p.size // num_shards)
     pad = chunk0 * num_shards - flat_p.size
@@ -151,7 +150,7 @@ def _shard_one(flat_p, flat_g, state_inner, tx, n, idx, num_shards,
     g_local = lax.psum_scatter(flat_g, axis_name, scatter_dimension=0,
                                tiled=True)
     for ax in pre_axes:
-        if _axis_size(ax) > 1:
+        if lax.axis_size(ax) > 1:
             _note_collective("psum", ax,
                              chunk * jnp.dtype(flat_g.dtype).itemsize, 1,
                              dtype=flat_g.dtype)
@@ -222,7 +221,7 @@ def zero1(tx, axis_name: str, *, num_shards: int, bucketed: bool = False):
 
         def update(grads, state, params, *, apply_mask=None, **kw):
             store = _store(params)
-            n = _axis_size(axis_name)
+            n = lax.axis_size(axis_name)
             idx = lax.axis_index(axis_name)
             packed_p = store.pack(params)
             packed_g = store.pack(grads, cast=True)
@@ -247,7 +246,7 @@ def zero1(tx, axis_name: str, *, num_shards: int, bucketed: bool = False):
         return Zero1State(inner=tx.init(flat))
 
     def update(grads, state, params, *, apply_mask=None, **kw):
-        n = _axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         idx = lax.axis_index(axis_name)
         flat_p = _flatten(params)
         flat_g = _flatten(grads).astype(flat_p.dtype)

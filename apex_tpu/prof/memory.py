@@ -51,6 +51,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
+from jax.extend.core import Literal
 
 from .capture import region_path
 
@@ -139,7 +140,7 @@ def live_buffer_walk(closed_jaxpr, *, region_depth: int = 1,
         last_use: Dict[Any, int] = {}
         for i, eqn in enumerate(j.eqns):
             for v in eqn.invars:
-                if hasattr(v, "aval") and not isinstance(v, jax.core.Literal):
+                if hasattr(v, "aval") and not isinstance(v, Literal):
                     last_use[v] = i
         # never free outputs NOR this jaxpr's own inputs: XLA keeps
         # (non-donated) arguments allocated for the whole execution, so
@@ -150,7 +151,7 @@ def live_buffer_walk(closed_jaxpr, *, region_depth: int = 1,
         keep = set(live)
         keep.update(v for v in j.outvars
                     if hasattr(v, "aval")
-                    and not isinstance(v, jax.core.Literal))
+                    and not isinstance(v, Literal))
         total = sum(b for b, *_ in live.values())
         peak, snap = total, dict(live)
         for i, eqn in enumerate(j.eqns):
@@ -178,7 +179,7 @@ def live_buffer_walk(closed_jaxpr, *, region_depth: int = 1,
                 sub_outs = sum(
                     _aval_bytes(v.aval) for v in inner.outvars
                     if hasattr(v, "aval")
-                    and not isinstance(v, jax.core.Literal))
+                    and not isinstance(v, Literal))
                 transient = max(0, sub_peak - sub_args - sub_outs)
             # outputs are born...
             born = []
@@ -198,7 +199,7 @@ def live_buffer_walk(closed_jaxpr, *, region_depth: int = 1,
                                               "<callee temps>")
             # ...then operands whose last use this was are freed
             for v in eqn.invars:
-                if isinstance(v, jax.core.Literal):
+                if isinstance(v, Literal):
                     continue
                 if (last_use.get(v) == i and v in live and v not in keep):
                     total -= live.pop(v)[0]
@@ -217,7 +218,7 @@ def live_buffer_walk(closed_jaxpr, *, region_depth: int = 1,
                     if hasattr(v, "aval"))
     out_bytes = sum(_aval_bytes(v.aval) for v in jaxpr.outvars
                     if hasattr(v, "aval")
-                    and not isinstance(v, jax.core.Literal))
+                    and not isinstance(v, Literal))
     return {"peak_bytes": int(peak), "argument_bytes": int(arg_bytes),
             "output_bytes": int(out_bytes), "by_region": by_region,
             "top_allocations": allocs[:max(1, top)]}

@@ -41,6 +41,8 @@ from jax.experimental import pallas as pl
 
 from ..normalization.fused_layer_norm import _use_pallas
 from ..pallas_compat import align_vma as _align_vma
+from ..pallas_compat import match_vma as _match_vma
+from ..pallas_compat import mxu_dot as _mxu_dot
 from ..pallas_compat import sds_with_vma as _sds
 from ..tune.dispatch import kernel_config as _tuned_config
 from ..tune.space import pow2_bucket as _pow2
@@ -150,8 +152,7 @@ def _qmm_kernel(x_ref, qw_ref, xs_ref, ws_ref, out_ref):
     xs = xs_ref[0, 0]                                   # scalar x_scale
     q = jnp.round(x_ref[:].astype(jnp.float32) * (1.0 / xs))
     qx = jnp.clip(q, -QMAX, QMAX).astype(jnp.int8)
-    acc = jax.lax.dot_general(qx, qw_ref[:], (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.int32)
+    acc = _mxu_dot(qx, qw_ref[:], ((1,), (0,)), jnp.int32)
     # dequantize-fused epilogue: per-channel scale broadcast over rows
     out = acc.astype(jnp.float32) * (xs * ws_ref[:])    # [1, bn] bcast
     out_ref[:] = out.astype(out_ref.dtype)
@@ -237,7 +238,10 @@ def _qmm_bwd(use_pallas, interpret, block_m, block_n, res, g):
     dx = jnp.dot(gx, w2d.T.astype(x2d.dtype)).astype(x2d.dtype)
     dw = jnp.dot(x2d.T.astype(w2d.dtype),
                  g.astype(w2d.dtype)).astype(w2d.dtype)
-    return dx, dw, jnp.zeros_like(x_scale), jnp.zeros_like(w_scale)
+    # a replicated weight under a sharded batch: dw is per-shard here
+    # and must arrive summed (see pallas_compat.match_vma)
+    return tuple(_match_vma(ct, p) for ct, p in zip(
+        (dx, dw, jnp.zeros_like(x_scale), jnp.zeros_like(w_scale)), res))
 
 
 _qmm.defvjp(_qmm_fwd, _qmm_bwd)

@@ -318,6 +318,16 @@ class StepPipeline:
                 self.tail_loop, state, window, self._full_valid)
         return self
 
+    def compiled(self, program: str = "hot"):
+        """The AOT executable :meth:`warmup` installed for ``program``
+        (``"hot"``/``"tail"``), or None when it was never warmed — for
+        host-side reads of the module that actually runs
+        (``as_text()``, ``memory_analysis()``)."""
+        for (prog, _sig), exe in self._aot.items():
+            if prog == program:
+                return exe
+        return None
+
     def step_window(self, state, window, n_valid: Optional[int] = None):
         """Dispatch one window: K steps, ONE program.
 
@@ -403,16 +413,13 @@ class StepPipeline:
         from .prof import memory as _memory
 
         stats = None
-        for (program, _sig), compiled in self._aot.items():
-            if program != "hot":
-                continue
+        compiled = self.compiled("hot")
+        if compiled is not None:
             try:
                 stats = _memory.stats_from_analysis(
-                    compiled.memory_analysis())  # jaxlint: disable=J010 -- exit-time host read of an ALREADY-compiled AOT executable (no retrace/recompile); the loop stops at the first usable result
+                    compiled.memory_analysis())
             except Exception:
                 stats = None
-            if stats:
-                break
         if stats is None and self._mem_template is not None:
             state_sds, window_sds = self._mem_template
             try:

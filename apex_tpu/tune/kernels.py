@@ -146,7 +146,16 @@ def _flash_case(shape: Mapping, interpret: bool) -> TuneCase:
                 jax.value_and_grad(loss, argnums=(0, 1, 2)))
         return f(q, k, v)
 
-    return TuneCase(run=run, tol=(2e-2, 2e-3))
+    def ref():
+        blockwise_attention = _mod("ops.attention").blockwise_attention
+
+        def loss(q, k, v):
+            o = blockwise_attention(q, k, v, causal=causal)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+    return TuneCase(run=run, ref=ref, tol=(2e-2, 2e-3))
 
 
 def _flash_bucket(shape: Mapping) -> str:
@@ -216,20 +225,22 @@ def _ln_case(shape: Mapping, interpret: bool) -> TuneCase:
     b = jnp.linspace(-0.1, 0.1, n2, dtype=jnp.float32)
     fns: Dict[int, object] = {}
 
+    def grad_fn(**kw):
+        def loss(x, w, b):
+            o = fused_layer_norm(x, (n2,), w, b, **kw)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+
     def run(cfg):
         rb = int(cfg["row_block"])
         f = fns.get(rb)
         if f is None:
-            def loss(x, w, b):
-                o = fused_layer_norm(x, (n2,), w, b, impl="pallas",
-                                     row_block=rb, interpret=interpret)
-                return jnp.sum(o.astype(jnp.float32) ** 2)
-
-            f = fns[rb] = jax.jit(jax.value_and_grad(loss,
-                                                     argnums=(0, 1, 2)))
+            f = fns[rb] = grad_fn(impl="pallas", row_block=rb,
+                                  interpret=interpret)
         return f(x, w, b)
 
-    return TuneCase(run=run)
+    return TuneCase(run=run, ref=lambda: grad_fn(impl="jnp")(x, w, b))
 
 
 def _ln_bucket(shape: Mapping) -> str:
@@ -298,26 +309,26 @@ def _bn_case(shape: Mapping, interpret: bool) -> TuneCase:
     scale = jnp.linspace(0.5, 1.5, c, dtype=jnp.float32)
     bias = jnp.linspace(-0.1, 0.1, c, dtype=jnp.float32)
     fns: Dict[int, object] = {}
+    args = (x, mean, invstd, scale, bias) + ((z,) if has_z else ())
+
+    def grad_fn(**kw):
+        def loss(x, mean, invstd, scale, bias, *rest):
+            o = bn_relu_residual(x, mean, invstd, scale, bias,
+                                 z=(rest[0] if has_z else None), **kw)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(len(args)))))
 
     def run(cfg):
         rb = int(cfg["row_block"])
         f = fns.get(rb)
         if f is None:
-            argnums = (0, 1, 2, 3, 4) + ((5,) if has_z else ())
-
-            def loss(x, mean, invstd, scale, bias, *rest):
-                o = bn_relu_residual(x, mean, invstd, scale, bias,
-                                     z=(rest[0] if has_z else None),
-                                     impl="pallas", interpret=interpret,
-                                     row_block=rb)
-                return jnp.sum(o.astype(jnp.float32) ** 2)
-
-            f = fns[rb] = jax.jit(jax.value_and_grad(loss,
-                                                     argnums=argnums))
-        args = (x, mean, invstd, scale, bias) + ((z,) if has_z else ())
+            f = fns[rb] = grad_fn(impl="pallas", interpret=interpret,
+                                  row_block=rb)
         return f(*args)
 
-    return TuneCase(run=run)
+    return TuneCase(run=run, ref=lambda: grad_fn(impl="jnp")(*args))
 
 
 def _bn_bucket(shape: Mapping) -> str:
@@ -402,7 +413,15 @@ def _xe_case(shape: Mapping, interpret: bool) -> TuneCase:
             f = fns[rb] = jax.jit(both)
         return f(logits, g)
 
-    return TuneCase(run=run)
+    def ref():
+        def both(logits, g):
+            losses, mlse = xe._fwd_ref(logits, labels, 0.1)
+            dx = xe._bwd_ref(g, logits, mlse, labels, 0.1)
+            return losses, mlse, dx
+
+        return jax.jit(both)(logits, g)
+
+    return TuneCase(run=run, ref=ref)
 
 
 def _xe_bucket(shape: Mapping) -> str:
@@ -475,23 +494,22 @@ def _qmm_case(shape: Mapping, interpret: bool) -> TuneCase:
     x_scale = 0.25 / 127.0
     fns: Dict[tuple, object] = {}
 
+    def grad_fn(**kw):
+        def loss(x, w):
+            o = quantized_matmul(x, w, x_scale=x_scale, **kw)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))
+
     def run(cfg):
         key = (int(cfg["block_m"]), int(cfg["block_n"]))
         f = fns.get(key)
         if f is None:
-            bm, bn = key
-
-            def loss(x, w):
-                o = quantized_matmul(x, w, x_scale=x_scale, impl="pallas",
-                                     interpret=interpret, block_m=bm,
-                                     block_n=bn)
-                return jnp.sum(o.astype(jnp.float32) ** 2)
-
-            f = fns[key] = jax.jit(jax.value_and_grad(loss,
-                                                      argnums=(0, 1)))
+            f = fns[key] = grad_fn(impl="pallas", interpret=interpret,
+                                   block_m=key[0], block_n=key[1])
         return f(x, w)
 
-    return TuneCase(run=run)
+    return TuneCase(run=run, ref=lambda: grad_fn(impl="jnp")(x, w))
 
 
 def _qmm_bucket(shape: Mapping) -> str:
@@ -537,6 +555,14 @@ def _conv_dims(shape: Mapping):
             bool(shape.get("residual", True)))
 
 
+def _conv_epilogue(shape: Mapping) -> bool:
+    """``epilogue: False`` builds the bare conv — what
+    :class:`apex_tpu.ops.PallasConv` dispatches from a model (BN needs
+    the conv's output for its statistics first); the default is the
+    fused conv+bn+relu(+residual) chain."""
+    return bool(shape.get("epilogue", True))
+
+
 def _conv_candidates(shape: Mapping, bound: Optional[str]):
     out = []
     for bm in (128, 256, 512, 1024):
@@ -551,11 +577,12 @@ def _conv_constraint(shape: Mapping, cfg: Dict[str, int]) -> bool:
     cv = _mod("ops.conv")
     n, h, w, cin, cout, kh, kw, s, dtype, res = _conv_dims(shape)
     padding = cv._norm_padding("SAME", h, w, kh, kw, s, s, 1, 1)
-    # want_preact=True: the training forward (epilogue + custom VJP)
-    # also streams the saved pre-activation block, the worst case.
+    # want_preact: the training forward (epilogue + custom VJP) also
+    # streams the saved pre-activation block, the worst case.
+    epi = _conv_epilogue(shape)
     return cv._fwd_fits(h, w, padding, cin, cout, kh, kw, s, s, 1, 1,
                         int(cfg["block_m"]), int(cfg["block_n"]),
-                        dtype.itemsize, res, True)
+                        dtype.itemsize, res and epi, epi)
 
 
 def _conv_case(shape: Mapping, interpret: bool) -> TuneCase:
@@ -566,42 +593,47 @@ def _conv_case(shape: Mapping, interpret: bool) -> TuneCase:
          ).astype(dtype)
     wt = (jrandom.normal(jrandom.PRNGKey(1), (kh, kw, cin, cout),
                          jnp.float32) * 0.05).astype(dtype)
-    mean = jnp.zeros((cout,), jnp.float32)
-    invstd = jnp.ones((cout,), jnp.float32)
-    scale = jnp.ones((cout,), jnp.float32)
-    bias = jnp.zeros((cout,), jnp.float32)
     oh, ow = -(-h // s), -(-w // s)
-    z = (jnp.ones((n, oh, ow, cout), jnp.float32).astype(dtype)
-         if res else None)
     fns: Dict[tuple, object] = {}
+    if _conv_epilogue(shape):
+        args = (x, wt, jnp.zeros((cout,), jnp.float32),     # mean
+                jnp.ones((cout,), jnp.float32),             # invstd
+                jnp.ones((cout,), jnp.float32),             # scale
+                jnp.zeros((cout,), jnp.float32))            # bias
+        z = (jnp.ones((n, oh, ow, cout), jnp.float32).astype(dtype)
+             if res else None)
+        epi = {"z": z, "relu": True}
+    else:
+        args, epi = (x, wt), {}
+
+    def grad_fn(**kw):
+        def loss(x, wt, *stats):
+            o = cv.conv2d(x, wt, stride=s, padding="SAME",
+                          **dict(zip(("mean", "invstd", "scale", "bias"),
+                                     stats)), **epi, **kw)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(len(args)))))
 
     def run(cfg):
         key = (int(cfg["block_m"]), int(cfg["block_n"]))
         f = fns.get(key)
         if f is None:
-            bm, bn = key
+            f = fns[key] = grad_fn(impl="pallas", interpret=interpret,
+                                   block_m=key[0], block_n=key[1])
+        return f(*args)
 
-            def loss(x, wt, mean, invstd, scale, bias):
-                o = cv.conv2d(x, wt, stride=s, padding="SAME",
-                              mean=mean, invstd=invstd, scale=scale,
-                              bias=bias, z=z, relu=True, impl="pallas",
-                              interpret=interpret, block_m=bm,
-                              block_n=bn)
-                return jnp.sum(o.astype(jnp.float32) ** 2)
-
-            f = fns[key] = jax.jit(jax.value_and_grad(
-                loss, argnums=(0, 1, 2, 3, 4, 5)))
-        return f(x, wt, mean, invstd, scale, bias)
-
-    return TuneCase(run=run)
+    return TuneCase(run=run, ref=lambda: grad_fn(impl="jnp")(*args))
 
 
 def _conv_bucket(shape: Mapping) -> str:
     cv = _mod("ops.conv")
     n, h, w, cin, cout, kh, kw, s, dtype, res = _conv_dims(shape)
     oh, ow = -(-h // s), -(-w // s)
+    epi = _conv_epilogue(shape)
     return cv.tune_bucket(n, oh, ow, cin, cout, kh, kw, s, s, 1, 1,
-                          dtype.itemsize, True, res)
+                          dtype.itemsize, epi, res and epi)
 
 
 def _conv_version() -> int:

@@ -48,6 +48,11 @@ class TuneCase:
     harness times it and compares candidates' outputs against the
     default config's."""
     run: Callable[[Dict[str, int]], object]
+    #: the same computation on the same inputs through the jnp reference
+    #: the kernel's module carries (its fallback and test oracle); same
+    #: output pytree as ``run``.  What an on-chip kernel sweep compares
+    #: the Mosaic-compiled ``run`` against.
+    ref: Optional[Callable[[], object]] = None
     #: oracle tolerance for non-exact kernels (rtol, atol)
     tol: Tuple[float, float] = (2e-2, 2e-3)
 

@@ -11,8 +11,10 @@ same script drives 1 chip, an 8-device CPU mesh, or a pod:
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python fsdp_train.py --zero 3 --steps 32
 
-    # multi-host: one process per host, env from the launcher
-    python -m apex_tpu.parallel.multiproc --nproc 2 fsdp_train.py --zero 3
+    # multi-host, emulated on the CPU backend: one process per "host",
+    # env from the launcher (on a TPU host ONE process drives all chips)
+    JAX_PLATFORMS=cpu python -m apex_tpu.parallel.multiproc --nproc 2 \
+        fsdp_train.py --zero 3
 """
 
 import os as _os

@@ -35,7 +35,13 @@ setup(
                 "(the capabilities of NVIDIA Apex, rebuilt on jax/XLA/Pallas)",
     packages=find_packages(include=["apex_tpu", "apex_tpu.*"]),
     package_data={"apex_tpu": ["csrc/*.cpp"]},
-    python_requires=">=3.10",
-    install_requires=["jax", "flax", "numpy"],
+    python_requires=">=3.12",
+    # The one installation the code is written for and has run on (the
+    # jax < 0.9 compatibility branches are gone: jax.shard_map,
+    # lax.axis_size, lax.pcast, ShapeDtypeStruct(vma=)).  The TPU runtime
+    # that ran chip_smoke.py is libtpu 0.0.34.
+    install_requires=["jax>=0.9.0", "jaxlib>=0.9.0", "flax>=0.12.3",
+                      "numpy>=2.0"],
+    extras_require={"tpu": ["libtpu>=0.0.34"]},
     cmdclass={"build_native": BuildNative},
 )

@@ -16,14 +16,6 @@ from apex_tpu.parallel.expert_parallel import moe_layer
 from apex_tpu.parallel.pipeline import stack_stage_params
 
 
-# Pre-vma jax (< 0.5; conftest shims shard_map with check_rep=False)
-# inserts no implicit psum when differentiating w.r.t. replicated params
-# under shard_map, so grad-vs-sequential-oracle comparisons only hold on
-# vma-aware jax.
-_pre_vma_jax = pytest.mark.skipif(
-    jax.__version_info__ < (0, 5),
-    reason="asserts jax>=0.5 shard_map autodiff (implicit psum) semantics")
-
 E = 4          # experts == ep ranks
 D = 8
 T = 16         # tokens per rank
@@ -104,7 +96,6 @@ def test_moe_drops_overflow_tokens(ep_mesh):
                                     * E * T)
 
 
-@_pre_vma_jax
 def test_moe_gradients_flow_to_experts_and_router(ep_mesh):
     router, experts = _params()
     stacked = stack_stage_params(experts)

@@ -134,14 +134,9 @@ def test_dp_train_step_on_mesh():
     init2, step2 = make_train_step(loss_fn, tx, opt_level="O2",
                                    keep_batchnorm_fp32=False)
     ref_state, _ = jax.jit(step2)(init2(params), (x, y))
-    # Pre-0.5 jax (conftest's check_rep=False shard_map shim) inserts no
-    # implicit psum, so grads reduce via the explicit collective — a
-    # different bf16 summation order than the single-device oracle;
-    # allow one bf16 ulp there, keep the tight gate on vma-aware jax.
-    tol = ({"atol": 1e-6, "rtol": 1e-6} if jax.__version_info__ >= (0, 5)
-           else {"atol": 4e-3, "rtol": 4e-3})
     np.testing.assert_allclose(np.asarray(new_state.params["w"]),
-                               np.asarray(ref_state.params["w"]), **tol)
+                               np.asarray(ref_state.params["w"]),
+                               atol=1e-6, rtol=1e-6)
 
 
 @pytest.mark.slow

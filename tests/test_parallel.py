@@ -27,14 +27,6 @@ from apex_tpu.optimizers import FusedSGD
 
 NDEV = 8
 
-# Pre-vma jax (< 0.5, conftest shims shard_map from the experimental
-# home with check_rep=False): shard_map autodiff inserts no implicit
-# psum and group collectives lower differently, so the tests asserting
-# those newer-jax contracts are version-gated.
-_pre_vma_jax = pytest.mark.skipif(
-    jax.__version_info__ < (0, 5),
-    reason="asserts jax>=0.5 shard_map vma/lowering semantics")
-
 
 def _mesh():
     return Mesh(np.array(jax.devices("cpu")[:NDEV]), ("data",))
@@ -72,7 +64,6 @@ def test_reduce_gradients_check_vma_false_still_reduces():
                                rtol=1e-6)
 
 
-@_pre_vma_jax
 def test_reduce_gradients_implicit_psum_with_subgroups_divides_full_axis():
     """Regression: a grad already full-axis-psummed by shard_map autodiff
     must be divided by the FULL axis size even when axis_index_groups names
@@ -408,7 +399,6 @@ def test_group_psum_butterfly_matches_expected():
     np.testing.assert_array_equal(out[4:], np.full(4, 26.0))   # 5+6+7+8
 
 
-@_pre_vma_jax
 def test_group_psum_butterfly_no_full_world_gather():
     """The lowered HLO for power-of-two groups must contain collective
     permutes, not a full-world all-gather (pod-scalability contract)."""

@@ -18,14 +18,6 @@ S = 4          # stages
 M = 4          # microbatches
 D = 16
 
-# Pre-vma jax (< 0.5; conftest shims shard_map with check_rep=False)
-# inserts no implicit psum when differentiating w.r.t. replicated params
-# under shard_map, so grad-vs-sequential-oracle comparisons only hold on
-# vma-aware jax.
-_pre_vma_jax = pytest.mark.skipif(
-    jax.__version_info__ < (0, 5),
-    reason="asserts jax>=0.5 shard_map autodiff (implicit psum) semantics")
-
 
 @pytest.fixture
 def pp_mesh():
@@ -63,7 +55,6 @@ def test_pipeline_forward_matches_sequential(pp_mesh):
                                atol=1e-6, rtol=1e-6)
 
 
-@_pre_vma_jax
 def test_pipeline_grads_match_sequential(pp_mesh):
     per_stage = _params()
     stacked = stack_stage_params(per_stage)

@@ -56,9 +56,6 @@ def _run_ring_flash(mesh, q, k, v, causal):
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_flash_forward_matches_oracle(sp_mesh, causal):
-    if not causal and jax.__version_info__ < (0, 5):
-        pytest.skip("pre-0.5 SPMD partitioner rejects the non-causal "
-                    "ring's PartitionId lowering (UNIMPLEMENTED)")
     q, k, v = _qkv()
     out = _run_ring_flash(sp_mesh, q, k, v, causal)
     ref = dot_product_attention(q, k, v, causal=causal)

@@ -259,11 +259,23 @@ def test_load_peaks_reads_bench_extra(tmp_path):
     assert pk["flops"] == pytest.approx(127.4e12)
     assert pk["hbm_gb_s"] == 881.0
     assert pk["bw_source"] == "measured_loop_fusion"
-    # a directory works too, and a missing file degrades to defaults
+    # a directory works too; a missing file is an error, not a default
     assert roofline.load_peaks(str(tmp_path))["flops"] \
         == pytest.approx(127.4e12)
-    empty = roofline.load_peaks(str(tmp_path / "nope.json"))
-    assert empty["flops"] > 0 and "default" in empty["source"]
+    with pytest.raises(ValueError, match="no usable peaks artifact"):
+        roofline.load_peaks(str(tmp_path / "nope.json"))
+
+
+def test_load_peaks_device_table_and_unknown_device():
+    """No artifact: the published peaks of the NAMED device; a device
+    that is not in the table (the CPU this suite runs on) raises
+    instead of borrowing the v5e's numbers."""
+    pk = roofline.load_peaks(device_kind="TPU v5 lite")
+    assert pk["flops"] == 197e12 and pk["hbm_gb_s"] == 819.0
+    with pytest.raises(ValueError, match="unknown device"):
+        roofline.load_peaks()
+    with pytest.raises(ValueError, match="unknown device"):
+        roofline.device_peaks("TPU v99")
 
 
 def test_roofline_cli_json(tmp_path, capsys, monkeypatch):
@@ -277,7 +289,8 @@ def test_roofline_cli_json(tmp_path, capsys, monkeypatch):
         "    return f, (jnp.zeros((256, 512)), jnp.zeros((512, 512)))\n")
     monkeypatch.syspath_prepend(str(tmp_path))
     rc = roofline.main(["--fn", "roofline_cli_target:entry", "--no-xla",
-                        "--step-ms", "1.0", "--json"])
+                        "--step-ms", "1.0", "--json",
+                        "--device-kind", "TPU v5 lite"])
     assert rc == 0
     led = json.loads(capsys.readouterr().out)
     assert led["total"]["matmul_flops_g"] == pytest.approx(

@@ -335,10 +335,9 @@ def test_as_dict_snapshot_consistent_fields():
 # -- collective byte events ---------------------------------------------------
 
 def test_reduce_gradients_records_psum_bytes(tmp_path):
-    from apex_tpu.parallel import import_shard_map
+    from jax import shard_map
     from apex_tpu.parallel.distributed import reduce_gradients
 
-    shard_map = import_shard_map()
     mesh = Mesh(np.array(jax.devices("cpu")[:NDEV]), ("data",))
     path = str(tmp_path / "run.jsonl")
     rec = telemetry.start(path)
@@ -356,10 +355,9 @@ def test_reduce_gradients_records_psum_bytes(tmp_path):
 
 
 def test_zero1_records_collective_pair(tmp_path):
-    from apex_tpu.parallel import import_shard_map
+    from jax import shard_map
     from apex_tpu.parallel.zero import zero1, zero1_partition_spec
 
-    shard_map = import_shard_map()
     mesh = Mesh(np.array(jax.devices("cpu")[:NDEV]), ("data",))
     path = str(tmp_path / "run.jsonl")
     rec = telemetry.start(path)

@@ -18,14 +18,6 @@ from apex_tpu.parallel import (column_parallel_dense, row_parallel_dense,
                                shard_column, shard_row, tp_mlp,
                                tp_self_attention)
 
-# Pre-vma jax (< 0.5; conftest shims shard_map with check_rep=False)
-# inserts no implicit psum when differentiating w.r.t. replicated params
-# under shard_map, so grad-vs-sequential-oracle comparisons only hold on
-# vma-aware jax.
-_pre_vma_jax = pytest.mark.skipif(
-    jax.__version_info__ < (0, 5),
-    reason="asserts jax>=0.5 shard_map autodiff (implicit psum) semantics")
-
 
 @pytest.fixture
 def tp_mesh():
@@ -117,7 +109,6 @@ def test_shard_helpers_roundtrip(tp_mesh):
     np.testing.assert_array_equal(np.asarray(rows), np.asarray(w))
 
 
-@_pre_vma_jax
 def test_tp_gradients_stay_local_and_match(tp_mesh):
     """Backprop through a column->row pair: each shard's weight grads equal
     the corresponding slice of the dense-model grads (no collective needed
