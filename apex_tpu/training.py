@@ -48,6 +48,18 @@ from .amp.properties import opt_levels
 from .optimizers import functional as F
 from .parallel.distributed import reduce_gradients
 
+#: The phases of a training step, as ``jax.named_scope``s around the work
+#: ``make_train_step`` issues.  This is the operator's vocabulary: XProf and
+#: TensorBoard show these names in the framework-op view of any trace of any
+#: apex_tpu training step, and the compiled HLO carries them in ``op_name``.
+#: The backward pass has no scope of its own: JAX renders the transposed
+#: equations of ``apex.forward`` as ``transpose(jvp(apex.forward))`` (and the
+#: gradient up-cast as ``transpose(jvp(apex.cast))``).  Scopes are metadata:
+#: they change no compiled program.
+PHASE_SCOPES = ("apex.cast", "apex.forward", "apex.allreduce", "apex.scaler",
+                "apex.optimizer", "apex.metrics")
+(_CAST, _FORWARD, _ALLREDUCE, _SCALER, _OPTIMIZER, _METRICS) = PHASE_SCOPES
+
 
 def _pmean_varying(x, axis_name):
     """pmean over only the axes ``x`` actually varies on (pmean over an
@@ -315,15 +327,20 @@ def make_train_step(loss_fn: Callable,
         raise ValueError(f"accum_steps must be >= 1, got {accum_steps}")
 
     def step_fn(state: TrainState, batch):
+        def scaled_loss_cp(cp, ms, mb):
+            with jax.named_scope(_FORWARD):
+                if has_model_state:
+                    loss, new_ms = loss_fn(cp, ms, mb)
+                else:
+                    loss = loss_fn(cp, mb)
+                    new_ms = ms
+                return (jnp.asarray(loss, jnp.float32)
+                        * state.scaler.loss_scale), (loss, new_ms)
+
         def scaled_loss(p, ms, mb):
-            cp = compute_cast(p)
-            if has_model_state:
-                loss, new_ms = loss_fn(cp, ms, mb)
-            else:
-                loss = loss_fn(cp, mb)
-                new_ms = ms
-            return (jnp.asarray(loss, jnp.float32)
-                    * state.scaler.loss_scale), (loss, new_ms)
+            with jax.named_scope(_CAST):
+                cp = compute_cast(p)
+            return scaled_loss_cp(cp, ms, mb)
 
         if accum_steps == 1:
             grads, (loss, new_ms) = jax.grad(
@@ -349,20 +366,12 @@ def make_train_step(loss_fn: Callable,
             # accumulated full-tree gradient is mapped back to the
             # stored layout after the scan — one gather and one scatter
             # per step, not per microbatch.
-            if param_view is not None:
-                full, view_vjp = jax.vjp(view, state.params)
-            else:
-                full, view_vjp = state.params, None
-            cp = cast_only(full)
-
-            def scaled_loss_cp(cp_, ms, mb):
-                if has_model_state:
-                    loss, new_ms = loss_fn(cp_, ms, mb)
+            with jax.named_scope(_CAST):
+                if param_view is not None:
+                    full, view_vjp = jax.vjp(view, state.params)
                 else:
-                    loss = loss_fn(cp_, mb)
-                    new_ms = ms
-                return (jnp.asarray(loss, jnp.float32)
-                        * state.scaler.loss_scale), (loss, new_ms)
+                    full, view_vjp = state.params, None
+                cp = cast_only(full)
 
             def one_micro(carry, mb):
                 ms, g_acc, l_acc = carry
@@ -381,44 +390,49 @@ def make_train_step(loss_fn: Callable,
                 grads, = view_vjp(grads)
 
         if axis_name is not None and reduce_grads:
-            grads = reduce_gradients(
-                grads, axis_name,
-                gradient_average=gradient_average,
-                gradient_predivide_factor=gradient_predivide_factor,
-                allreduce_always_fp32=allreduce_always_fp32,
-                axis_index_groups=axis_index_groups)
+            with jax.named_scope(_ALLREDUCE):
+                grads = reduce_gradients(
+                    grads, axis_name,
+                    gradient_average=gradient_average,
+                    gradient_predivide_factor=gradient_predivide_factor,
+                    allreduce_always_fp32=allreduce_always_fp32,
+                    axis_index_groups=axis_index_groups)
 
-        grads, scaler_state = scaler.unscale(grads, state.scaler)
-        if dynamic and axis_name is not None:
-            # Sharded (e.g. tensor-parallel) grads: agree on overflow
-            # mesh-wide so every rank skips (or steps) together.
-            scaler_state = scaler_state._replace(
-                overflow=_por_varying(scaler_state.overflow, axis_name))
-        if dynamic:
-            apply_mask = jnp.logical_not(scaler_state.overflow)
-        else:
-            apply_mask = None
-        new_params, new_opt_state = optimizer.update(
-            grads, state.opt_state, state.params, apply_mask=apply_mask)
-        scaler_state = scaler.update_scale(scaler_state)
+        with jax.named_scope(_SCALER):
+            grads, scaler_state = scaler.unscale(grads, state.scaler)
+            if dynamic and axis_name is not None:
+                # Sharded (e.g. tensor-parallel) grads: agree on overflow
+                # mesh-wide so every rank skips (or steps) together.
+                scaler_state = scaler_state._replace(
+                    overflow=_por_varying(scaler_state.overflow, axis_name))
+            if dynamic:
+                apply_mask = jnp.logical_not(scaler_state.overflow)
+            else:
+                apply_mask = None
+        with jax.named_scope(_OPTIMIZER):
+            new_params, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params, apply_mask=apply_mask)
+        with jax.named_scope(_SCALER):
+            scaler_state = scaler.update_scale(scaler_state)
 
-        if axis_name is not None:
-            # Replicated metric, like the reference examples' allreduced
-            # loss prints (main_amp.py:356-394); batch stats (BN running
-            # mean/var) averaged across replicas so the carried state stays
-            # replicated — the reference leaves stats per-rank, which only
-            # works because each rank owns its module copy; under SPMD a
-            # replicated pytree is the contract.  Each value is averaged
-            # only over axes it actually varies on.
-            loss = _pmean_varying(loss, axis_name)
-            if new_ms is not None:
-                new_ms = jax.tree_util.tree_map(
-                    lambda x: _pmean_varying(x, axis_name), new_ms)
-        metrics = {"loss": loss,
-                   "loss_scale": scaler_state.loss_scale,
-                   "overflow": (jnp.logical_not(apply_mask)
-                                if apply_mask is not None
-                                else jnp.asarray(False))}
+        with jax.named_scope(_METRICS):
+            if axis_name is not None:
+                # Replicated metric, like the reference examples' allreduced
+                # loss prints (main_amp.py:356-394); batch stats (BN running
+                # mean/var) averaged across replicas so the carried state
+                # stays replicated — the reference leaves stats per-rank,
+                # which only works because each rank owns its module copy;
+                # under SPMD a replicated pytree is the contract.  Each
+                # value is averaged only over axes it actually varies on.
+                loss = _pmean_varying(loss, axis_name)
+                if new_ms is not None:
+                    new_ms = jax.tree_util.tree_map(
+                        lambda x: _pmean_varying(x, axis_name), new_ms)
+            metrics = {"loss": loss,
+                       "loss_scale": scaler_state.loss_scale,
+                       "overflow": (jnp.logical_not(apply_mask)
+                                    if apply_mask is not None
+                                    else jnp.asarray(False))}
         return TrainState(params=new_params, opt_state=new_opt_state,
                           scaler=scaler_state, model_state=new_ms), metrics
 
