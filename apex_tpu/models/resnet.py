@@ -8,20 +8,20 @@ created fp32 via the keep-bn-fp32 path convention — parameters live under
 SyncBatchNorm-pluggable for the ``--sync_bn`` flow
 (``main_amp.py:141-146``).
 
-**Fused conv epilogues (ISSUE 7).**  Every residual block is a chain of
-``conv -> bn -> relu`` with a trailing ``bn -> (+residual) -> relu``; on
-the memory-bound amp-O2 step those elementwise tails are where the HBM
-bytes go (r05 ledger: ~93% of HBM peak, MXU 25% busy).  The blocks
-therefore route each chain through a *norm-factory hook*: when the norm
-module supports the apex ``bn_relu``/``bn_add_relu`` contract
+**BN epilogues through the norm (ISSUE 7).**  Every residual block is a
+chain of ``conv -> bn -> relu`` with a trailing ``bn -> (+residual) ->
+relu``.  The blocks route each chain through a *norm-factory hook*: when
+the norm module supports the apex ``bn_relu``/``bn_add_relu`` contract
 (``fuse_relu=`` ctor flag + ``z=`` residual call arg — SyncBatchNorm and
-``contrib.groupbn.BatchNorm2d_NHWC`` both do, backed by the Pallas
-:func:`apex_tpu.normalization.bn_relu_residual` epilogue), the whole
-chain becomes ONE fused epilogue; plain ``nn.BatchNorm`` keeps the
-explicit ``relu(bn(y) + residual)`` statements.  ``norm_cls`` injects an
-external factory (e.g. ``functools.partial(BatchNorm2d_NHWC,
-bn_group=...)``); ``fused_epilogue`` forces the routing on (error if
-unsupported) or off.
+``contrib.groupbn.BatchNorm2d_NHWC`` both do, through
+:func:`apex_tpu.normalization.bn_relu_residual`), the chain is one call
+into the norm; plain ``nn.BatchNorm`` keeps the explicit ``relu(bn(y) +
+residual)`` statements.  On the TPU both are jnp that XLA fuses alike:
+measured on the v5e, a Mosaic kernel at each of these sites made the
+ResNet-50 amp-O2 step several times slower, so none is chosen
+(``PERF.md`` section 6, PR 26).  ``norm_cls`` injects an external factory
+(e.g. ``functools.partial(BatchNorm2d_NHWC, bn_group=...)``);
+``fused_epilogue`` forces the routing on (error if unsupported) or off.
 """
 
 from __future__ import annotations

@@ -1,37 +1,37 @@
-"""Fused conv-side BN epilogue: normalize + affine + residual-add + ReLU.
-
-The conv-path analog of :mod:`.fused_layer_norm` (ISSUE 7).  The r05
-roofline ledger puts ResNet-50 amp O2 at ~93% of HBM peak with the MXU
-only ~25% busy: the step is *memory*-bound, and a large share of the
-traffic is the elementwise ``bn -> relu -> (+residual)`` chains between
-convolutions — each a separate read-modify-write sweep over the
-activation tensor when left to generic fusion.  The reference attacks
-exactly this with the apex contrib ``groupbn`` persistent NHWC kernels
-(``bn_relu`` / ``bn_add_relu`` epilogues, ``csrc/groupbn/*``); the
-TPU-native equivalent is ONE Pallas pass:
+"""BatchNorm epilogue: normalize + affine + residual-add + ReLU.
 
     ``y = relu((x - mean) * invstd * scale + bias [+ z])``
 
-Statistics (batch mean/var, the cross-replica psum, running-stat
-updates) stay in XLA — they are channel reductions XLA schedules well
-and they carry the SyncBatchNorm collective contract; the kernel owns
-only the elementwise epilogue, where the bytes are.
+The conv-path analog of :mod:`.fused_layer_norm`, and the apex contrib
+``groupbn`` contract (``bn_relu`` / ``bn_add_relu``, ``csrc/groupbn/*``).
+Statistics (batch mean/var, the cross-replica psum, running-stat updates)
+are computed by the caller; this module owns the elementwise tail and its
+backward.
 
-Structure mirrors ``fused_layer_norm.py``/``contrib/xentropy``: a jnp
-reference (``_fwd_ref``/``_bwd_ref``) that doubles as the CPU fallback
-and the test oracle, Pallas forward/backward kernels with a
-``custom_vjp`` around them, and interpreter mode (``interpret=True``)
-so CPU tests exercise the REAL kernel against the reference
-(tier-parity, ISSUE 7 satellite).
+Two implementations of the same mathematics:
+
+* **XLA** (``_fwd_ref``/``_bwd_ref``, the automatic choice): plain jnp on
+  the activation in the shape it arrives in, the per-channel vectors
+  broadcast over the last axis, so the compiler fuses the tail into its
+  neighbours and no layout change stands between a convolution and its
+  BatchNorm.  Measured on the v5e inside the ResNet-50 amp-O2 step
+  (``PERF.md`` section 6, PR 26): the Mosaic kernel below ran at a fifth
+  of its HBM roofline and, as a custom call, cost a physical
+  ``[N,H,W,C] -> [rows,C]`` copy per site and fenced the fusions around
+  it; at no ResNet-50 shape did it win.
+* **Mosaic** (``impl="pallas"``, or ``interpret=True`` on the CPU): one
+  Pallas pass over ``[rows, C]``.  Kept for the tests, the tuner and
+  ``chip_smoke.py``'s kernel sweep; nothing selects it automatically.
+
+Both sit under one ``custom_vjp`` that saves ``x`` and ``z`` and
+recomputes the ReLU mask, and ``_fwd_ref``/``_bwd_ref`` double as the
+test oracle.
 
 The backward treats ``mean``/``invstd`` as independent differentiable
-inputs: their cotangents flow back into the XLA-side statistics
-computation, so autodiff of the *whole* BN (stats + epilogue) remains
-exact — the kernel never needs the Welford transpose.  Per-channel
-reductions (d_scale, d_bias, d_mean, d_invstd) are column sums XLA
-already does optimally and stay as jnp ops fused into the same program;
-the kernel computes the two activation-sized outputs (dx, dz) in one
-pass.
+inputs: their cotangents flow back into the caller's statistics, so
+autodiff of the *whole* BN (stats + epilogue) remains exact.  On the
+Mosaic side the kernel computes the two activation-sized outputs
+(dx, dz) and the per-channel reductions stay jnp.
 """
 
 from __future__ import annotations
@@ -56,11 +56,10 @@ __all__ = ["bn_relu_residual", "bn_act_epilogue_ref"]
 TUNE_VERSION = 1
 
 
-# -- reference math (jnp fallback + oracle) -----------------------------------
+# -- the XLA implementation (also the oracle) ---------------------------------
 #
-# Kept op-for-op identical to the tail SyncBatchNorm historically inlined
-# (normalize fp32, affine, + z, relu, cast back) so routing the module
-# through this function is a bitwise no-op on the jnp path.
+# Rank-agnostic: ``x`` is ``[..., C]`` and the per-channel operands are
+# ``[C]``, broadcast over the last axis.  Arithmetic in fp32, cast back.
 
 def _fwd_ref(x, mean, invstd, scale, bias, z, relu):
     out = (x.astype(jnp.float32) - mean) * invstd
@@ -91,26 +90,34 @@ def _bwd_ref(g, x, mean, invstd, scale, bias, z, relu):
     * ``d_scale = sum(g' * xhat)``; ``d_bias = sum(g')``   (per channel)
     * ``d_mean = -sum(g' * scale) * invstd``
     * ``d_invstd = sum(g' * scale * (x - mean))``
+
+    ``scale`` and ``invstd`` are constant along the reduced axes, so the
+    four are two reductions, ``sum(g')`` and ``sum(g' * (x - mean))``,
+    and per-channel products of them.
     """
-    xf = x.astype(jnp.float32)
-    gf = g.astype(jnp.float32)
+    xmu = x.astype(jnp.float32) - mean
     if relu:
-        pre = (xf - mean) * invstd
+        pre = xmu * invstd
         if scale is not None:
             pre = pre * scale + bias
         if z is not None:
             pre = pre + z.astype(jnp.float32)
-        gf = jnp.where(pre > 0, gf, 0.0)
+        # masked in g's own dtype: ONE activation-sized tensor is then
+        # dz and what the sums and dx read, where a mask applied in
+        # fp32 made XLA keep the mask as a further array beside g
+        g = jnp.where(pre > 0, g, jnp.zeros_like(g))
+    gf = g.astype(jnp.float32)
     s = scale if scale is not None else jnp.float32(1.0)
-    dx = (gf * s * invstd).astype(x.dtype)
-    dz = gf.astype(z.dtype) if z is not None else None
+    dx = (gf * (s * invstd)).astype(x.dtype)
+    dz = g.astype(z.dtype) if z is not None else None
     red = tuple(range(x.ndim - 1))          # all but the channel axis
-    xmu = xf - mean
-    d_scale = (jnp.sum(gf * xmu * invstd, axis=red)
-               if scale is not None else None)
-    d_bias = jnp.sum(gf, axis=red) if bias is not None else None
-    d_mean = -jnp.sum(gf * s, axis=red) * jnp.ravel(invstd)
-    d_invstd = jnp.sum(gf * s * xmu, axis=red)
+    sum_g = jnp.sum(gf, axis=red)
+    sum_gxmu = jnp.sum(gf * xmu, axis=red)
+    invstd = jnp.ravel(invstd)
+    d_scale = sum_gxmu * invstd if scale is not None else None
+    d_bias = sum_g if bias is not None else None
+    d_mean = -sum_g * s * invstd
+    d_invstd = sum_gxmu * s
     return dx, d_mean, d_invstd, d_scale, d_bias, dz
 
 
@@ -249,66 +256,49 @@ def _pallas_bwd(g2d, x2d, mean, invstd, scale, bias, z2d, relu, interpret,
 
 # -- dispatch -----------------------------------------------------------------
 
-# In-context crossover, same lesson as fused_layer_norm's: below a few
-# million elements the custom call is a fusion barrier that costs more
-# than it saves.  Conv-side activations at benchmark shapes (b128 x 56^2
-# x 256 = ~100M elements) sit far above it.
-_JNP_MAX_ELEMENTS = 2 * 1024 * 1024
+def _dispatch_pallas(c: int, impl: Optional[str], itemsize: int) -> bool:
+    """True when the Mosaic kernel is what runs.
 
-
-def _dispatch_pallas(n_rows: int, c: int, impl: Optional[str],
-                     itemsize: int) -> bool:
+    The automatic choice (``impl=None``) is XLA at every shape: inside the
+    ResNet-50 amp-O2 step on the v5e the kernel never won (``PERF.md``
+    section 6, PR 26).  A class of shapes a later measurement earns goes
+    here, as a predicate on what this function is given."""
     if impl not in (None, "pallas", "jnp"):
         raise ValueError(
             f"impl must be None, 'pallas', or 'jnp'; got {impl!r}")
-    if not _use_pallas() or not _kernel_fits(c, itemsize):
-        return False
-    if impl is not None:
-        return impl == "pallas"
-    return n_rows * c >= _JNP_MAX_ELEMENTS
+    return impl == "pallas" and _use_pallas() and _kernel_fits(c, itemsize)
 
 
 # -- public op with custom VJP ------------------------------------------------
+#
+# ``x``/``z`` are ``[rows, C]`` on the Mosaic side and in the caller's own
+# shape on the XLA side; the per-channel operands are ``[C]`` on both.
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
-def _epilogue(x2d, mean, invstd, scale, bias, z2d, relu, use_pallas,
+def _epilogue(x, mean, invstd, scale, bias, z, relu, use_pallas,
               interpret, row_block):
     if use_pallas:
-        return _pallas_fwd(x2d, mean, invstd, scale, bias, z2d, relu,
+        return _pallas_fwd(x, mean, invstd, scale, bias, z, relu,
                            interpret, row_block)
-    return _fwd_ref(x2d, mean, invstd, scale, bias, z2d, relu)
+    return _fwd_ref(x, mean, invstd, scale, bias, z, relu)
 
 
-def _epilogue_fwd(x2d, mean, invstd, scale, bias, z2d, relu, use_pallas,
+def _epilogue_fwd(x, mean, invstd, scale, bias, z, relu, use_pallas,
                   interpret, row_block):
-    out = _epilogue(x2d, mean, invstd, scale, bias, z2d, relu, use_pallas,
+    out = _epilogue(x, mean, invstd, scale, bias, z, relu, use_pallas,
                     interpret, row_block)
-    return out, (x2d, mean, invstd, scale, bias, z2d)
+    return out, (x, mean, invstd, scale, bias, z)
 
 
 def _epilogue_bwd(relu, use_pallas, interpret, row_block, res, g):
-    x2d, mean, invstd, scale, bias, z2d = res
+    x, mean, invstd, scale, bias, z = res
+    dx, d_mean, d_invstd, d_scale, d_bias, dz = _bwd_ref(
+        g, x, mean, invstd, scale, bias, z, relu)
     if use_pallas:
-        dx, dz = _pallas_bwd(g, x2d, mean, invstd, scale, bias, z2d, relu,
+        # the activation-sized pair comes from the kernel; the
+        # per-channel column sums above stay jnp
+        dx, dz = _pallas_bwd(g, x, mean, invstd, scale, bias, z, relu,
                              interpret, row_block)
-        # Per-channel reductions recompute the relu mask in jnp — column
-        # sums XLA fuses with the kernel's outputs; the activation-sized
-        # work stayed in the Pallas pass.
-        _, d_mean, d_invstd, d_scale, d_bias, _ = _bwd_ref(
-            g, x2d, mean, invstd, scale, bias, z2d, relu)
-    else:
-        dx, d_mean, d_invstd, d_scale, d_bias, dz = _bwd_ref(
-            g, x2d, mean, invstd, scale, bias, z2d, relu)
-    # mean/invstd cotangents keep their input shapes ([1, C] rows here).
-    d_mean = jnp.reshape(d_mean, jnp.shape(mean)).astype(
-        jnp.asarray(mean).dtype)
-    d_invstd = jnp.reshape(d_invstd, jnp.shape(invstd)).astype(
-        jnp.asarray(invstd).dtype)
-    if scale is not None:
-        d_scale = jnp.reshape(d_scale, jnp.shape(scale)).astype(
-            jnp.asarray(scale).dtype)
-        d_bias = jnp.reshape(d_bias, jnp.shape(bias)).astype(
-            jnp.asarray(bias).dtype)
     # Per-channel operands are usually replicated over a data axis the
     # activations are sharded on: their column sums are per-shard here
     # and must arrive summed (see pallas_compat.match_vma).
@@ -323,7 +313,7 @@ def bn_relu_residual(x, mean, invstd, scale=None, bias=None, z=None,
                      relu=True, impl: Optional[str] = None,
                      interpret: bool = False,
                      row_block: Optional[int] = None):
-    """Fused BN epilogue: ``relu((x - mean) * invstd * scale + bias + z)``.
+    """BN epilogue: ``relu((x - mean) * invstd * scale + bias + z)``.
 
     ``x`` is channels-last (``[..., C]``); ``mean``/``invstd`` and the
     optional affine ``scale``/``bias`` are per-channel ``[C]`` (or any
@@ -332,9 +322,11 @@ def bn_relu_residual(x, mean, invstd, scale=None, bias=None, z=None,
     BEFORE the ReLU (the apex ``bn_add_relu`` contract).  Returns
     ``x.dtype``; all arithmetic accumulates in fp32.
 
-    ``impl``: ``None`` picks pallas-vs-jnp by size (pallas only on TPU);
-    ``"pallas"``/``"jnp"`` force a path.  ``interpret=True`` runs the
-    Pallas kernel in interpreter mode (CPU tier-parity tests).
+    ``impl``: ``None`` and ``"jnp"`` run the XLA implementation on ``x``
+    as it is (no reshape: the compiler fuses it with its neighbours);
+    ``"pallas"`` forces the Mosaic kernel on the TPU, over ``x``
+    reshaped to ``[rows, C]``.  ``interpret=True`` runs that kernel in
+    interpreter mode (CPU tier-parity tests).
 
     Differentiable in ``x``, ``mean``, ``invstd``, ``scale``, ``bias``
     and ``z`` — statistics computed outside (XLA reductions, psums for
@@ -346,26 +338,26 @@ def bn_relu_residual(x, mean, invstd, scale=None, bias=None, z=None,
     the hard-coded 256-row default as the fallback.
     """
     c = x.shape[-1]
-    n_rows = 1
-    for s in x.shape[:-1]:
-        n_rows *= s
-    x2d = x.reshape(n_rows, c)
-    z2d = z.reshape(n_rows, c) if z is not None else None
     mean = jnp.ravel(jnp.asarray(mean, jnp.float32))
     invstd = jnp.ravel(jnp.asarray(invstd, jnp.float32))
     if scale is not None:
         scale = jnp.ravel(jnp.asarray(scale, jnp.float32))
         bias = jnp.ravel(jnp.asarray(bias, jnp.float32))
-    isz = jnp.dtype(x2d.dtype).itemsize
-    use_pallas = _dispatch_pallas(n_rows, c, impl, isz)
+    isz = jnp.dtype(x.dtype).itemsize
+    use_pallas = _dispatch_pallas(c, impl, isz)
     if interpret and impl != "jnp":
         use_pallas = True
-    if use_pallas and row_block is None:
+    if not use_pallas:
+        return _epilogue(x, mean, invstd, scale, bias, z, bool(relu),
+                         False, False, None)
+    n_rows = x.size // c
+    if row_block is None:
         cfg = _tuned_config("bn_relu_residual", TUNE_VERSION,
                             tune_bucket(n_rows, c, isz, z is not None),
                             params=("row_block",))
         if cfg:
             row_block = cfg["row_block"]
-    out = _epilogue(x2d, mean, invstd, scale, bias, z2d, bool(relu),
-                    use_pallas, bool(interpret), row_block)
+    out = _epilogue(x.reshape(n_rows, c), mean, invstd, scale, bias,
+                    z.reshape(n_rows, c) if z is not None else None,
+                    bool(relu), True, bool(interpret), row_block)
     return out.reshape(x.shape)

@@ -1,15 +1,23 @@
 """NHWC implicit-GEMM Pallas convolution with fused BN/ReLU/residual
 epilogue (ISSUE 18).
 
-The r05 roofline ledger puts ResNet-50 amp O2 at ~26% MFU with the conv
-path owned end to end by XLA; the stage1/stage2 convs are *memory*-bound
-(~0.77-0.93 GB per region for only 39-158 GFLOPs).  This module is the
-TPU-native analog of the implicit-GEMM formulation cuDNN uses for the
-reference's NVIDIA convs: the im2col tile is materialized **in VMEM
-only** — never in HBM — by a static shift-and-matmul tap loop, and the
-:func:`apex_tpu.normalization.bn_relu_residual` epilogue is fused into
-the forward kernel's epilogue so a ``conv -> bn -> relu (+residual)``
-chain costs one HBM round-trip per block instead of three.
+**Nothing selects these kernels automatically.**  Measured on the v5e
+inside the ResNet-50 amp-O2 step (``PERF.md`` section 6, PR 26), every
+stride-1 site ran 18 to 23 times longer than the roofline of its shape,
+and each custom call cost a ``jnp.pad``, a layout copy and the fusions
+it fenced; XLA's own convolution is faster at every ResNet-50 shape.
+``impl=None`` therefore runs ``lax.conv_general_dilated`` (with the
+padding in its ``padding`` argument, never a ``jnp.pad``), and
+:class:`PallasConv` runs at every site the XLA conv ``nn.Conv`` runs.
+``impl="pallas"`` (on the TPU) and ``interpret=True`` (anywhere) still
+run the kernels below: the tests, the tuner and ``chip_smoke.py``'s
+kernel sweep do.
+
+The kernels are the TPU analog of the implicit-GEMM formulation cuDNN
+uses for the reference's NVIDIA convs: the im2col tile is materialized
+**in VMEM only** — never in HBM — by a static shift-and-matmul tap loop,
+with the :func:`apex_tpu.normalization.bn_relu_residual` epilogue in
+the forward kernel's epilogue.
 
 Kernel scheme (forward)
     grid ``(N, ceil(O/block_n), ceil(OH/boh))`` — the innermost axis
@@ -47,12 +55,12 @@ Contract (the repo kernel contract, ISSUE 7/14):
   with the hard-coded defaults as the zero-cost fallback.  Block
   partitioning never reorders a single output element's tap/K reduction,
   so tuned configs match the default BITWISE (``exact=True``).
-* Shapes the kernel cannot serve — grouped/depthwise convs, strided
-  convs on the compiled path (:func:`_mosaic_accepts`), blocks that
-  cannot fit scoped VMEM (e.g. the C=3 stem conv, whose lane-padded
-  image block alone overflows), sub-crossover sizes — fall back to XLA
-  per call site; :class:`PallasConv` counts them in
-  :func:`conv_dispatch_stats` so coverage loss is visible.
+* Shapes the kernel cannot serve even when forced — grouped/depthwise
+  convs, strided convs on the compiled path (:func:`_mosaic_accepts`),
+  blocks that cannot fit scoped VMEM (e.g. the C=3 stem conv, whose
+  lane-padded image block alone overflows) — run XLA's conv;
+  :class:`PallasConv` counts every site and why it runs XLA's conv in
+  :func:`conv_dispatch_stats`.
 """
 
 from __future__ import annotations
@@ -87,11 +95,6 @@ TUNE_VERSION = 1
 #: output-channel tile — the zero-cost fallback the tune cache refines.
 _DEFAULT_BLOCK_M = 512
 _DEFAULT_BLOCK_N = 256
-
-# In-context crossover, the fused_bn_act lesson: below a few million
-# output elements the custom call is a fusion barrier that costs more
-# than the saved HBM sweeps.
-_JNP_MAX_ELEMENTS = 2 * 1024 * 1024
 
 _DN_NHWC = ("NHWC", "HWIO", "NHWC")
 
@@ -535,15 +538,17 @@ def _mosaic_accepts(stride) -> bool:
     return tuple(stride) == (1, 1)
 
 
-def _dispatch_pallas(impl: Optional[str], n_out: int, fits: bool) -> bool:
+def _dispatch_pallas(impl: Optional[str], fits: bool) -> bool:
+    """True when the Mosaic kernels are what runs.
+
+    The automatic choice (``impl=None``) is XLA's conv at every shape:
+    inside the ResNet-50 amp-O2 step on the v5e no site's kernel won
+    (``PERF.md`` section 6, PR 26).  A class of shapes a later
+    measurement earns goes here, as a predicate on the site's shape."""
     if impl not in (None, "pallas", "jnp"):
         raise ValueError(
             f"impl must be None, 'pallas', or 'jnp'; got {impl!r}")
-    if not _use_pallas() or not fits:
-        return False
-    if impl is not None:
-        return impl == "pallas"
-    return n_out >= _JNP_MAX_ELEMENTS
+    return impl == "pallas" and fits and _use_pallas()
 
 
 def conv2d(x, w, *, stride=(1, 1), padding="SAME", dilation=(1, 1),
@@ -571,14 +576,15 @@ def conv2d(x, w, *, stride=(1, 1), padding="SAME", dilation=(1, 1),
     cotangents, and the fused path is gradient-exact vs the explicit
     ``conv2d`` → ``bn_relu_residual`` chain.
 
-    ``impl``: ``None`` picks pallas-vs-jnp by size (pallas only on TPU,
-    and only when the kernel can serve the shape — ``groups == 1`` and
-    the blocks fit scoped VMEM); ``"pallas"``/``"jnp"`` force a path.
-    ``interpret=True`` runs the real kernels in interpreter mode (CPU
-    tier-parity tests).  ``block_m`` (im2col row tile) / ``block_n``
-    (output-channel tile): explicit kernel blocks; left ``None`` the
-    per-device config cache (:mod:`apex_tpu.tune`) is consulted at
-    trace time with the hard-coded defaults as zero-cost fallback.
+    ``impl``: ``None`` and ``"jnp"`` run XLA's conv (see the module
+    docstring for the measurement); ``"pallas"`` forces the kernels on
+    the TPU where they can serve the shape (``groups == 1``, stride 1,
+    blocks that fit scoped VMEM).  ``interpret=True`` runs the real
+    kernels in interpreter mode (CPU tier-parity tests).  ``block_m``
+    (im2col row tile) / ``block_n`` (output-channel tile): explicit
+    kernel blocks; left ``None`` the per-device config cache
+    (:mod:`apex_tpu.tune`) is consulted at trace time with the
+    hard-coded defaults as zero-cost fallback.
     """
     stride, dilation = _pair(stride), _pair(dilation)
     if x.ndim != 4 or w.ndim != 4:
@@ -619,7 +625,7 @@ def conv2d(x, w, *, stride=(1, 1), padding="SAME", dilation=(1, 1),
         h, w_in, padding, cin, o, kh, kw, *stride, *dilation,
         block_m or _DEFAULT_BLOCK_M, block_n or _DEFAULT_BLOCK_N, isz,
         z is not None, epilogue)
-    use_pallas = _dispatch_pallas(impl, n * oh * ow * o, fits)
+    use_pallas = _dispatch_pallas(impl, fits)
     if interpret and impl != "jnp" and capable:
         use_pallas = True
     if use_pallas and block_m is None and block_n is None:
@@ -645,10 +651,12 @@ _FALLBACK_REASONS: Dict[str, int] = {}
 
 def conv_dispatch_stats() -> Dict[str, Any]:
     """Trace-time :class:`PallasConv` dispatch counters: how many conv
-    call sites traced the Pallas kernel vs fell back to XLA, and why
-    (``groups`` / ``rank`` / ``backend`` / ``stride`` / ``vmem`` /
-    ``small``).  A site counts as pallas only when the kernel IS what
-    was traced — off the TPU every site is a ``backend`` fallback.
+    call sites traced the Pallas kernel vs ran XLA's conv, and why
+    (``groups`` / ``rank`` / ``backend`` / ``stride`` / ``vmem``: the
+    kernel cannot serve the site; ``xla``: it can, and the automatic
+    dispatch takes XLA's conv).  A site counts as pallas only when the
+    kernel IS what was traced — off the TPU every site is a ``backend``
+    fallback.
     Counts accumulate per trace (init, apply, and grad traces each count
     their sites)."""
     return {"pallas_sites": _DISPATCH_COUNTS["pallas"],
@@ -701,15 +709,14 @@ def _site_reason(x_shape, w_shape, padding, stride, dilation,
         return "backend"
     if not _mosaic_accepts(stride):
         return "stride"
-    n, h, w_in, cin = x_shape
+    _, h, w_in, cin = x_shape
     kh, kw, _, o = w_shape
-    oh, ow = _out_hw(h, w_in, padding, kh, kw, *stride, *dilation)
     if not _fwd_fits(h, w_in, padding, cin, o, kh, kw, *stride,
                      *dilation, _DEFAULT_BLOCK_M, _DEFAULT_BLOCK_N, isz,
                      False, False):
         return "vmem"
-    if n * oh * ow * o < _JNP_MAX_ELEMENTS:
-        return "small"
+    if not _dispatch_pallas(None, True):
+        return "xla"
     return None
 
 
@@ -720,10 +727,10 @@ class PallasConv(nn.Module):
     optional ``bias``, identical initializers), so swapping it in via
     the ResNet ``conv_cls=`` hook changes no checkpoint or init — with
     the flag off (``conv_cls=None`` → ``nn.Conv``) the model is
-    bit-identical to before.  Call sites the kernel cannot serve
-    (grouped/depthwise, strided, VMEM-overflow like the C=3 stem,
-    sub-crossover sizes, any backend but the TPU) run exactly the XLA
-    conv ``nn.Conv`` runs, in the operands' dtype, and are counted in
+    bit-identical to before.  Every call site the automatic dispatch
+    does not give the kernel (today: all of them, see
+    :func:`_dispatch_pallas`) runs exactly the XLA conv ``nn.Conv``
+    runs, in the operands' dtype, and is counted with its reason in
     :func:`conv_dispatch_stats`.  ``precision`` is accepted for
     signature parity but ignored (the kernel always accumulates fp32).
     """

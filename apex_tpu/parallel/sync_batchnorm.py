@@ -150,13 +150,13 @@ class SyncBatchNorm(nn.Module):
             bias = self.param("bias", self.bias_init,
                               (num_features,), jnp.float32)
         if self.channel_last:
-            # The whole elementwise tail — normalize, affine, the
-            # optional ``z`` residual add (reference batch_norm_add_relu)
-            # and the fused ReLU — is ONE conv-side epilogue: a Pallas
-            # pass on TPU, the op-identical jnp reference elsewhere
-            # (ISSUE 7).  Statistics (the psum above, running stats)
-            # stay in XLA; the epilogue's custom VJP hands their
-            # cotangents back exactly.
+            # The elementwise tail (normalize, affine, the optional
+            # ``z`` residual add of the reference's batch_norm_add_relu,
+            # the ReLU) is ``bn_relu_residual``: plain jnp on ``x`` as it
+            # is, which XLA fuses into its neighbours, under a custom VJP
+            # that saves ``x`` and ``z`` and hands the statistics their
+            # cotangents exactly.  Its Mosaic kernel is never chosen
+            # from here (PERF.md section 6, PR 26).
             from ..normalization.fused_bn_act import bn_relu_residual
             return bn_relu_residual(x, mean, invstd, weight, bias, z=z,
                                     relu=self.fuse_relu)

@@ -16,13 +16,15 @@ One process, one TPU host, no network, nothing but this checkout:
    metric read.  On more than one device ``--sync_bn`` is added (BN
    statistics psum'd over the mesh).  Asserts finite losses, a loss scale
    that did not collapse, the step count, ZERO compiles after warm-up,
-   Pallas custom calls in the compiled step, memory in use on every
-   device and, on several devices, an all-reduce in the compiled step and
-   replicas that agree.
+   the fused-loss kernel's custom calls in the compiled step (the one
+   Mosaic kernel the step holds: its conv and BatchNorm sites run XLA's
+   own code), memory in use on every device and, on several devices, an
+   all-reduce in the compiled step and replicas that agree.
 3. **kernel sweep** — every kernel in ``apex_tpu.tune.registry.all_specs()``
-   compiled by Mosaic (``interpret=False``) at its ``example_shape`` and at
-   the shapes the ResNet-50 step dispatches, forward and backward, against
-   the jnp reference its module carries, within the spec's tolerance.
+   compiled by Mosaic (``interpret=False``, ``impl="pallas"`` forced by
+   the tuner's builders) at its ``example_shape`` and at the ResNet-50
+   step's shapes, forward and backward, against the jnp reference its
+   module carries, within the spec's tolerance.
 
 Any failure is an exception: non-zero exit, no result line.  On success the
 last line of stdout is ``{"ok": true, "device": {...}}``.  The seconds it
@@ -43,9 +45,12 @@ BATCH_PER_CHIP = 128
 IMAGE_SIZE = 224
 
 #: (h, cin, cout, k): every distinct conv site of the ResNet-50 step that
-#: ``PallasConv`` sends to the kernel — the stride-1 1x1 and 3x3 convs at
-#: output widths 56/28/14/7, bare (``epilogue: False``).  The six stride-2
-#: sites and the C=3 stem take the XLA conv (``ops.conv._mosaic_accepts``).
+#: the Mosaic conv kernel can serve — the stride-1 1x1 and 3x3 convs at
+#: output widths 56/28/14/7, bare (``epilogue: False``).  The step itself
+#: runs XLA's conv at all of them (``ops.conv._dispatch_pallas``; the six
+#: stride-2 sites and the C=3 stem the kernel cannot serve at all,
+#: ``ops.conv._mosaic_accepts``); the sweep forces the kernel here so that
+#: it keeps compiling and matching its reference.
 RESNET50_CONVS = [
     (56, 64, 64, 1), (56, 64, 64, 3), (56, 64, 256, 1), (56, 256, 64, 1),
     (56, 256, 128, 1), (28, 128, 128, 3), (28, 128, 512, 1),
@@ -54,7 +59,8 @@ RESNET50_CONVS = [
     (7, 512, 512, 3), (7, 512, 2048, 1), (7, 2048, 512, 1),
 ]
 #: (rows, channels, residual) of its BN epilogues: the stem, a stage-1
-#: bottleneck tail and the stage-4 tail.
+#: bottleneck tail and the stage-4 tail (XLA in the step, the kernel
+#: forced in the sweep, as above).
 RESNET50_BN = [
     (BATCH_PER_CHIP * 112 * 112, 64, False),
     (BATCH_PER_CHIP * 56 * 56, 256, True),
@@ -147,8 +153,8 @@ def main_path(devices):
     say(f"compiled step: {n_custom} tpu_custom_call(s), {n_allreduce} "
         f"all-reduce(s); conv_dispatch_stats={conv_dispatch_stats()}")
     assert n_custom > 0, (
-        "no tpu_custom_call in the compiled step although Pallas kernels "
-        "are default-ON for these shapes")
+        "no tpu_custom_call in the compiled step although the fused "
+        "xentropy loss, a Mosaic kernel, is default-ON")
     if n_dev > 1:
         assert n_allreduce > 0, "no all-reduce in a multi-device step"
 

@@ -90,20 +90,22 @@ def parse(argv=None):
                    default=True,
                    help="route the ResNet bn->relu->(+residual) chains "
                         "through contrib.groupbn.BatchNorm2d_NHWC (the "
-                        "apex bn_add_relu contract, backed by the Pallas "
-                        "bn_relu_residual epilogue); works with and "
-                        "without --sync_bn (ISSUE 7).  --no-fused-bn "
-                        "keeps plain nn.BatchNorm + explicit relu/add")
+                        "apex bn_add_relu contract, over normalization."
+                        "bn_relu_residual); works with and without "
+                        "--sync_bn (ISSUE 7).  --no-fused-bn keeps plain "
+                        "nn.BatchNorm + explicit relu/add.  Both are jnp "
+                        "that XLA compiles alike: no Mosaic kernel is "
+                        "chosen at a BN site (PERF.md, PR 26)")
     p.add_argument("--pallas-conv", action=argparse.BooleanOptionalAction,
                    default=True,
-                   help="route the ResNet convs through the NHWC "
-                        "implicit-GEMM Pallas kernel (apex_tpu.ops."
-                        "PallasConv via the conv_cls= hook, ISSUE 18); "
-                        "shapes the kernel can't serve (the C=3 stem, "
-                        "sub-crossover sizes) fall back to XLA per site "
-                        "and are counted in the tune: line.  "
-                        "--no-pallas-conv restores the XLA conv path "
-                        "bitwise (same params, same checkpoint)")
+                   help="build the ResNet convs from apex_tpu.ops."
+                        "PallasConv (the conv_cls= hook, ISSUE 18) "
+                        "instead of nn.Conv.  Its automatic dispatch "
+                        "takes XLA's conv at every site (the Mosaic "
+                        "kernel was slower at every ResNet-50 shape on "
+                        "the v5e: PERF.md, PR 26) and counts the sites "
+                        "in the tune: line, so both settings run the "
+                        "same XLA conv on the same params and checkpoint")
     p.add_argument("--fused-loss", action=argparse.BooleanOptionalAction,
                    default=True,
                    help="contrib.xentropy fused softmax-cross-entropy "
@@ -318,10 +320,11 @@ def build(args):
             jax.random.PRNGKey(0), x0)
 
     if args.fused_bn:
-        # Fused conv epilogues (ISSUE 7): the contrib GroupBN NHWC
-        # module carries the apex bn_relu/bn_add_relu contract, so the
-        # ResNet norm-factory hook collapses every bn->relu->(+residual)
-        # chain into ONE Pallas epilogue.  --sync_bn maps to
+        # BN epilogues through the norm (ISSUE 7): the contrib GroupBN
+        # NHWC module carries the apex bn_relu/bn_add_relu contract, so
+        # the ResNet norm-factory hook sends every bn->relu->(+residual)
+        # chain through bn_relu_residual (jnp that XLA fuses; its Mosaic
+        # kernel is not chosen: PERF.md, PR 26).  --sync_bn maps to
         # bn_group=n_dev (stats psum'd across the mesh, same contract
         # as SyncBatchNorm).  Init runs on this model directly —
         # collectives are guarded off under is_initializing() — so the

@@ -10,11 +10,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from apex_tpu.ops import conv as conv_mod
 from apex_tpu.ops.conv import (conv2d, conv2d_ref, PallasConv,
                                conv_dispatch_stats,
                                reset_conv_dispatch_stats)
 from apex_tpu.normalization.fused_bn_act import bn_act_epilogue_ref
 from apex_tpu.prof import assert_trace_count
+from test_fused_bn_act import CHIP_SMOKE
 
 
 def _mk(rs, *shape, dtype=jnp.float32):
@@ -200,7 +202,7 @@ def test_depthwise_falls_back_and_is_counted():
 
 # -- dispatch & tuning --------------------------------------------------------
 
-def test_dispatch_gates():
+def test_dispatch_gates(monkeypatch):
     x, w = jnp.ones((1, 4, 4, 8)), jnp.ones((3, 3, 8, 8))
     with pytest.raises(ValueError, match="impl"):
         conv2d(x, w, impl="bogus")
@@ -209,6 +211,42 @@ def test_dispatch_gates():
     out = conv2d(x, w, impl="pallas")
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(conv2d_ref(x, w)))
+    assert not conv_mod._dispatch_pallas("pallas", True)
+    # on the TPU the kernel runs only when forced and able
+    monkeypatch.setattr(conv_mod, "_use_pallas", lambda: True)
+    assert conv_mod._dispatch_pallas("pallas", True)
+    assert not conv_mod._dispatch_pallas("pallas", False)
+    assert not conv_mod._dispatch_pallas(None, True)
+    assert not conv_mod._dispatch_pallas("jnp", True)
+
+
+@pytest.mark.parametrize("h,cin,cout,k", CHIP_SMOKE.RESNET50_CONVS)
+def test_resnet50_site_takes_xla_unless_forced(h, cin, cout, k, monkeypatch):
+    """Every stride-1 conv site of the ResNet-50 step at b256, bf16, as
+    if on the TPU.  The kernel can serve each (no capability gate
+    answers first), the automatic dispatch still takes XLA's conv with
+    the padding in the conv and no ``pad`` before it, and
+    ``impl="pallas"`` is still the kernel (PERF.md section 6, PR 26)."""
+    monkeypatch.setattr(conv_mod, "_use_pallas", lambda: True)
+    x_shape, w_shape = (256, h, h, cin), (k, k, cin, cout)
+    padding = conv_mod._norm_padding("SAME", h, h, k, k, 1, 1, 1, 1)
+    assert conv_mod._site_reason(x_shape, w_shape, padding, (1, 1), (1, 1),
+                                 1, 2) == "xla"
+
+    x = jax.ShapeDtypeStruct(x_shape, jnp.bfloat16)
+    w = jax.ShapeDtypeStruct(w_shape, jnp.bfloat16)
+    reset_conv_dispatch_stats()
+    module = PallasConv(features=cout, kernel_size=(k, k), use_bias=False,
+                        dtype=jnp.bfloat16)
+    auto = jax.make_jaxpr(
+        lambda x, w: module.apply({"params": {"kernel": w}}, x))(x, w)
+    assert conv_dispatch_stats()["fallback_reasons"] == {"xla": 1}
+    reset_conv_dispatch_stats()
+    assert [e.primitive.name for e in auto.jaxpr.eqns] == [
+        "conv_general_dilated"]         # no pad, no cast: as nn.Conv runs it
+    forced = str(jax.make_jaxpr(
+        lambda x, w: conv2d(x, w, impl="pallas"))(x, w))
+    assert "pallas_call" in forced
 
 
 def test_tuned_blocks_match_default_bitwise():

@@ -1,10 +1,14 @@
-"""Fused conv-side BN epilogue tests (ISSUE 7): bn_relu_residual kernel
-parity (interpret mode vs the jnp reference), custom-VJP exactness
-through full-BN autodiff, the SyncBatchNorm tail routing, and the
-ResNet norm-factory hook's fused-vs-explicit block equivalence.
+"""BN epilogue tests (ISSUE 7, ISSUE 26): bn_relu_residual kernel parity
+(interpret mode vs the jnp reference), the NHWC XLA implementation's
+parity with both, the automatic dispatch (XLA at every ResNet-50 site,
+the kernel only when forced), custom-VJP exactness through full-BN
+autodiff, the SyncBatchNorm tail routing, and the ResNet norm-factory
+hook's fused-vs-explicit block equivalence.
 """
 
 import functools
+import importlib.util
+import os
 
 import flax.linen as nn
 import jax
@@ -12,10 +16,35 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from apex_tpu.normalization import fused_bn_act
 from apex_tpu.normalization.fused_bn_act import (_dispatch_pallas,
                                                  _kernel_fits,
                                                  bn_act_epilogue_ref,
                                                  bn_relu_residual)
+
+
+def load_chip_smoke():
+    """``chip_smoke.py`` of this checkout as a module (its import touches
+    neither JAX nor the chip): the lists of ResNet-50 sites live there."""
+    path = os.path.join(os.path.dirname(__file__), os.pardir,
+                        "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def activation_sized(jaxpr, names, size):
+    """Equations of ``jaxpr`` (sub-jaxprs included) whose primitive is in
+    ``names`` and that read an operand of at least ``size`` elements."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in names and any(
+                getattr(v.aval, "size", 0) >= size for v in eqn.invars):
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += activation_sized(sub, names, size)
+    return found
 
 
 def _operands(c=8, dtype=jnp.float32, seed=0):
@@ -108,14 +137,106 @@ def test_sync_batchnorm_tail_routes_through_epilogue():
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5)
 
 
-def test_dispatch_gates():
-    """Off-TPU the dispatch always takes jnp; the width gate keeps
-    blocks whose 8-row floor exceeds scoped VMEM off the kernel."""
-    assert not _dispatch_pallas(10 ** 6, 256, None, 4)   # no TPU backend
+def test_dispatch_gates(monkeypatch):
+    """Off-TPU the dispatch always takes jnp, forced or not; on the TPU
+    the kernel runs only when forced, and the width gate keeps blocks
+    whose 8-row floor exceeds scoped VMEM off it even then."""
+    assert not _dispatch_pallas(256, None, 4)            # no TPU backend
+    assert not _dispatch_pallas(256, "pallas", 4)
     with pytest.raises(ValueError, match="impl"):
-        _dispatch_pallas(8, 8, "mosaic", 4)
+        _dispatch_pallas(8, "mosaic", 4)
     assert _kernel_fits(256, 4)
     assert not _kernel_fits(10 ** 6, 4)                  # 8-row floor OOM
+    monkeypatch.setattr(fused_bn_act, "_use_pallas", lambda: True)
+    assert _dispatch_pallas(256, "pallas", 4)
+    assert not _dispatch_pallas(256, "jnp", 4)
+    assert not _dispatch_pallas(10 ** 6, "pallas", 4)
+
+
+CHIP_SMOKE = load_chip_smoke()
+
+
+@pytest.mark.parametrize("rows,c,has_z", CHIP_SMOKE.RESNET50_BN)
+def test_resnet50_site_takes_xla_unless_forced(rows, c, has_z, monkeypatch):
+    """Every BN site of the ResNet-50 step at b256 (chip_smoke lists
+    them by their rows at its own batch), bf16, as if on the TPU: the
+    automatic choice is XLA, ``impl="pallas"`` is still the kernel
+    (PERF.md section 6, PR 26)."""
+    monkeypatch.setattr(fused_bn_act, "_use_pallas", lambda: True)
+    assert not _dispatch_pallas(c, None, 2)
+    assert _dispatch_pallas(c, "pallas", 2)
+    hw = int(round((rows // CHIP_SMOKE.BATCH_PER_CHIP) ** 0.5))
+    x = jax.ShapeDtypeStruct((256, hw, hw, c), jnp.bfloat16)
+    vec = jax.ShapeDtypeStruct((c,), jnp.float32)
+
+    def site(impl, x, mean, invstd, scale, bias):
+        return bn_relu_residual(x, mean, invstd, scale, bias,
+                                z=x if has_z else None, impl=impl)
+
+    auto = str(jax.make_jaxpr(functools.partial(site, None))(
+        x, vec, vec, vec, vec))
+    forced = str(jax.make_jaxpr(functools.partial(site, "pallas"))(
+        x, vec, vec, vec, vec))
+    assert "pallas_call" not in auto and "pallas_call" in forced
+
+
+@pytest.mark.parametrize("with_z", [True, False])
+def test_xla_side_issues_no_activation_sized_reshape_or_pad(with_z,
+                                                            monkeypatch):
+    """W = 28 is no multiple of the bf16 sublane tile, so on the TPU an
+    ``[N,H,W,C] -> [rows,C]`` reshape is a physical copy: the automatic
+    path, forward and backward, must stay on the NHWC array."""
+    monkeypatch.setattr(fused_bn_act, "_use_pallas", lambda: True)
+    x = jnp.ones((2, 28, 28, 16), jnp.bfloat16)
+    vec = jnp.ones((16,), jnp.float32)
+
+    def loss(x, mean, invstd, scale, bias, z):
+        out = bn_relu_residual(x, mean, invstd, scale, bias,
+                               z=z if with_z else None)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    closed = jax.make_jaxpr(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4, 5)))(x, vec, vec, vec, vec, x)
+    assert "pallas_call" not in str(closed)
+    assert not activation_sized(closed.jaxpr, ("reshape", "pad"), x.size)
+    # the check can see one: the forced kernel reshapes to [rows, C]
+    forced = jax.make_jaxpr(lambda x: bn_relu_residual(
+        x, vec, vec, vec, vec, impl="pallas"))(x)
+    assert activation_sized(forced.jaxpr, ("reshape",), x.size)
+
+
+@pytest.mark.parametrize("with_z", [True, False])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_nhwc_xla_side_matches_reference_and_kernel(with_z, dtype):
+    """Value and the six cotangents of the NHWC jnp epilogue (custom
+    VJP, two reductions) against plain autodiff of
+    ``bn_act_epilogue_ref`` and against the interpreted kernel."""
+    x, z, mean, invstd, w, b = _operands(c=16, dtype=dtype, seed=6)
+    zz = z if with_z else None
+    nargs = 6 if with_z else 5
+
+    def loss(fn, xx, mm, ii, ww, bb, z_=None):
+        out = fn(xx, mm, ii, ww, bb, z=z_, relu=True)
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+
+    args = (x, mean, invstd, w, b) + ((zz,) if with_z else ())
+    sides = {
+        "xla": bn_relu_residual,
+        "ref": bn_act_epilogue_ref,
+        "kernel": functools.partial(bn_relu_residual, interpret=True),
+    }
+    got = {name: jax.value_and_grad(functools.partial(loss, fn),
+                                    argnums=tuple(range(nargs)))(*args)
+           for name, fn in sides.items()}
+    tol = 5e-2 if dtype == jnp.bfloat16 else 1e-4
+    for other in ("ref", "kernel"):
+        np.testing.assert_allclose(float(got["xla"][0]),
+                                   float(got[other][0]), rtol=tol)
+        for a, r in zip(got["xla"][1], got[other][1]):
+            assert a.shape == r.shape and a.dtype == r.dtype
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(r, np.float32),
+                                       atol=tol, rtol=tol, err_msg=other)
 
 
 def _tiny_resnet(fused_epilogue):

@@ -52,6 +52,14 @@ def _bn(kw):
     return loss, params, (_rand(0, ROWS, C), _rand(5, ROWS, C))
 
 
+def _bn_nhwc(kw):
+    """The BN epilogue as a ResNet site calls it: a 4-D activation
+    sharded on its batch axis.  The XLA side works on it as it is
+    (reductions over axes (0, 1, 2)); only the kernel side reshapes."""
+    loss2d, params, _ = _bn(kw)
+    return loss2d, params, (_rand(0, 4, 6, 6, C), _rand(5, 4, 6, 6, C))
+
+
 def _qmm(kw):
     def loss(p, x):
         out = quantized_matmul(x, p["w"], x_scale=4.0 / 127.0, **kw)
@@ -73,7 +81,7 @@ def _conv(kw):
 
 
 CASES = pytest.mark.parametrize(
-    "case", [_layer_norm, _bn, _qmm, _conv],
+    "case", [_layer_norm, _bn, _bn_nhwc, _qmm, _conv],
     ids=lambda f: f.__name__.strip("_"))
 
 
@@ -111,6 +119,27 @@ def test_kernel_path_types_check_under_vma(case, monkeypatch):
     loss, params, x = case({"impl": "pallas"})
     text = str(jax.make_jaxpr(_sharded_grad(loss))(params, x))
     assert "pallas_call" in text and "psum" in text
+
+
+@pytest.mark.parametrize("case", [_bn_nhwc, _conv],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_automatic_dispatch_takes_xla_under_shard_map(case, monkeypatch):
+    """``impl=None`` as if on the TPU, under the mesh: no kernel is
+    traced, and the replicated parameters' gradients arrive summed and
+    equal to the single-device ones (ISSUE 26)."""
+    import importlib
+    for mod in ("normalization.fused_bn_act", "ops.conv"):
+        monkeypatch.setattr(importlib.import_module("apex_tpu." + mod),
+                            "_use_pallas", lambda: True)
+    loss, params, x = case({})
+    assert "pallas_call" not in str(
+        jax.make_jaxpr(_sharded_grad(loss))(params, x))
+    got = jax.jit(_sharded_grad(loss))(params, x)
+    want = jax.grad(loss)(params, x)
+    for name in params:
+        np.testing.assert_allclose(np.asarray(got[name]),
+                                   np.asarray(want[name]),
+                                   rtol=2e-5, atol=2e-5, err_msg=name)
 
 
 def test_imagenet_step_default_flags_lowers_on_mesh(tmp_path):
