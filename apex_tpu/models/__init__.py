@@ -5,3 +5,4 @@ from .resnet import (ResNet, ResNet18, ResNet34, ResNet50,  # noqa: F401
 from .bert import BertEncoder, bert_base, bert_tiny         # noqa: F401
 from .dcgan import Generator, Discriminator                 # noqa: F401
 from .gpt import GPT, gpt2_small, gpt_tiny, init_cache      # noqa: F401
+from .granite_hybrid import GraniteHybrid, granite_hybrid_tiny  # noqa: F401

@@ -6,7 +6,10 @@ fused label-smoothing xentropy loss, FusedAdam with the BERT-style
 no-decay-on-bias/LayerNorm parameter groups, and the fully-jitted amp
 train step.  With ``--sp N`` the sequence is sharded over an ``sp`` mesh
 axis and attention runs as ring attention (``--attention ring`` or
-``ring_flash``).
+``ring_flash``).  ``--model granite-hybrid`` trains the Granite-4.0-H block
+instead (``apex_tpu.models.GraniteHybrid``: Mamba-2 state-space layers
+beside GQA attention layers in the published period of ten, SwiGLU, RMSNorm)
+through the same loss, train step and pipeline.
 
 The loop runs on :class:`apex_tpu.runtime.StepPipeline`:
 ``--steps-per-call K`` chains K steps into ONE compiled program
@@ -18,6 +21,7 @@ never blocks on a scalar.
     python main_amp.py --synthetic --steps 5 --seq-len 256 --opt-level O2
     python main_amp.py --synthetic --steps 32 --steps-per-call 8
     python main_amp.py --synthetic --steps 2 --sp 2 --attention ring
+    python main_amp.py --synthetic --steps 5 --model granite-hybrid --layers 10
 """
 
 import os as _os
@@ -38,13 +42,19 @@ import numpy as np
 
 from apex_tpu import runtime, training
 from apex_tpu.contrib.xentropy import softmax_cross_entropy_loss
-from apex_tpu.models import GPT
+from apex_tpu.models import GPT, GraniteHybrid, granite_hybrid
 from apex_tpu.training import make_train_step
 
 
 def parse():
     p = argparse.ArgumentParser()
     p.add_argument("--synthetic", action="store_true")
+    p.add_argument("--model", type=str, default="gpt",
+                   choices=["gpt", "granite-hybrid"],
+                   help="gpt: GPT-2 blocks; granite-hybrid: Mamba-2 and GQA "
+                        "attention layers by the published period (five "
+                        "mamba, attention, four mamba), heads of "
+                        "hidden/heads for both kinds of layer")
     p.add_argument("--steps", type=int, default=10)
     p.add_argument("-b", "--batch-size", type=int, default=8)
     p.add_argument("--seq-len", type=int, default=256)
@@ -189,12 +199,26 @@ def _train(args):
             "flash", "blockwise", "full"):
         raise SystemExit("--kv-heads needs --attention flash/blockwise/full "
                          "(GQA is shard-local; ring/ulysses paths are MHA)")
-    model = GPT(vocab_size=args.vocab, hidden_size=args.hidden,
-                num_layers=args.layers, num_heads=args.heads,
-                mlp_dim=4 * args.hidden, max_len=args.seq_len,
-                dtype=jnp.bfloat16, attention_impl=args.attention,
-                num_kv_heads=args.kv_heads, window=args.window,
-                sp_axis="sp" if sp > 1 else None)
+    hybrid = args.model == "granite-hybrid"
+    if hybrid:
+        if sp > 1 or args.window is not None or args.attention != "flash":
+            raise SystemExit("--model granite-hybrid runs unsharded with "
+                             "--attention flash and no --window")
+        period = granite_hybrid.PERIOD
+        model = GraniteHybrid(
+            vocab_size=args.vocab, hidden_size=args.hidden,
+            layer_types=tuple(period[i % len(period)]
+                              for i in range(args.layers)),
+            num_heads=args.heads, num_kv_heads=args.kv_heads or args.heads,
+            mlp_dim=4 * args.hidden, mamba_heads=2 * args.heads,
+            mamba_head_dim=args.hidden // args.heads, dtype=jnp.bfloat16)
+    else:
+        model = GPT(vocab_size=args.vocab, hidden_size=args.hidden,
+                    num_layers=args.layers, num_heads=args.heads,
+                    mlp_dim=4 * args.hidden, max_len=args.seq_len,
+                    dtype=jnp.bfloat16, attention_impl=args.attention,
+                    num_kv_heads=args.kv_heads, window=args.window,
+                    sp_axis="sp" if sp > 1 else None)
     # Same architecture without the sp axis for (replicated) init.
     init_model = model if sp == 1 else model.clone(attention_impl="full",
                                                    sp_axis=None)
@@ -212,7 +236,8 @@ def _train(args):
     params = init_model.init(jax.random.PRNGKey(0), ids[:1, :8])["params"]
     n_params = sum(int(np.prod(l.shape)) for l in
                    jax.tree_util.tree_leaves(params))
-    print(f"GPT {args.layers}L/{args.hidden}H  {n_params/1e6:.1f}M params  "
+    print(f"{type(model).__name__} {args.layers}L/{args.hidden}H  "
+          f"{n_params/1e6:.1f}M params  "
           f"attention={args.attention}  opt_level = {args.opt_level}")
 
     def loss_fn(p, batch):
@@ -239,7 +264,9 @@ def _train(args):
     init_fn, step_fn = make_train_step(
         loss_fn, training.adam(args.lr, weight_decay=args.weight_decay),
         opt_level=args.opt_level, loss_scale=loss_scale,
-        axis_name="sp" if sp > 1 else None)
+        axis_name="sp" if sp > 1 else None,
+        # O2 keeps the mixer's A_log, dt_bias and D float32 beside the norms
+        norm_predicate=granite_hybrid.keep_fp32 if hybrid else None)
     state = init_fn(params)
 
     spc = max(1, args.steps_per_call)

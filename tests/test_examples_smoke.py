@@ -194,6 +194,21 @@ def test_lm_example(monkeypatch, capsys):
     assert "opt_level = O2" in out
 
 
+def test_lm_example_granite_hybrid(monkeypatch, capsys):
+    """The same loop over the Mamba-2/attention hybrid: eight layers are
+    five mamba, one attention, two mamba."""
+    _run_example(monkeypatch, "examples/lm/main_amp.py", [
+        "--synthetic", "--steps", "2", "-b", "2", "--seq-len", "33",
+        "--hidden", "32", "--layers", "8", "--heads", "2", "--kv-heads", "1",
+        "--vocab", "128", "--opt-level", "O2", "--loss-scale", "dynamic",
+        "--model", "granite-hybrid"])
+    out = capsys.readouterr().out
+    assert "GraniteHybrid 8L/32H" in out and "loss_scale 65536" in out
+    with pytest.raises(SystemExit, match="granite-hybrid runs unsharded"):
+        _run_example(monkeypatch, "examples/lm/main_amp.py", [
+            "--synthetic", "--model", "granite-hybrid", "--window", "8"])
+
+
 def test_lm_example_sequence_parallel(monkeypatch):
     """GPT over a 2-way sp mesh with ring attention."""
     _run_example(monkeypatch, "examples/lm/main_amp.py", [
