@@ -13,11 +13,17 @@ attention and logit multipliers::
 
 Mamba-2 mixer: ``[z | xBC | dt] = in_proj(x)``; ``xBC = silu(causal
 depthwise conv(xBC) + b)``; ``[x | B | C] = split(xBC)``; ``dt = softplus(dt
-+ dt_bias)``; ``A = -exp(A_log)``; ``y = ssd_scan(x, dt, A, B, C, D)``
++ dt_bias)``; ``A = -exp(A_log)``; ``y = ssd(x, dt, A, B, C, D)``
 (:mod:`apex_tpu.ops.ssd`); ``y = RMSNorm(y * silu(z))`` over all of
-``d_inner``; ``out_proj(y)``.  Attention: q, k, v, o without bias, causal,
-no positional encoding, scores scaled by ``m_a`` through
-``ops.flash_attention`` and its shape dispatch.
+``d_inner``; ``out_proj(y)``.  Between its two projections the mixer keeps
+every array as ``[batch, chunk, channels, tokens of the chunk]``, which is
+what the scan's products read and write: the conv reads a chunk's first
+tokens from the chunk before, nothing is re-tiled, and what crosses HBM is
+in the compute dtype (float32 between a load and a store; ``PERF.md``, PR
+28).  The norm's factor of a token is applied to ``out_proj``'s float32 sums,
+where it commutes.  Attention: q, k, v, o without bias, causal, no
+positional encoding, scores scaled by ``m_a`` through ``ops.flash_attention``
+and its shape dispatch.
 
 Every layer is a ``jax.checkpoint`` that saves its input only: at the
 published widths a training step does not fit one chip otherwise.  bf16
@@ -25,22 +31,23 @@ matmuls with float32 norms, decays, softmax statistics and loss.
 
 Named scopes (metadata, like ``training.PHASE_SCOPES``): ``apex.ssm``
 around the whole mixer, and inside it ``apex.ssm.conv``, ``apex.ssm.scan``
-and ``apex.ssm.norm``.
+and ``apex.ssm.norm``; the backward rules of the conv and of the scan's
+products inherit the scope they were called under.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any, Sequence
+from typing import Any, Callable, Sequence
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from ..amp.policy import default_norm_predicate
-from ..normalization import RMSNorm
+from ..normalization import RMSNorm, gated_rms_norm_factors
 from ..ops.flash_attention import flash_attention
-from ..ops.ssd import ssd_scan
+from ..ops.ssd import causal_conv_silu, ssd_chunked
 
 #: the scopes of the Mamba-2 mixer, outermost first
 SSM_SCOPES = ("apex.ssm", "apex.ssm.conv", "apex.ssm.scan", "apex.ssm.norm")
@@ -81,6 +88,21 @@ def _dense(features, dtype, name):
                            kernel_init=_dense_init, name=name)
 
 
+class _Leaf(nn.Module):
+    """One float32 parameter under a sub-module's name.  The tree keeps
+    ``nn.Dense``'s ``kernel`` and ``RMSNorm``'s ``scale`` where the references
+    and the checkpoints read them (same names, shapes and initialisation),
+    while the mixer writes the products around them itself."""
+    leaf: str
+    initializer: Callable
+    shape: Sequence[int]
+
+    @nn.compact
+    def __call__(self):
+        return self.param(self.leaf, self.initializer, tuple(self.shape),
+                          jnp.float32)
+
+
 class Mamba2Mixer(nn.Module):
     num_heads: int = 64
     head_dim: int = 64
@@ -95,22 +117,31 @@ class Mamba2Mixer(nn.Module):
     def __call__(self, x):
         h, p, n, g = (self.num_heads, self.head_dim, self.state_size,
                       self.n_groups)
-        d_inner, t = h * p, x.shape[1]
+        d_inner = h * p
         d_conv = d_inner + 2 * g * n
+        b, t, d = x.shape
+        q = min(self.chunk_size, t)
+        tail = -t % q
+        lead = (b, (t + tail) // q)
         with jax.named_scope(_SSM):
-            zxbcdt = _dense(d_inner + d_conv + h, self.dtype, "in_proj")(x)
-            z, xbc, dt = jnp.split(zxbcdt, [d_inner, d_inner + d_conv], axis=-1)
+            # From here to out_proj every array is [batch, chunk, channels,
+            # tokens of the chunk]: what the scan's products read and write,
+            # so nothing in between is re-tiled.  Padded tokens come last
+            # and the mixer is causal: they reach no output that is kept.
+            if tail:
+                x = jnp.pad(x, ((0, 0), (0, tail), (0, 0)))
+            x = x.reshape(lead + (q, d)).astype(self.dtype)
+            w_in = _Leaf("kernel", _dense_init, (d, d_inner + d_conv + h),
+                         name="in_proj")().astype(self.dtype)
+            z, xbc, dt = jnp.split(jnp.einsum("bcqd,de->bceq", x, w_in),
+                                   [d_inner, d_inner + d_conv], axis=2)
             with jax.named_scope(_SSM_CONV):
                 taps = self.param("conv_kernel", _conv_init,
                                   (self.conv_width, d_conv), jnp.float32)
                 bias = self.param("conv_bias", nn.initializers.zeros,
                                   (d_conv,), jnp.float32)
-                padded = jnp.pad(xbc.astype(jnp.float32),
-                                 ((0, 0), (self.conv_width - 1, 0), (0, 0)))
-                conv = sum(padded[:, k:k + t] * taps[k].astype(jnp.float32)
-                           for k in range(self.conv_width))
-                xbc = jax.nn.silu(conv + bias.astype(jnp.float32)).astype(
-                    xbc.dtype)
+                xbc = causal_conv_silu(xbc, taps.astype(jnp.float32),
+                                       bias.astype(jnp.float32))
             dt_bias = self.param("dt_bias", _dt_bias_init, (h,), jnp.float32)
             a_log = self.param(
                 "A_log", lambda key, shape, dtype: jnp.log(
@@ -125,19 +156,26 @@ class Mamba2Mixer(nn.Module):
                         f"amp cast rounded it: pass norm_predicate="
                         f"models.granite_hybrid.keep_fp32 to make_train_step")
             with jax.named_scope(_SSM_SCAN):
-                xs, b_, c_ = jnp.split(xbc, [d_inner, d_inner + g * n], axis=-1)
-                lead = xs.shape[:2]
-                y = ssd_scan(
-                    xs.reshape(lead + (h, p)),
-                    jax.nn.softplus(dt.astype(jnp.float32)
-                                    + dt_bias.astype(jnp.float32)),
-                    -jnp.exp(a_log.astype(jnp.float32)),
-                    b_.reshape(lead + (g, n)), c_.reshape(lead + (g, n)),
-                    skip, chunk=self.chunk_size)
+                xs, b_, c_ = jnp.split(xbc, [d_inner, d_inner + g * n], axis=2)
+                y = ssd_chunked(
+                    xs.reshape(lead + (h, p, q)),
+                    jax.nn.softplus(dt.astype(jnp.float32) + dt_bias[:, None]),
+                    -jnp.exp(a_log),
+                    b_.reshape(lead + (g, n, q)), c_.reshape(lead + (g, n, q)),
+                    skip)
             with jax.named_scope(_SSM_NORM):
-                y = RMSNorm(self.eps, name="norm")(y.reshape(lead + (d_inner,)),
-                                                   gate=z)
-            return _dense(x.shape[-1], self.dtype, "out_proj")(y)
+                scale = _Leaf("scale", nn.initializers.ones, (d_inner,),
+                              name="norm")()
+                y, inv_rms = gated_rms_norm_factors(
+                    y.reshape(lead + (d_inner, q)), z, scale, self.eps, axis=2)
+            w_out = _Leaf("kernel", _dense_init, (d_inner, d),
+                          name="out_proj")().astype(self.dtype)
+            # the norm's factor of a token commutes with the product over the
+            # channels: it scales the float32 sums, one pass over y earlier
+            out = jnp.einsum("bceq,ed->bcqd", y, w_out,
+                             preferred_element_type=jnp.float32)
+            out = (out * jnp.swapaxes(inv_rms, 2, 3)).astype(self.dtype)
+            return out.reshape(b, t + tail, d)[:, :t]
 
 
 class GQAttention(nn.Module):

@@ -32,6 +32,29 @@ def gated_rms_norm(x, z, weight=None, eps: float = 1e-5):
     return rms_norm(gated, weight, eps).astype(x.dtype)
 
 
+def gated_rms_norm_factors(x, z, weight, eps: float = 1e-5, axis: int = -1):
+    """:func:`gated_rms_norm` over ``axis`` as two factors, for a consumer
+    that is linear along that axis: ``x * silu(z) * weight`` in ``x``'s dtype,
+    and the float32 ``rsqrt(mean((x silu(z))**2) + eps)`` with ``axis`` kept.
+    A token's factor commutes with a matrix product over ``axis``, so the
+    caller scales the product's float32 sums by it: ``x`` and ``z`` are read
+    once, and what is rounded to ``x``'s dtype is the same number up to that
+    factor."""
+    # x as it arrives: XLA otherwise hoists the up-cast over the reshapes
+    # before it and writes a float32 copy of x for this fusion to read
+    x = jax.lax.optimization_barrier(x)
+    gated = x.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    inv_rms = jax.lax.rsqrt(
+        jnp.mean(gated * gated, axis=axis, keepdims=True) + eps)
+    shape = [1] * x.ndim
+    shape[axis] = x.shape[axis]
+    out = (gated * weight.astype(jnp.float32).reshape(shape)).astype(x.dtype)
+    # written once, beside the sum of squares: without the barrier XLA
+    # recomputes the gate inside the consumer's product and, for the two
+    # readers, writes a float32 copy of x
+    return jax.lax.optimization_barrier(out), inv_rms
+
+
 class RMSNorm(nn.Module):
     """``nn.RMSNorm``-semantics module; ``__call__(x, gate=None)`` applies
     :func:`gated_rms_norm` when a gate is given.  The weight is created

@@ -120,6 +120,30 @@ def test_the_mixers_scopes_reach_the_compiled_step(o2_step):
     assert "/layer_5/mamba/" not in compiled
 
 
+def test_the_backward_rules_keep_the_mixers_scopes(o2_step):
+    """The conv and the scan's products have backward rules of their own
+    (custom VJPs).  By the benchmark's readers' rule (``scopes_of`` for the
+    phase, the innermost ``apex.*`` name of the ``op_name`` for the scope)
+    their instructions still count as backward under ``apex.ssm.conv`` and
+    ``apex.ssm.scan``: the rules' own, under ``transpose(jvp(apex.forward))``,
+    and the forward recomputed for them, under ``rematted_computation``."""
+    from benchmark import phase_reduce, scope_reduce
+
+    step, state, batch = o2_step
+    hlo = step.lower(state, batch).compile().as_text()
+    phase = phase_reduce.scopes_of(hlo)
+    seen = {scope: set() for scope in granite_hybrid.SSM_SCOPES[1:3]}
+    for name, rest in phase_reduce._INSTRUCTION.findall(hlo):
+        m = phase_reduce._OP_NAME.search(rest)
+        found = scope_reduce._SCOPE.findall(m.group(1)) if m else []
+        if found and found[-1] in seen and phase[name] == "backward":
+            assert "transpose(jvp(apex.forward))" in m.group(1)
+            seen[found[-1]].add("recomputed" if "rematted_computation"
+                                in m.group(1) else "rule")
+    assert seen == {"apex.ssm.conv": {"rule", "recomputed"},
+                    "apex.ssm.scan": {"rule", "recomputed"}}
+
+
 def test_the_mixer_refuses_its_scalars_in_a_reduced_dtype():
     """An O2 cast without ``keep_fp32`` rounds ``A_log``, ``dt_bias`` and
     ``D`` to bf16: the model fails at the trace, not silently."""

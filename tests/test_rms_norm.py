@@ -63,3 +63,34 @@ def test_module_creates_a_float32_scale_of_ones():
     np.testing.assert_array_equal(
         module.apply({"params": params}, x, gate=z),
         gated_rms_norm(x, z, params["scale"], EPS))
+
+
+@pytest.mark.parametrize("axis", [-1, 1])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)])
+def test_the_gated_norms_two_factors_multiply_to_it(dtype, tol, axis):
+    """``gated_rms_norm_factors``: ``x silu(z) w`` in ``x``'s dtype and the
+    float32 factor of each row; their product is the gated norm, value and
+    gradients, over the last axis and over one in the middle."""
+    from apex_tpu.normalization import gated_rms_norm_factors
+
+    x, w, z, cot = _inputs(dtype)
+    if axis != -1:
+        x, z, cot = (jnp.moveaxis(a, -1, axis) for a in (x, z, cot))
+
+    def product(x, w, z):
+        gated, inv_rms = gated_rms_norm_factors(x, z, w, EPS, axis=axis)
+        assert gated.dtype == x.dtype and inv_rms.dtype == jnp.float32
+        assert inv_rms.shape[axis] == 1
+        return gated.astype(jnp.float32) * inv_rms
+
+    plain = lambda x, w, z: jnp.moveaxis(_plain(
+        jnp.moveaxis(x, axis, -1), w, jnp.moveaxis(z, axis, -1)), -1, axis)
+    loss = lambda f: lambda *a: jnp.sum(f(*a) * cot)
+    got = product(x, w, z)
+    want = plain(x, w, z)
+    assert float(jnp.abs(got - want).max()) < tol * float(jnp.abs(want).max())
+    for a, b in zip(jax.grad(loss(product), argnums=(0, 1, 2))(x, w, z),
+                    jax.grad(loss(plain), argnums=(0, 1, 2))(x, w, z)):
+        scale = float(jnp.abs(b.astype(jnp.float32)).max())
+        assert float(jnp.abs(a.astype(jnp.float32)
+                             - b.astype(jnp.float32)).max()) < 2 * tol * scale

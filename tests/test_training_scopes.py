@@ -14,6 +14,22 @@ from jax.sharding import Mesh, PartitionSpec as P
 from apex_tpu import training
 
 
+@pytest.fixture(autouse=True)
+def no_persistent_compile_cache():
+    """The persistent cache's key leaves metadata out, so where a test of
+    another file has enabled it in this worker (``apex_tpu.cache.enable``),
+    the step compiled without scopes reads the scoped executable back and
+    these tests fail by the order the files ran in."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
 def _loss_fn(p, batch):
     h = jnp.tanh(batch["x"].astype(p["w1"].dtype) @ p["w1"])
     return jnp.mean((h @ p["w2"]).astype(jnp.float32) ** 2)
