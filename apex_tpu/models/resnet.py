@@ -20,8 +20,7 @@ residual)`` statements.  On the TPU both are jnp that XLA fuses alike:
 measured on the v5e, a Mosaic kernel at each of these sites made the
 ResNet-50 amp-O2 step several times slower, so none is chosen
 (``PERF.md`` section 6, PR 26).  ``norm_cls`` injects an external factory
-(e.g. ``functools.partial(BatchNorm2d_NHWC, bn_group=...)``);
-``fused_epilogue`` forces the routing on (error if unsupported) or off.
+(e.g. ``functools.partial(BatchNorm2d_NHWC, bn_group=...)``).
 """
 
 from __future__ import annotations
@@ -30,7 +29,6 @@ import functools
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from ..parallel import SyncBatchNorm
@@ -75,23 +73,15 @@ class BottleneckBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        # checkpoint_name is an identity outside jax.checkpoint; under
-        # ResNet(remat="conv_out") the policy saves exactly these values
-        # and recomputes the BN/ReLU chain from them in the backward.
-        from jax.ad_checkpoint import checkpoint_name
         residual = x
         y = self.conv(self.filters, (1, 1), name="conv1")(x)
-        y = checkpoint_name(y, "conv_out")
         y = self._bn_relu(y, "bn1")
         y = self.conv(self.filters, (3, 3), self.strides, name="conv2")(y)
-        y = checkpoint_name(y, "conv_out")
         y = self._bn_relu(y, "bn2")
         y = self.conv(self.filters * 4, (1, 1), name="conv3")(y)
-        y = checkpoint_name(y, "conv_out")
         if residual.shape != y.shape:
             residual = self.conv(self.filters * 4, (1, 1), self.strides,
                                  name="downsample_conv")(residual)
-            residual = checkpoint_name(residual, "conv_out")
             residual = self.norm(name="downsample_bn")(residual)
         return self._bn_add_relu(y, residual, "bn3",
                                  scale_init=nn.initializers.zeros)
@@ -139,32 +129,11 @@ class ResNet(nn.Module):
     #: contract the blocks route their chains through it.  Overrides
     #: ``sync_bn``.
     norm_cls: Any = None
-    #: external conv factory (a module class or functools.partial over
-    #: one) mirroring ``norm_cls``, e.g. ``apex_tpu.ops.PallasConv``.
-    #: Must match the ``nn.Conv`` signature and parameter pytree so the
-    #: swap changes no checkpoint; shapes the factory cannot serve fall
-    #: back per site inside the factory itself.  None = ``nn.Conv``.
-    conv_cls: Any = None
-    #: route ``bn -> relu -> (+residual)`` chains through the norm's
-    #: fused epilogue: None = auto (fuse when the norm supports it),
-    #: True = require it (ValueError if the norm can't), False = keep
-    #: the explicit relu/add statements.
-    fused_epilogue: Optional[bool] = None
-    # Rematerialization per residual block (jax.checkpoint), an HBM-
-    # traffic experiment knob for the bandwidth-bound O2 step (~93% of
-    # HBM peak, MXU ~25% busy — r5 bytes ledger):
-    #   False      — save everything (XLA default; measured 46.9 ms dev)
-    #   "full"     — nothing_saveable: recompute whole blocks from their
-    #                inputs.  Measured WORSE (57.8 ms dev, conv traffic
-    #                28.0 -> 30.2 GB): the recompute is itself convs.
-    #   "conv_out" — save only conv outputs; recompute the BN/ReLU
-    #                elementwise chains from them in the backward.
-    remat: Any = False
 
     @nn.compact
     def __call__(self, x, train: bool = True):
-        conv = functools.partial(self.conv_cls or nn.Conv, use_bias=False,
-                                 dtype=self.dtype, param_dtype=jnp.float32)
+        conv = functools.partial(nn.Conv, use_bias=False, dtype=self.dtype,
+                                 param_dtype=jnp.float32)
         if self.norm_cls is not None:
             norm = functools.partial(self.norm_cls,
                                      use_running_average=not train)
@@ -180,16 +149,8 @@ class ResNet(nn.Module):
                 momentum=1.0 - self.bn_momentum, epsilon=1e-5,
                 dtype=self.dtype, param_dtype=jnp.float32)
 
-        fused = self.fused_epilogue
-        if fused is None:
-            fused = norm_supports_epilogue(norm)
-        elif fused and not norm_supports_epilogue(norm):
-            raise ValueError(
-                f"fused_epilogue=True but norm factory "
-                f"{_norm_factory_cls(norm).__name__} has no fuse_relu/z "
-                f"contract — use SyncBatchNorm / contrib.groupbn."
-                f"BatchNorm2d_NHWC or pass fused_epilogue=False")
-        norm_act = functools.partial(norm, fuse_relu=True) if fused else None
+        norm_act = (functools.partial(norm, fuse_relu=True)
+                    if norm_supports_epilogue(norm) else None)
 
         x = conv(self.num_filters, (7, 7), (2, 2), padding=[(3, 3), (3, 3)],
                  name="conv_init")(x)
@@ -197,28 +158,14 @@ class ResNet(nn.Module):
             x = norm_act(name="bn_init")(x)
         else:
             x = norm(name="bn_init")(x)
-            x = nn.relu(x)  # jaxlint: disable=J011 -- this IS the deliberate unfused fallback (fused_epilogue=False / plain nn.BatchNorm); the fused routing is the branch above
+            x = nn.relu(x)  # jaxlint: disable=J011 -- this IS the deliberate unfused fallback (plain nn.BatchNorm); the fused routing is the branch above
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
-        block_cls = self.block_cls
-        if self.remat:
-            # `train` reaches the block through the norm partials
-            # (closure), so the block itself takes only x.
-            if self.remat == "conv_out":
-                policy = jax.checkpoint_policies.save_only_these_names(
-                    "conv_out")
-            elif self.remat in (True, "full"):
-                policy = jax.checkpoint_policies.nothing_saveable
-            else:
-                raise ValueError(
-                    f"remat must be False, 'full', or 'conv_out'; got "
-                    f"{self.remat!r}")
-            block_cls = nn.remat(block_cls, policy=policy)
         for i, block_size in enumerate(self.stage_sizes):
             for j in range(block_size):
                 strides = (2, 2) if i > 0 and j == 0 else (1, 1)
-                x = block_cls(self.num_filters * 2 ** i, strides,
-                              conv=conv, norm=norm, norm_act=norm_act,
-                              name=f"stage{i + 1}_block{j + 1}")(x)
+                x = self.block_cls(self.num_filters * 2 ** i, strides,
+                                   conv=conv, norm=norm, norm_act=norm_act,
+                                   name=f"stage{i + 1}_block{j + 1}")(x)
         x = jnp.mean(x, axis=(1, 2))
         x = nn.Dense(self.num_classes, dtype=self.dtype,
                      param_dtype=jnp.float32, name="head")(x)

@@ -84,8 +84,8 @@ _JNP_MAX_ELEMENTS = 4 * 1024 * 1024
 # the per-element worst case: g, x, dx at the input itemsize plus four
 # fp32 row-major temporaries (3*isz + 16 B/element; see _pick_rows).
 # The math itself lives in apex_tpu.tune.space (ISSUE 14 satellite: one
-# home shared by this kernel, fused_bn_act, and the autotuner's
-# constraint checker); the module-level names stay as aliases.
+# home shared by this kernel and the autotuner's constraint checker);
+# the module-level names stay as aliases.
 _VMEM_BUDGET_BYTES = _space.VMEM_BUDGET_BYTES
 _SUBLANE_ROWS = _space.SUBLANE_ROWS
 

@@ -10,9 +10,6 @@ it shapes the sharding design (ring/Ulysses sequence parallelism in
 from .attention import (blockwise_attention, mha_attention,  # noqa: F401
                         dot_product_attention)
 from .flash_attention import flash_attention  # noqa: F401
-from .conv import (conv2d, conv2d_ref, PallasConv,  # noqa: F401
-                   conv_dispatch_stats, reset_conv_dispatch_stats,
-                   publish_conv_counters)
 from .ssd import causal_conv_silu, ssd_chunked, ssd_scan  # noqa: F401
 from . import losses  # noqa: F401
 from .losses import (binary_cross_entropy,  # noqa: F401

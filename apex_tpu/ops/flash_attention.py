@@ -83,8 +83,8 @@ _DEFAULT_BLOCK_K = 1024
 # per-launch overhead and block machinery cannot amortize (BERT seq 128:
 # 27.7% of the device step was zero-attributed custom-calls).  Measured
 # crossover on a v5e under an earlier installation (tools/
-# attention_sweep.py -> ATTENTION_SWEEP.json, 15 configs over seq x
-# head_dim x batch*heads x causal; not re-measured on this one): below
+# attention_sweep.py, 15 configs over seq x head_dim x batch*heads x
+# causal, rows in docs/attention.md; not re-measured on this one): below
 # 1024 the jnp path wins or ties within noise (e.g. causal b16 s512: jnp
 # 9.7 ms vs kernel-best 12.4); from 1024 the kernel wins decisively
 # (causal b16 s1024: 12.4 vs 21.6; s2048: 18.8 vs 47.7; 1024^2 blocks

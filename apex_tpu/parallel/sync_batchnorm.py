@@ -155,8 +155,7 @@ class SyncBatchNorm(nn.Module):
             # the ReLU) is ``bn_relu_residual``: plain jnp on ``x`` as it
             # is, which XLA fuses into its neighbours, under a custom VJP
             # that saves ``x`` and ``z`` and hands the statistics their
-            # cotangents exactly.  Its Mosaic kernel is never chosen
-            # from here (PERF.md section 6, PR 26).
+            # cotangents exactly.
             from ..normalization.fused_bn_act import bn_relu_residual
             return bn_relu_residual(x, mean, invstd, weight, bias, z=z,
                                     relu=self.fuse_relu)

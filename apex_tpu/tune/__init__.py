@@ -2,15 +2,15 @@
 
 Every Pallas kernel in the repo used to ship hand-picked block constants
 from a single v5e sweep (``_DEFAULT_BLOCK_Q/_K`` in flash attention,
-``_ROW_BLOCK`` in the normalization epilogues, ``_BLOCK_M/_N`` in the
+``_ROW_BLOCK`` in the fused LayerNorm, ``_BLOCK_M/_N`` in the
 quantized matmuls).  This package replaces those frozen sweeps with a
 measured, per-device search:
 
 * :mod:`~apex_tpu.tune.registry` — each tunable kernel declares its
   config space (block sizes / grid layouts), VMEM-budget constraint,
   correctness oracle, and which roofline-ledger regions it lives in.
-  flash_attention (fwd+bwd), fused_layer_norm, bn_relu_residual,
-  contrib xentropy, and the quantized matmuls all register.
+  flash_attention (fwd+bwd), fused_layer_norm, contrib xentropy, and
+  the quantized matmuls all register.
 * :mod:`~apex_tpu.tune.measure` — times candidate configs on-device
   (min-of-K with explicit sync, compile excluded; candidates failing
   the oracle or the VMEM gate are rejected before timing) and
@@ -26,8 +26,8 @@ measured, per-device search:
   interpret paths never tune — tuning is always an explicit
   :func:`~apex_tpu.tune.measure.tune_kernel` / CLI run.
 * :mod:`~apex_tpu.tune.space` — the shared VMEM-budget / row-block
-  math both the normalization kernels and the tuner's constraint
-  checker use (hoisted out of ``fused_layer_norm``/``fused_bn_act``).
+  math both the row-blocked kernels and the tuner's constraint
+  checker use (hoisted out of ``fused_layer_norm``).
 
 CLI::
 

@@ -13,20 +13,12 @@ Per-spec notes:
   kernels are half the measured clock, exactly as in training.  The
   online-softmax recurrence reorders with the KV block, so the oracle
   checks to tolerance, not bitwise.
-* **fused_layer_norm / bn_relu_residual / xentropy** — ``row_block``
+* **fused_layer_norm / xentropy** — ``row_block``
   sweeps; row partitioning never changes per-row math, so candidates
   must match the default config BITWISE.
 * **quantized_matmul** — ``block_m``/``block_n`` tiles; each output
   element is an int32 dot over the full K regardless of tile, so the
   oracle is bitwise too.
-* **conv2d** — ``block_m`` (im2col row tile) / ``block_n`` (output
-  channels); the tap loop is static and each tap contracts the FULL
-  input-channel axis in one dot, so partitioning never reorders an
-  output element's reduction: bitwise across configs.  The case runs
-  ``value_and_grad`` through the fused conv+bn_relu_residual custom
-  VJP so dgrad/wgrad are part of the measured clock.  kind="memory":
-  the r05 resnet ledger calls the stage1/stage2 conv regions
-  memory-bound, so small blocks visit first.
 
 Candidate priority (the ledger hook): memory-bound verdicts visit
 smaller blocks first (layout/pipelining candidates — more grid steps,
@@ -54,8 +46,8 @@ import importlib
 def _mod(name):
     return importlib.import_module("apex_tpu." + name)
 
-__all__ = ["FLASH_ATTENTION", "FUSED_LAYER_NORM", "BN_RELU_RESIDUAL",
-           "XENTROPY", "QUANTIZED_MATMUL", "CONV2D"]
+__all__ = ["FLASH_ATTENTION", "FUSED_LAYER_NORM", "XENTROPY",
+           "QUANTIZED_MATMUL"]
 
 #: generous flash-kernel VMEM estimate budget (operand + score blocks +
 #: scratch; the proven-on-chip 1024x1024 default must pass)
@@ -278,94 +270,6 @@ FUSED_LAYER_NORM = register(KernelSpec(
     regions=("layer_norm", "layernorm", "ln")))
 
 
-def _bn_dims(shape: Mapping):
-    return (int(shape.get("rows", 16384)), int(shape.get("channels", 256)),
-            bool(shape.get("residual", True)),
-            jnp.dtype(shape.get("dtype", "float32")))
-
-
-def _bn_candidates(shape: Mapping, bound: Optional[str]):
-    rows, c, has_z, dtype = _bn_dims(shape)
-    blocks = _space.row_block_candidates(rows, c, 4 * dtype.itemsize + 12)
-    return [{"row_block": b} for b in blocks]
-
-
-def _bn_constraint(shape: Mapping, cfg: Dict[str, int]) -> bool:
-    _, c, _, dtype = _bn_dims(shape)
-    return cfg["row_block"] % _space.SUBLANE_ROWS == 0 \
-        and _space.floor_block_fits(c, 3 * dtype.itemsize + 8)
-
-
-def _bn_case(shape: Mapping, interpret: bool) -> TuneCase:
-    import jax.random as jrandom
-    bn_relu_residual = _mod("normalization.fused_bn_act").bn_relu_residual
-    rows, c, has_z, dtype = _bn_dims(shape)
-    keys = jrandom.split(jrandom.PRNGKey(0), 2)
-    x = (jrandom.normal(keys[0], (rows, c), jnp.float32)).astype(dtype)
-    z = (jrandom.normal(keys[1], (rows, c), jnp.float32)).astype(dtype) \
-        if has_z else None
-    mean = jnp.linspace(-0.2, 0.2, c, dtype=jnp.float32)
-    invstd = jnp.linspace(0.8, 1.2, c, dtype=jnp.float32)
-    scale = jnp.linspace(0.5, 1.5, c, dtype=jnp.float32)
-    bias = jnp.linspace(-0.1, 0.1, c, dtype=jnp.float32)
-    fns: Dict[int, object] = {}
-    args = (x, mean, invstd, scale, bias) + ((z,) if has_z else ())
-
-    def grad_fn(**kw):
-        def loss(x, mean, invstd, scale, bias, *rest):
-            o = bn_relu_residual(x, mean, invstd, scale, bias,
-                                 z=(rest[0] if has_z else None), **kw)
-            return jnp.sum(o.astype(jnp.float32) ** 2)
-
-        return jax.jit(jax.value_and_grad(
-            loss, argnums=tuple(range(len(args)))))
-
-    def run(cfg):
-        rb = int(cfg["row_block"])
-        f = fns.get(rb)
-        if f is None:
-            f = fns[rb] = grad_fn(impl="pallas", interpret=interpret,
-                                  row_block=rb)
-        return f(*args)
-
-    return TuneCase(run=run, ref=lambda: grad_fn(impl="jnp")(*args))
-
-
-def _bn_bucket(shape: Mapping) -> str:
-    fba = _mod("normalization.fused_bn_act")
-    rows, c, has_z, dtype = _bn_dims(shape)
-    return fba.tune_bucket(rows, c, dtype.itemsize, has_z)
-
-
-def _bn_version() -> int:
-    fba = _mod("normalization.fused_bn_act")
-    return fba.TUNE_VERSION
-
-
-def _bn_effective(shape: Mapping, cfg: Dict[str, int]):
-    rows, c, _, dtype = _bn_dims(shape)
-    isz = dtype.itemsize
-    return (_space.pick_rows(rows, c, 3 * isz + 8,
-                             row_block=cfg["row_block"]),
-            _space.pick_rows(rows, c, 4 * isz + 12,
-                             row_block=cfg["row_block"]))
-
-
-BN_RELU_RESIDUAL = register(KernelSpec(
-    name="bn_relu_residual", version=_bn_version(),
-    params=("row_block",), kind="memory", exact=True,
-    defaults=lambda shape: {"row_block": 256},
-    candidates=_bn_candidates, constraint=_bn_constraint,
-    build=_bn_case, bucket=_bn_bucket,
-    priority=lambda shape, cfg, bound: _rows_priority(cfg, bound),
-    effective=_bn_effective,
-    example_shape={"rows": 16384, "channels": 256, "residual": True,
-                   "dtype": "bfloat16"},
-    small_shape={"rows": 64, "channels": 128, "residual": True,
-                 "dtype": "float32"},
-    regions=("bn", "batchnorm", "stage", "downsample")))
-
-
 def _xe_dims(shape: Mapping):
     return (int(shape.get("rows", 4096)), int(shape.get("vocab", 8192)))
 
@@ -542,125 +446,3 @@ QUANTIZED_MATMUL = register(KernelSpec(
     example_shape={"m": 8192, "k": 4096, "n": 4096, "dtype": "bfloat16"},
     small_shape={"m": 64, "k": 128, "n": 128, "dtype": "float32"},
     regions=("quant", "qmm", "dense", "proj", "mlp")))
-
-
-# -- pallas conv2d (implicit GEMM + fused epilogue) ---------------------------
-
-def _conv_dims(shape: Mapping):
-    return (int(shape.get("batch", 32)), int(shape.get("h", 28)),
-            int(shape.get("w", 28)), int(shape.get("cin", 128)),
-            int(shape.get("cout", 128)), int(shape.get("kh", 3)),
-            int(shape.get("kw", 3)), int(shape.get("stride", 1)),
-            jnp.dtype(shape.get("dtype", "bfloat16")),
-            bool(shape.get("residual", True)))
-
-
-def _conv_epilogue(shape: Mapping) -> bool:
-    """``epilogue: False`` builds the bare conv — what
-    :class:`apex_tpu.ops.PallasConv` dispatches from a model (BN needs
-    the conv's output for its statistics first); the default is the
-    fused conv+bn+relu(+residual) chain."""
-    return bool(shape.get("epilogue", True))
-
-
-def _conv_candidates(shape: Mapping, bound: Optional[str]):
-    out = []
-    for bm in (128, 256, 512, 1024):
-        for bn in (128, 256, 512):
-            cfg = {"block_m": bm, "block_n": bn}
-            if _conv_constraint(shape, cfg):
-                out.append(cfg)
-    return out
-
-
-def _conv_constraint(shape: Mapping, cfg: Dict[str, int]) -> bool:
-    cv = _mod("ops.conv")
-    n, h, w, cin, cout, kh, kw, s, dtype, res = _conv_dims(shape)
-    padding = cv._norm_padding("SAME", h, w, kh, kw, s, s, 1, 1)
-    # want_preact: the training forward (epilogue + custom VJP) also
-    # streams the saved pre-activation block, the worst case.
-    epi = _conv_epilogue(shape)
-    return cv._fwd_fits(h, w, padding, cin, cout, kh, kw, s, s, 1, 1,
-                        int(cfg["block_m"]), int(cfg["block_n"]),
-                        dtype.itemsize, res and epi, epi)
-
-
-def _conv_case(shape: Mapping, interpret: bool) -> TuneCase:
-    import jax.random as jrandom
-    cv = _mod("ops.conv")
-    n, h, w, cin, cout, kh, kw, s, dtype, res = _conv_dims(shape)
-    x = (jrandom.normal(jrandom.PRNGKey(0), (n, h, w, cin), jnp.float32)
-         ).astype(dtype)
-    wt = (jrandom.normal(jrandom.PRNGKey(1), (kh, kw, cin, cout),
-                         jnp.float32) * 0.05).astype(dtype)
-    oh, ow = -(-h // s), -(-w // s)
-    fns: Dict[tuple, object] = {}
-    if _conv_epilogue(shape):
-        args = (x, wt, jnp.zeros((cout,), jnp.float32),     # mean
-                jnp.ones((cout,), jnp.float32),             # invstd
-                jnp.ones((cout,), jnp.float32),             # scale
-                jnp.zeros((cout,), jnp.float32))            # bias
-        z = (jnp.ones((n, oh, ow, cout), jnp.float32).astype(dtype)
-             if res else None)
-        epi = {"z": z, "relu": True}
-    else:
-        args, epi = (x, wt), {}
-
-    def grad_fn(**kw):
-        def loss(x, wt, *stats):
-            o = cv.conv2d(x, wt, stride=s, padding="SAME",
-                          **dict(zip(("mean", "invstd", "scale", "bias"),
-                                     stats)), **epi, **kw)
-            return jnp.sum(o.astype(jnp.float32) ** 2)
-
-        return jax.jit(jax.value_and_grad(
-            loss, argnums=tuple(range(len(args)))))
-
-    def run(cfg):
-        key = (int(cfg["block_m"]), int(cfg["block_n"]))
-        f = fns.get(key)
-        if f is None:
-            f = fns[key] = grad_fn(impl="pallas", interpret=interpret,
-                                   block_m=key[0], block_n=key[1])
-        return f(*args)
-
-    return TuneCase(run=run, ref=lambda: grad_fn(impl="jnp")(*args))
-
-
-def _conv_bucket(shape: Mapping) -> str:
-    cv = _mod("ops.conv")
-    n, h, w, cin, cout, kh, kw, s, dtype, res = _conv_dims(shape)
-    oh, ow = -(-h // s), -(-w // s)
-    epi = _conv_epilogue(shape)
-    return cv.tune_bucket(n, oh, ow, cin, cout, kh, kw, s, s, 1, 1,
-                          dtype.itemsize, epi, res and epi)
-
-
-def _conv_version() -> int:
-    return _mod("ops.conv").TUNE_VERSION
-
-
-def _conv_effective(shape: Mapping, cfg: Dict[str, int]):
-    cv = _mod("ops.conv")
-    n, h, w, cin, cout, kh, kw, s, dtype, res = _conv_dims(shape)
-    oh, ow = -(-h // s), -(-w // s)
-    return (cv._pick_boh(oh, ow, int(cfg["block_m"])),
-            cv._pick_block(cout, int(cfg["block_n"]), 128))
-
-
-CONV2D = register(KernelSpec(
-    name="conv2d", version=_conv_version(),
-    params=("block_m", "block_n"), kind="memory", exact=True,
-    defaults=lambda shape: {"block_m": 512, "block_n": 256},
-    candidates=_conv_candidates, constraint=_conv_constraint,
-    build=_conv_case, bucket=_conv_bucket,
-    priority=lambda shape, cfg, bound: _area_priority(
-        cfg["block_m"] * cfg["block_n"], bound),
-    effective=_conv_effective,
-    example_shape={"batch": 32, "h": 28, "w": 28, "cin": 128,
-                   "cout": 128, "kh": 3, "kw": 3, "stride": 1,
-                   "dtype": "bfloat16", "residual": True},
-    small_shape={"batch": 2, "h": 8, "w": 8, "cin": 8, "cout": 16,
-                 "kh": 3, "kw": 3, "stride": 1, "dtype": "float32",
-                 "residual": True},
-    regions=("conv", "stage", "downsample")))

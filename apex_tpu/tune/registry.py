@@ -138,7 +138,7 @@ def registered_versions() -> Dict[str, int]:
 
 def load_builtin() -> None:
     """Import the builtin registrations (flash_attention,
-    fused_layer_norm, bn_relu_residual, xentropy, quantized_matmul).
+    fused_layer_norm, xentropy, quantized_matmul).
     Idempotent; kernels keep importing fine without it — this is the
     tuner/CLI side only."""
     global _BUILTIN_LOADED

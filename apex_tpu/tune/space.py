@@ -1,12 +1,10 @@
 """Shared VMEM-budget / block-shape math (ISSUE 14 satellite).
 
 One home for the sizing rules the row-blocked Pallas kernels and the
-tuner's constraint checker must agree on.  Previously
-``normalization/fused_bn_act.py`` imported the private
-``_SUBLANE_ROWS``/``_VMEM_BUDGET_BYTES`` from ``fused_layer_norm.py``
-and re-implemented ``_pick_rows``; both kernels now call these helpers,
-and :mod:`apex_tpu.tune.measure` uses the same functions to reject
-candidate configs that cannot fit scoped VMEM **before** timing them.
+tuner's constraint checker must agree on: the kernels call these
+helpers, and :mod:`apex_tpu.tune.measure` uses the same functions to
+reject candidate configs that cannot fit scoped VMEM **before** timing
+them.
 
 The model: a row-blocked kernel holds ``rows x width`` blocks whose
 per-element footprint is ``bytes_per_elem`` (the caller sums its live
@@ -23,7 +21,7 @@ from typing import List, Optional
 
 __all__ = ["VMEM_BUDGET_BYTES", "SUBLANE_ROWS", "LANE_COLS", "pick_rows",
            "floor_block_fits", "max_width", "row_block_candidates",
-           "pow2_bucket", "nhwc_bucket"]
+           "pow2_bucket"]
 
 #: scoped-VMEM budget a single kernel block may claim (conservative
 #: slice of the ~16 MB scoped limit; measured r5 — see fused_layer_norm)
@@ -107,16 +105,3 @@ def pow2_bucket(n: int) -> int:
         b <<= 1
     return b
 
-
-def nhwc_bucket(n: int, h: int, w: int, c: int) -> str:
-    """Shape bucket for a 4-D NHWC conv operand (ISSUE 18 satellite).
-
-    Batch and the JOINT spatial extent ``h*w`` round to powers of two —
-    a conv kernel blocks over flattened output rows, so it is the
-    ``h*w`` product that selects a block shape, and bucketing ``h`` and
-    ``w`` separately would split e.g. ``56x56`` and ``64x49`` (same row
-    count, same winning config) into distinct one-entry cache keys.
-    Channels stay exact: they set the matmul contraction width and the
-    lane-tiled VMEM footprint, where off-by-one-bucket reuse is wrong.
-    """
-    return f"n{pow2_bucket(n)}_s{pow2_bucket(h * w)}_c{int(c)}"

@@ -22,9 +22,9 @@ One process, one TPU host, no network, nothing but this checkout:
    all-reduce in the compiled step and replicas that agree.
 3. **kernel sweep** — every kernel in ``apex_tpu.tune.registry.all_specs()``
    compiled by Mosaic (``interpret=False``, ``impl="pallas"`` forced by
-   the tuner's builders) at its ``example_shape`` and at the ResNet-50
-   step's shapes, forward and backward, against the jnp reference its
-   module carries, within the spec's tolerance.
+   the tuner's builders) at its ``example_shape`` (xentropy also at the
+   ResNet-50 step's shape), forward and backward, against the jnp
+   reference its module carries, within the spec's tolerance.
 
 Any failure is an exception: non-zero exit, no result line.  On success the
 last line of stdout is ``{"ok": true, "device": {...}}``.  The seconds it
@@ -43,29 +43,6 @@ STEPS_PER_CALL = 16
 WINDOWS = 3                 # timed windows after the drain window
 BATCH_PER_CHIP = 128
 IMAGE_SIZE = 224
-
-#: (h, cin, cout, k): every distinct conv site of the ResNet-50 step that
-#: the Mosaic conv kernel can serve — the stride-1 1x1 and 3x3 convs at
-#: output widths 56/28/14/7, bare (``epilogue: False``).  The step itself
-#: runs XLA's conv at all of them (``ops.conv._dispatch_pallas``; the six
-#: stride-2 sites and the C=3 stem the kernel cannot serve at all,
-#: ``ops.conv._mosaic_accepts``); the sweep forces the kernel here so that
-#: it keeps compiling and matching its reference.
-RESNET50_CONVS = [
-    (56, 64, 64, 1), (56, 64, 64, 3), (56, 64, 256, 1), (56, 256, 64, 1),
-    (56, 256, 128, 1), (28, 128, 128, 3), (28, 128, 512, 1),
-    (28, 512, 128, 1), (28, 512, 256, 1), (14, 256, 256, 3),
-    (14, 256, 1024, 1), (14, 1024, 256, 1), (14, 1024, 512, 1),
-    (7, 512, 512, 3), (7, 512, 2048, 1), (7, 2048, 512, 1),
-]
-#: (rows, channels, residual) of its BN epilogues: the stem, a stage-1
-#: bottleneck tail and the stage-4 tail (XLA in the step, the kernel
-#: forced in the sweep, as above).
-RESNET50_BN = [
-    (BATCH_PER_CHIP * 112 * 112, 64, False),
-    (BATCH_PER_CHIP * 56 * 56, 256, True),
-    (BATCH_PER_CHIP * 7 * 7, 2048, True),
-]
 
 
 def say(msg):
@@ -113,7 +90,6 @@ def main_path(devices):
     import main_amp as imagenet
 
     from apex_tpu import native, prof, runtime
-    from apex_tpu.ops import conv_dispatch_stats
 
     n_dev = len(devices)
     argv = ["--synthetic", "-a", "resnet50",
@@ -151,7 +127,7 @@ def main_path(devices):
     n_custom = hlo.count('custom_call_target="tpu_custom_call"')
     n_allreduce = hlo.count(" all-reduce(") + hlo.count(" all-reduce-start(")
     say(f"compiled step: {n_custom} tpu_custom_call(s), {n_allreduce} "
-        f"all-reduce(s); conv_dispatch_stats={conv_dispatch_stats()}")
+        f"all-reduce(s)")
     assert n_custom > 0, (
         "no tpu_custom_call in the compiled step although the fused "
         "xentropy loss, a Mosaic kernel, is default-ON")
@@ -218,15 +194,7 @@ def main_path(devices):
 
 def _sweep_shapes(spec):
     shapes = [dict(spec.example_shape)]
-    if spec.name == "conv2d":
-        shapes += [{"batch": BATCH_PER_CHIP, "h": h, "w": h, "cin": ci,
-                    "cout": co, "kh": k, "kw": k, "stride": 1,
-                    "dtype": "bfloat16", "epilogue": False}
-                   for h, ci, co, k in RESNET50_CONVS]
-    elif spec.name == "bn_relu_residual":
-        shapes += [{"rows": r, "channels": c, "residual": z,
-                    "dtype": "bfloat16"} for r, c, z in RESNET50_BN]
-    elif spec.name == "xentropy":
+    if spec.name == "xentropy":
         shapes.append({"rows": BATCH_PER_CHIP, "vocab": 1000})
     return shapes
 

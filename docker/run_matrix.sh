@@ -25,8 +25,8 @@ export XLA_FLAGS="--xla_force_host_platform_device_count=8"
 # static jaxpr walk and the watchdog a pure host fold, so every tier
 # must produce identical ledgers/alerts.
 # test_contrib.py + test_fused_bn_act.py ride along for the conv-path
-# fusion engine (ISSUE 7): the tier-parity tests run the REAL pallas
-# kernels in interpret mode against the jnp references, so the
+# epilogues (ISSUE 7): test_contrib's tier-parity tests run the REAL
+# xentropy kernels in interpret mode against the jnp references, so the
 # no-pallas tiers must stay numerically identical.  test_cache.py rides
 # for the warm-start engine (AOT warmup is pure host machinery — every
 # tier must keep zero-compile-after-step-0 and bitwise parity).
@@ -50,9 +50,6 @@ export XLA_FLAGS="--xla_force_host_platform_device_count=8"
 # run the REAL quantized-matmul kernel via interpret=True, the
 # no-pallas tiers the jnp reference — every tier must hold the
 # kernel-parity, O4-fallback-bitwise-O2, and int8-KV decode contracts.
-# test_conv.py rides for the Pallas implicit-GEMM conv (ISSUE 18): the
-# interpret kernels, the fused conv+bn_relu_residual epilogue, and the
-# conv_cls resnet hook must match the XLA oracle on every tier.
 # test_tune.py rides for the kernel autotuner (ISSUE 14): the config
 # cache is pure host JSON and the tuner's interpret-mode probes run the
 # REAL kernels, so every tier must hold the roundtrip/invalidation/
@@ -62,7 +59,7 @@ export XLA_FLAGS="--xla_force_host_platform_device_count=8"
 # the offline analyzer are pure host machinery over the event stream,
 # so every tier must produce identical span trees, goodput verdicts,
 # and bitwise-unchanged traced tokens.
-FAST="python -m pytest tests/test_install_matrix.py tests/test_multi_tensor.py tests/test_telemetry.py tests/test_roofline.py tests/test_watchdog.py tests/test_contrib.py tests/test_fused_bn_act.py tests/test_cache.py tests/test_checkpoint.py tests/test_faultinject.py tests/test_fleet.py tests/test_export.py tests/test_memory.py tests/test_serving.py tests/test_tracing.py tests/test_requests.py tests/test_mesh.py tests/test_quant.py tests/test_tune.py tests/test_conv.py -q -m 'not slow'"
+FAST="python -m pytest tests/test_install_matrix.py tests/test_multi_tensor.py tests/test_telemetry.py tests/test_roofline.py tests/test_watchdog.py tests/test_contrib.py tests/test_fused_bn_act.py tests/test_cache.py tests/test_checkpoint.py tests/test_faultinject.py tests/test_fleet.py tests/test_export.py tests/test_memory.py tests/test_serving.py tests/test_tracing.py tests/test_requests.py tests/test_mesh.py tests/test_quant.py tests/test_tune.py -q -m 'not slow'"
 
 echo "=== tier 1: full (native + pallas) ==="
 python setup.py build_native

@@ -39,6 +39,7 @@ except ModuleNotFoundError:  # running from a source checkout
         _os.path.dirname(__file__), *[_os.pardir] * 2)))
 
 import argparse
+import functools
 import time
 import types
 
@@ -51,6 +52,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from apex_tpu import cache as apex_cache
 from apex_tpu import data as apex_data
 from apex_tpu import runtime, training
+from apex_tpu.contrib.groupbn import BatchNorm2d_NHWC
 from apex_tpu.data import normalize_images, synthetic_imagenet
 from apex_tpu.models import (ResNet18, ResNet34, ResNet50, ResNet101,
                              ResNet152)
@@ -86,26 +88,6 @@ def parse(argv=None):
                         "of two per parameter leaf")
     p.add_argument("--keep-batchnorm-fp32", type=str, default=None)
     p.add_argument("--loss-scale", type=str, default=None)
-    p.add_argument("--fused-bn", action=argparse.BooleanOptionalAction,
-                   default=True,
-                   help="route the ResNet bn->relu->(+residual) chains "
-                        "through contrib.groupbn.BatchNorm2d_NHWC (the "
-                        "apex bn_add_relu contract, over normalization."
-                        "bn_relu_residual); works with and without "
-                        "--sync_bn (ISSUE 7).  --no-fused-bn keeps plain "
-                        "nn.BatchNorm + explicit relu/add.  Both are jnp "
-                        "that XLA compiles alike: no Mosaic kernel is "
-                        "chosen at a BN site (PERF.md, PR 26)")
-    p.add_argument("--pallas-conv", action=argparse.BooleanOptionalAction,
-                   default=True,
-                   help="build the ResNet convs from apex_tpu.ops."
-                        "PallasConv (the conv_cls= hook, ISSUE 18) "
-                        "instead of nn.Conv.  Its automatic dispatch "
-                        "takes XLA's conv at every site (the Mosaic "
-                        "kernel was slower at every ResNet-50 shape on "
-                        "the v5e: PERF.md, PR 26) and counts the sites "
-                        "in the tune: line, so both settings run the "
-                        "same XLA conv on the same params and checkpoint")
     p.add_argument("--fused-loss", action=argparse.BooleanOptionalAction,
                    default=True,
                    help="contrib.xentropy fused softmax-cross-entropy "
@@ -283,6 +265,24 @@ def _health_extras(rec) -> str:
     return extras
 
 
+def build_model(args, n_dev):
+    """The ResNet the example trains.  BN epilogues go through the norm
+    (ISSUE 7): the contrib GroupBN NHWC module carries the apex
+    bn_relu/bn_add_relu contract, so the ResNet norm-factory hook sends
+    every bn->relu->(+residual) chain through bn_relu_residual (jnp
+    that XLA fuses).  --sync_bn maps to bn_group=n_dev (stats psum'd
+    across the mesh, same contract as SyncBatchNorm)."""
+    dtype = (jnp.bfloat16 if args.opt_level in ("O1", "O2", "O3")
+             else jnp.float32)
+    norm_cls = functools.partial(
+        BatchNorm2d_NHWC,
+        bn_group=n_dev if args.sync_bn else 1,
+        axis_name="data" if args.sync_bn else None,
+        world_size=n_dev if args.sync_bn else None)
+    return ARCHS[args.arch](num_classes=1000, dtype=dtype,
+                            norm_cls=norm_cls)
+
+
 def build(args):
     """Model, train state and device loop exactly as the training loop
     below drives them: ``make_train_step`` -> ``runtime.StepPipeline``
@@ -304,59 +304,15 @@ def build(args):
     # Reference lr scaling: lr * global_batch/256 (main_amp.py --lr help).
     lr = args.lr * args.batch_size / 256.0
 
-    dtype = (jnp.bfloat16 if args.opt_level in ("O1", "O2", "O3")
-             else jnp.float32)
-    model_cls = ARCHS[args.arch]
-    conv_cls = None
-    if args.pallas_conv:
-        from apex_tpu.ops import PallasConv
-        conv_cls = PallasConv
-    x0 = jnp.ones((2, args.image_size, args.image_size, 3), jnp.float32)
-
-    def init_variables(m):
-        # jitted: ONE compile instead of one per initializer op (eager
-        # flax init dispatches hundreds of tiny programs); same values
-        return jax.jit(lambda key, x: m.init(key, x, train=True))(
-            jax.random.PRNGKey(0), x0)
-
-    if args.fused_bn:
-        # BN epilogues through the norm (ISSUE 7): the contrib GroupBN
-        # NHWC module carries the apex bn_relu/bn_add_relu contract, so
-        # the ResNet norm-factory hook sends every bn->relu->(+residual)
-        # chain through bn_relu_residual (jnp that XLA fuses; its Mosaic
-        # kernel is not chosen: PERF.md, PR 26).  --sync_bn maps to
-        # bn_group=n_dev (stats psum'd across the mesh, same contract
-        # as SyncBatchNorm).  Init runs on this model directly —
-        # collectives are guarded off under is_initializing() — so the
-        # batch_stats pytree is structure-stable from step 0.
-        import functools
-        from apex_tpu.contrib.groupbn import BatchNorm2d_NHWC
-        norm_cls = functools.partial(
-            BatchNorm2d_NHWC,
-            bn_group=n_dev if args.sync_bn else 1,
-            axis_name="data" if args.sync_bn else None,
-            world_size=n_dev if args.sync_bn else None)
-        model = model_cls(num_classes=1000, dtype=dtype,
-                          norm_cls=norm_cls, conv_cls=conv_cls)
-        variables = init_variables(model)
-    else:
-        model = model_cls(num_classes=1000, dtype=dtype,
-                          sync_bn=args.sync_bn,
-                          axis_name="data" if args.sync_bn else None,
-                          conv_cls=conv_cls)
-        init_model = model_cls(num_classes=1000, dtype=dtype,
-                               conv_cls=conv_cls)
-        variables = init_variables(init_model)
-        if args.sync_bn:
-            # init_model uses plain BatchNorm (SyncBatchNorm's
-            # collectives need the mesh, absent at init); adopt its
-            # stats under the sync module's names so the batch_stats
-            # pytree is structure-stable — the K-step scan carry
-            # requires it.
-            from apex_tpu.parallel import adopt_batchnorm_stats
-            variables = dict(
-                variables,
-                batch_stats=adopt_batchnorm_stats(variables["batch_stats"]))
+    model = build_model(args, n_dev)
+    # jitted: ONE compile instead of one per initializer op (eager flax
+    # init dispatches hundreds of tiny programs); same values.  Init
+    # runs on the training model directly — collectives are guarded off
+    # under is_initializing() — so the batch_stats pytree is
+    # structure-stable from step 0.
+    variables = jax.jit(lambda key, x: model.init(key, x, train=True))(
+        jax.random.PRNGKey(0),
+        jnp.ones((2, args.image_size, args.image_size, 3), jnp.float32))
 
     if args.fused_loss:
         # Fused label-smoothing softmax-xentropy (contrib port): one
@@ -691,8 +647,8 @@ def _train(args):
         print(f"best-window {args.batch_size * 2 * spc / best:.1f} img/s "
               f"over {2 * spc}-iter windows")
     # Input-engine attribution (ISSUE 3): the share of the wall clock
-    # the train loop spent WAITING on the loader — bench.py parses this
-    # line into loader_stall_pct per example.
+    # the train loop spent WAITING on the loader (the line
+    # tests/test_native_data.py parses back).
     if hasattr(windows, "stats"):
         from apex_tpu.prof import loader_ledger
         led = loader_ledger(windows.stats.as_dict())
@@ -723,13 +679,8 @@ def _train(args):
         if ts["by_kernel"]:
             tuned = sorted(k for k, v in ts["by_kernel"].items()
                            if v["tuned"])
-            from apex_tpu.ops import conv_dispatch_stats
-            cs = conv_dispatch_stats()
             print(f"tune: {ts['tuned_kernel_pct']:.0f}% of consulted "
-                  f"kernels tuned ({', '.join(tuned) or 'none'}); "
-                  f"conv sites {cs['pallas_sites']} pallas / "
-                  f"{cs['fallback_sites']} xla-fallback "
-                  f"{cs['fallback_reasons'] or ''}".rstrip())
+                  f"kernels tuned ({', '.join(tuned) or 'none'})")
     except Exception:                            # pragma: no cover
         pass
     if stop is not None:

@@ -157,40 +157,6 @@ def test_groupbn_z_add_relu_matches_oracle():
     np.testing.assert_allclose(np.asarray(y), want, atol=1e-4)
 
 
-def test_groupbn_epilogue_pallas_interpret_parity():
-    """The groupbn elementwise tail IS normalization.bn_relu_residual;
-    tier parity of that kernel (interpret mode) against its reference,
-    z-residual corner included, through fwd and grads."""
-    from apex_tpu.normalization.fused_bn_act import (bn_act_epilogue_ref,
-                                                     bn_relu_residual)
-    rng = np.random.RandomState(5)
-    x = jnp.asarray(rng.randn(2, 4, 4, 8), jnp.float32)
-    z = jnp.asarray(rng.randn(2, 4, 4, 8), jnp.float32)
-    mean = jnp.asarray(rng.randn(8), jnp.float32)
-    invstd = jnp.asarray(np.abs(rng.randn(8)) + 0.3, jnp.float32)
-    w = jnp.asarray(rng.randn(8), jnp.float32)
-    b = jnp.asarray(rng.randn(8), jnp.float32)
-
-    for zz in (z, None):
-        got = bn_relu_residual(x, mean, invstd, w, b, z=zz, relu=True,
-                               interpret=True)
-        want = bn_act_epilogue_ref(x, mean, invstd, w, b, z=zz, relu=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=1e-5)
-
-    def loss(interp, *operands):
-        return jnp.sum(bn_relu_residual(*operands, z=z, relu=True,
-                                        interpret=interp) ** 2)
-
-    g_k = jax.grad(lambda *o: loss(True, *o), argnums=(0, 1, 2, 3, 4))(
-        x, mean, invstd, w, b)
-    g_r = jax.grad(lambda *o: loss(False, *o), argnums=(0, 1, 2, 3, 4))(
-        x, mean, invstd, w, b)
-    for a, r in zip(g_k, g_r):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
-                                   atol=1e-4, rtol=1e-4)
-
-
 def test_groupbn_bn_group_sync_on_mesh():
     """bn_group=4 on an 8-replica mesh: stats shared within each half."""
     mesh = Mesh(np.array(jax.devices("cpu")[:8]), ("data",))

@@ -240,13 +240,12 @@ def test_lm_example_fused_loss_parity(monkeypatch, capsys):
 
 
 def test_imagenet_example_unfused_flags(monkeypatch, capsys):
-    """--no-fused-bn/--no-fused-loss/--no-aot-warmup keep the plain
-    nn.BatchNorm + log_softmax + cold-compile surface alive."""
+    """--no-fused-loss/--no-aot-warmup keep the plain log_softmax +
+    cold-compile surface alive."""
     _run_example(monkeypatch, "examples/imagenet/main_amp.py", [
         "--synthetic", "--prof", "2", "-b", "8", "--image-size", "32",
         "-a", "resnet18", "--epochs", "1", "--steps-per-epoch", "2",
-        "--opt-level", "O2", "--no-fused-bn", "--no-fused-loss",
-        "--no-aot-warmup"])
+        "--opt-level", "O2", "--no-fused-loss", "--no-aot-warmup"])
     out = capsys.readouterr().out
     assert "done" in out
 

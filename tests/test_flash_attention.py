@@ -518,3 +518,30 @@ def test_causal_more_queries_than_keys_raises():
     k = _rand((1, 4, 2, 16), 1)
     with pytest.raises(ValueError, match="q_len"):
         flash_attention(q, k, q * 0, causal=True)
+
+
+def test_attention_sweep_tool_quick(tmp_path, monkeypatch, capsys):
+    """``tools/attention_sweep.py``, the sweep the dispatch threshold
+    cites, stands on its own (its execution-forcing fetch was imported
+    from the deleted ``bench.py``): ``--quick`` on the CPU times all
+    three implementations and writes its table."""
+    import json
+    import os
+    import runpy
+    import sys
+
+    tool = os.path.join(os.path.dirname(__file__), os.pardir, "tools",
+                        "attention_sweep.py")
+    out = tmp_path / "sweep.json"
+    monkeypatch.setattr(sys, "argv", [tool, "--quick", "--iters", "1",
+                                      "--out", str(out)])
+    runpy.run_path(tool, run_name="__main__")
+    table = json.loads(out.read_text())
+    (row,) = table["rows"]
+    assert table["backend"] == "cpu"
+    assert {"full_ms", "blockwise_ms", "flash_128_ms",
+            "flash_best_ms", "jnp_best_ms"} <= set(row)
+    assert all(row[k] > 0 for k in ("full_ms", "blockwise_ms",
+                                    "flash_128_ms"))
+    assert json.loads(capsys.readouterr().out.splitlines()[-1])[
+        "n_rows"] == 1

@@ -1,37 +1,20 @@
-"""BN epilogue tests (ISSUE 7, ISSUE 26): bn_relu_residual kernel parity
-(interpret mode vs the jnp reference), the NHWC XLA implementation's
-parity with both, the automatic dispatch (XLA at every ResNet-50 site,
-the kernel only when forced), custom-VJP exactness through full-BN
-autodiff, the SyncBatchNorm tail routing, and the ResNet norm-factory
-hook's fused-vs-explicit block equivalence.
+"""BN epilogue tests (ISSUE 7, ISSUE 26, ISSUE 29): ``bn_relu_residual``'s
+forward and hand-written backward against autodiff of a plain jnp
+composition, its NHWC shape discipline (no activation-sized reshape or
+pad), custom-VJP exactness through full-BN autodiff, the SyncBatchNorm
+tail routing, and the ResNet norm-factory hook's fused-vs-explicit block
+equivalence.
 """
 
 import functools
-import importlib.util
-import os
 
-import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from apex_tpu.normalization import fused_bn_act
-from apex_tpu.normalization.fused_bn_act import (_dispatch_pallas,
-                                                 _kernel_fits,
-                                                 bn_act_epilogue_ref,
+from apex_tpu.normalization.fused_bn_act import (bn_act_epilogue_ref,
                                                  bn_relu_residual)
-
-
-def load_chip_smoke():
-    """``chip_smoke.py`` of this checkout as a module (its import touches
-    neither JAX nor the chip): the lists of ResNet-50 sites live there."""
-    path = os.path.join(os.path.dirname(__file__), os.pardir,
-                        "chip_smoke.py")
-    spec = importlib.util.spec_from_file_location("chip_smoke", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
 
 
 def activation_sized(jaxpr, names, size):
@@ -58,37 +41,59 @@ def _operands(c=8, dtype=jnp.float32, seed=0):
     return x, z, mean, invstd, w, b
 
 
+def _plain_epilogue(x, mean, invstd, scale, bias, z, relu):
+    """The epilogue's definition, written out here so that nothing of
+    ``fused_bn_act`` stands on both sides of a comparison."""
+    out = (x.astype(jnp.float32) - mean) * invstd
+    if scale is not None:
+        out = out * scale + bias
+    if z is not None:
+        out = out + z.astype(jnp.float32)
+    if relu:
+        out = jnp.maximum(out, 0.0)
+    return out.astype(x.dtype)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("relu", [True, False])
 @pytest.mark.parametrize("with_z", [True, False])
 @pytest.mark.parametrize("affine", [True, False])
-def test_kernel_interpret_forward_parity(dtype, relu, with_z, affine):
-    x, z, mean, invstd, w, b = _operands(dtype=dtype)
-    zz = z if with_z else None
-    ww, bb = (w, b) if affine else (None, None)
-    got = bn_relu_residual(x, mean, invstd, ww, bb, z=zz, relu=relu,
-                           interpret=True)
-    want = bn_act_epilogue_ref(x, mean, invstd, ww, bb, z=zz, relu=relu)
-    assert got.dtype == x.dtype
-    tol = 1e-2 if dtype == jnp.bfloat16 else 1e-5
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), atol=tol)
+def test_forward_and_all_gradients_match_autodiff(dtype, relu, with_z,
+                                                  affine):
+    """The forward and the hand-written backward (``_bwd_ref``: the mask
+    in the gradient's own dtype, two reductions for four per-channel
+    cotangents) against autodiff of the plain composition, in every
+    combination both ResNet cells run and the ones they do not."""
+    x, z, mean, invstd, w, b = _operands(dtype=dtype, seed=1)
+    args = {"x": x, "mean": mean, "invstd": invstd}
+    if affine:
+        args.update(scale=w, bias=b)
+    if with_z:
+        args["z"] = z
 
+    def loss(fn, a):
+        out = fn(a["x"], a["mean"], a["invstd"], a.get("scale"),
+                 a.get("bias"), a.get("z"), relu)
+        assert out.dtype == dtype
+        # a cotangent that differs element by element
+        return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
 
-def test_kernel_interpret_gradient_parity_all_inputs():
-    x, z, mean, invstd, w, b = _operands(seed=1)
-
-    def loss(interp, xx, mm, ii, ww, bb, zz):
-        return jnp.sum(bn_relu_residual(xx, mm, ii, ww, bb, z=zz,
-                                        relu=True, interpret=interp) ** 2)
-
-    g_k = jax.grad(functools.partial(loss, True),
-                   argnums=(0, 1, 2, 3, 4, 5))(x, mean, invstd, w, b, z)
-    g_r = jax.grad(functools.partial(loss, False),
-                   argnums=(0, 1, 2, 3, 4, 5))(x, mean, invstd, w, b, z)
-    for a, r in zip(g_k, g_r):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
-                                   atol=1e-4, rtol=1e-4)
+    (_, got), g_got = jax.value_and_grad(
+        functools.partial(loss, bn_relu_residual), has_aux=True)(args)
+    (_, want), g_want = jax.value_and_grad(
+        functools.partial(loss, _plain_epilogue), has_aux=True)(args)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    assert set(g_got) == set(args)
+    for name in args:
+        assert g_got[name].dtype == g_want[name].dtype == args[name].dtype
+        assert g_got[name].shape == args[name].shape
+        # x and z gradients are rounded to bf16 last; the per-channel
+        # sums are float32 on both sides and differ by summation order
+        tol = 1e-2 if g_got[name].dtype == jnp.bfloat16 else 2e-5
+        np.testing.assert_allclose(np.asarray(g_got[name], np.float32),
+                                   np.asarray(g_want[name], np.float32),
+                                   rtol=tol, atol=tol, err_msg=name)
 
 
 def test_custom_vjp_exact_through_full_bn():
@@ -137,56 +142,11 @@ def test_sync_batchnorm_tail_routes_through_epilogue():
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5)
 
 
-def test_dispatch_gates(monkeypatch):
-    """Off-TPU the dispatch always takes jnp, forced or not; on the TPU
-    the kernel runs only when forced, and the width gate keeps blocks
-    whose 8-row floor exceeds scoped VMEM off it even then."""
-    assert not _dispatch_pallas(256, None, 4)            # no TPU backend
-    assert not _dispatch_pallas(256, "pallas", 4)
-    with pytest.raises(ValueError, match="impl"):
-        _dispatch_pallas(8, "mosaic", 4)
-    assert _kernel_fits(256, 4)
-    assert not _kernel_fits(10 ** 6, 4)                  # 8-row floor OOM
-    monkeypatch.setattr(fused_bn_act, "_use_pallas", lambda: True)
-    assert _dispatch_pallas(256, "pallas", 4)
-    assert not _dispatch_pallas(256, "jnp", 4)
-    assert not _dispatch_pallas(10 ** 6, "pallas", 4)
-
-
-CHIP_SMOKE = load_chip_smoke()
-
-
-@pytest.mark.parametrize("rows,c,has_z", CHIP_SMOKE.RESNET50_BN)
-def test_resnet50_site_takes_xla_unless_forced(rows, c, has_z, monkeypatch):
-    """Every BN site of the ResNet-50 step at b256 (chip_smoke lists
-    them by their rows at its own batch), bf16, as if on the TPU: the
-    automatic choice is XLA, ``impl="pallas"`` is still the kernel
-    (PERF.md section 6, PR 26)."""
-    monkeypatch.setattr(fused_bn_act, "_use_pallas", lambda: True)
-    assert not _dispatch_pallas(c, None, 2)
-    assert _dispatch_pallas(c, "pallas", 2)
-    hw = int(round((rows // CHIP_SMOKE.BATCH_PER_CHIP) ** 0.5))
-    x = jax.ShapeDtypeStruct((256, hw, hw, c), jnp.bfloat16)
-    vec = jax.ShapeDtypeStruct((c,), jnp.float32)
-
-    def site(impl, x, mean, invstd, scale, bias):
-        return bn_relu_residual(x, mean, invstd, scale, bias,
-                                z=x if has_z else None, impl=impl)
-
-    auto = str(jax.make_jaxpr(functools.partial(site, None))(
-        x, vec, vec, vec, vec))
-    forced = str(jax.make_jaxpr(functools.partial(site, "pallas"))(
-        x, vec, vec, vec, vec))
-    assert "pallas_call" not in auto and "pallas_call" in forced
-
-
 @pytest.mark.parametrize("with_z", [True, False])
-def test_xla_side_issues_no_activation_sized_reshape_or_pad(with_z,
-                                                            monkeypatch):
+def test_xla_side_issues_no_activation_sized_reshape_or_pad(with_z):
     """W = 28 is no multiple of the bf16 sublane tile, so on the TPU an
-    ``[N,H,W,C] -> [rows,C]`` reshape is a physical copy: the automatic
-    path, forward and backward, must stay on the NHWC array."""
-    monkeypatch.setattr(fused_bn_act, "_use_pallas", lambda: True)
+    ``[N,H,W,C] -> [rows,C]`` reshape is a physical copy: the epilogue,
+    forward and backward, must stay on the NHWC array."""
     x = jnp.ones((2, 28, 28, 16), jnp.bfloat16)
     vec = jnp.ones((16,), jnp.float32)
 
@@ -199,18 +159,18 @@ def test_xla_side_issues_no_activation_sized_reshape_or_pad(with_z,
         loss, argnums=(0, 1, 2, 3, 4, 5)))(x, vec, vec, vec, vec, x)
     assert "pallas_call" not in str(closed)
     assert not activation_sized(closed.jaxpr, ("reshape", "pad"), x.size)
-    # the check can see one: the forced kernel reshapes to [rows, C]
-    forced = jax.make_jaxpr(lambda x: bn_relu_residual(
-        x, vec, vec, vec, vec, impl="pallas"))(x)
-    assert activation_sized(forced.jaxpr, ("reshape",), x.size)
+    # the check can see one
+    flat = jax.make_jaxpr(lambda x: bn_relu_residual(
+        x.reshape(-1, 16), vec, vec, vec, vec))(x)
+    assert activation_sized(flat.jaxpr, ("reshape",), x.size)
 
 
 @pytest.mark.parametrize("with_z", [True, False])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_nhwc_xla_side_matches_reference_and_kernel(with_z, dtype):
+def test_nhwc_xla_side_matches_reference(with_z, dtype):
     """Value and the six cotangents of the NHWC jnp epilogue (custom
     VJP, two reductions) against plain autodiff of
-    ``bn_act_epilogue_ref`` and against the interpreted kernel."""
+    ``bn_act_epilogue_ref``."""
     x, z, mean, invstd, w, b = _operands(c=16, dtype=dtype, seed=6)
     zz = z if with_z else None
     nargs = 6 if with_z else 5
@@ -220,76 +180,61 @@ def test_nhwc_xla_side_matches_reference_and_kernel(with_z, dtype):
         return jnp.sum(jnp.sin(out.astype(jnp.float32)))
 
     args = (x, mean, invstd, w, b) + ((zz,) if with_z else ())
-    sides = {
-        "xla": bn_relu_residual,
-        "ref": bn_act_epilogue_ref,
-        "kernel": functools.partial(bn_relu_residual, interpret=True),
-    }
+    sides = {"xla": bn_relu_residual, "ref": bn_act_epilogue_ref}
     got = {name: jax.value_and_grad(functools.partial(loss, fn),
                                     argnums=tuple(range(nargs)))(*args)
            for name, fn in sides.items()}
     tol = 5e-2 if dtype == jnp.bfloat16 else 1e-4
-    for other in ("ref", "kernel"):
-        np.testing.assert_allclose(float(got["xla"][0]),
-                                   float(got[other][0]), rtol=tol)
-        for a, r in zip(got["xla"][1], got[other][1]):
-            assert a.shape == r.shape and a.dtype == r.dtype
-            np.testing.assert_allclose(np.asarray(a, np.float32),
-                                       np.asarray(r, np.float32),
-                                       atol=tol, rtol=tol, err_msg=other)
-
-
-def _tiny_resnet(fused_epilogue):
-    from apex_tpu.models import ResNet18
-    return ResNet18(num_classes=10, dtype=jnp.float32, sync_bn=True,
-                    fused_epilogue=fused_epilogue)
+    np.testing.assert_allclose(float(got["xla"][0]), float(got["ref"][0]),
+                               rtol=tol)
+    for a, r in zip(got["xla"][1], got["ref"][1]):
+        assert a.shape == r.shape and a.dtype == r.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(r, np.float32),
+                                   atol=tol, rtol=tol)
 
 
 def test_resnet_norm_factory_fused_matches_explicit():
     """The block rewiring is routing, not math: a SyncBatchNorm ResNet
-    with the fused chains must match the explicit relu/add statements
-    on the SAME parameters — forward and grads."""
+    (chains through the norm's epilogue) must match the plain
+    ``nn.BatchNorm`` one (explicit relu/add statements) on the SAME
+    parameters — loss and gradients."""
+    from apex_tpu.models import ResNet18
+    from apex_tpu.parallel import adopt_batchnorm_stats
+
     rng = np.random.RandomState(4)
     x = jnp.asarray(rng.randn(2, 32, 32, 3), jnp.float32)
-    m_fused, m_plain = _tiny_resnet(None), _tiny_resnet(False)
-    variables = m_fused.init(jax.random.PRNGKey(0), x, train=True)
-    # identical param/stat trees: the hook changes no module names
-    v2 = m_plain.init(jax.random.PRNGKey(0), x, train=True)
-    assert (jax.tree_util.tree_structure(variables)
-            == jax.tree_util.tree_structure(v2))
+    m_fused = ResNet18(num_classes=10, num_filters=8, sync_bn=True)
+    m_plain = ResNet18(num_classes=10, num_filters=8)
+    variables = m_plain.init(jax.random.PRNGKey(0), x, train=True)
+    stats_plain = variables["batch_stats"]
+    stats_fused = adopt_batchnorm_stats(stats_plain)
+    # identical param trees: the hook changes no module names
+    v2 = jax.eval_shape(lambda: m_fused.init(jax.random.PRNGKey(0), x,
+                                             train=True))
+    assert (jax.tree_util.tree_structure(variables["params"])
+            == jax.tree_util.tree_structure(v2["params"]))
+    assert (jax.tree_util.tree_structure(stats_fused)
+            == jax.tree_util.tree_structure(v2["batch_stats"]))
 
-    def fwd(model, p):
-        y, upd = model.apply({"params": p,
-                              "batch_stats": variables["batch_stats"]},
-                             x, train=True, mutable=["batch_stats"])
-        return jnp.sum(y ** 2), upd
+    def fwd(model, stats, p):
+        y, _ = model.apply({"params": p, "batch_stats": stats},
+                           x, train=True, mutable=["batch_stats"])
+        return jnp.sum(y ** 2)
 
-    (y_f, upd_f), g_f = jax.value_and_grad(
-        lambda p: fwd(m_fused, p), has_aux=True)(variables["params"])
-    (y_p, upd_p), g_p = jax.value_and_grad(
-        lambda p: fwd(m_plain, p), has_aux=True)(variables["params"])
-    np.testing.assert_allclose(float(y_f), float(y_p), rtol=1e-6)
+    y_f, g_f = jax.jit(jax.value_and_grad(
+        functools.partial(fwd, m_fused, stats_fused)))(variables["params"])
+    y_p, g_p = jax.jit(jax.value_and_grad(
+        functools.partial(fwd, m_plain, stats_plain)))(variables["params"])
+    np.testing.assert_allclose(float(y_f), float(y_p), rtol=1e-5)
     for a, r in zip(jax.tree_util.tree_leaves(g_f),
                     jax.tree_util.tree_leaves(g_p)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(r),
-                                   atol=1e-5, rtol=1e-4)
-    for a, r in zip(jax.tree_util.tree_leaves(upd_f),
-                    jax.tree_util.tree_leaves(upd_p)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
-                                   atol=1e-5, rtol=1e-4)
-
-
-def test_resnet_fused_epilogue_requires_capable_norm():
-    from apex_tpu.models import ResNet18
-
-    model = ResNet18(num_classes=10, fused_epilogue=True)  # plain BN
-    x = jnp.ones((1, 32, 32, 3))
-    with pytest.raises(ValueError, match="fuse_relu"):
-        model.init(jax.random.PRNGKey(0), x, train=True)
+                                   atol=1e-4, rtol=1e-3)
 
 
 def test_resnet_groupbn_norm_cls_end_to_end():
-    """The imagenet --fused-bn wiring: ResNet over
+    """The imagenet example's wiring: ResNet over
     contrib.groupbn.BatchNorm2d_NHWC trains a step and keeps its
     keep-bn-fp32-friendly param paths (bn*/bn/scale)."""
     from apex_tpu.contrib.groupbn import BatchNorm2d_NHWC

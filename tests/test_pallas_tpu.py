@@ -538,66 +538,7 @@ def test_layer_norm_dispatch_structural():
 # ``check_against_reference`` compiles the spec's tune case with Mosaic
 # (forward AND backward through the custom VJP), asserts the kernel — not
 # the jnp path — is what was traced, and compares with the module's jnp
-# reference within the spec's tolerance.  Shapes are the ones the
-# ResNet-50 amp-O2 step dispatches at batch 128 per chip.
-
-@pytest.mark.parametrize("rows,channels,residual", [
-    (128 * 112 * 112, 64, False),      # stem bn+relu (lane-sparse C)
-    (128 * 56 * 56, 256, True),        # stage-1 bottleneck tail
-    (128 * 7 * 7, 2048, True),         # stage-4 bottleneck tail
-])
-def test_bn_relu_residual_resnet50_shapes_on_chip(rows, channels, residual):
-    from apex_tpu.tune.measure import check_against_reference
-
-    check_against_reference("bn_relu_residual", {
-        "rows": rows, "channels": channels, "residual": residual,
-        "dtype": "bfloat16"})
-
-
-@pytest.mark.parametrize("h,cin,cout,k", [
-    (56, 64, 64, 3), (56, 64, 256, 1),         # OW 56
-    (28, 128, 128, 3), (28, 512, 128, 1),      # OW 28
-    (14, 256, 256, 3), (14, 256, 1024, 1),     # OW 14
-    (7, 512, 512, 3), (7, 2048, 512, 1),       # OW 7
-])
-def test_conv2d_resnet50_stride1_sites_on_chip(h, cin, cout, k):
-    """Bare convs (what ``PallasConv`` dispatches), fwd + dgrad + wgrad."""
-    from apex_tpu.tune.measure import check_against_reference
-
-    check_against_reference("conv2d", {
-        "batch": 128, "h": h, "w": h, "cin": cin, "cout": cout, "kh": k,
-        "kw": k, "stride": 1, "dtype": "bfloat16", "epilogue": False})
-
-
-def test_conv2d_fused_epilogue_on_chip():
-    """The registered example shape: conv + bn + residual + relu fused."""
-    from apex_tpu.tune import registry
-    from apex_tpu.tune.measure import check_against_reference
-
-    spec = registry.get_spec("conv2d")
-    check_against_reference(spec, spec.example_shape)
-
-
-def test_conv2d_strided_site_takes_xla_on_chip():
-    """Mosaic refuses the kernel's strided taps ("Only 2D gather is
-    supported"), so a stride-2 site must lower WITHOUT a custom call —
-    and be counted as a ``stride`` fallback, not as a pallas site."""
-    from apex_tpu.ops import (PallasConv, conv_dispatch_stats,
-                              reset_conv_dispatch_stats)
-
-    conv = PallasConv(128, (3, 3), strides=2, use_bias=False,
-                      dtype=jnp.bfloat16)
-    x = jax.ShapeDtypeStruct((128, 56, 56, 128), jnp.bfloat16)
-    variables = jax.eval_shape(
-        lambda: conv.init(jax.random.PRNGKey(0),
-                          jnp.zeros(x.shape, x.dtype)))
-    reset_conv_dispatch_stats()
-    text = jax.jit(conv.apply).lower(variables, x).as_text()
-    assert "tpu_custom_call" not in text
-    stats = conv_dispatch_stats()
-    assert stats["pallas_sites"] == 0
-    assert stats["fallback_reasons"] == {"stride": 1}
-
+# reference within the spec's tolerance.
 
 def test_quantized_matmul_on_chip():
     from apex_tpu.tune import registry

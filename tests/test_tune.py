@@ -63,7 +63,6 @@ def _fresh_reload(path):
 
 def test_space_is_the_one_home_for_vmem_math():
     import importlib
-    fba = importlib.import_module("apex_tpu.normalization.fused_bn_act")
     # (the package __init__ re-exports the FUNCTION under this name)
     fln = importlib.import_module(
         "apex_tpu.normalization.fused_layer_norm")
@@ -71,14 +70,12 @@ def test_space_is_the_one_home_for_vmem_math():
     # the kernel aliases ARE the shared constants
     assert fln._VMEM_BUDGET_BYTES == space.VMEM_BUDGET_BYTES
     assert fln._SUBLANE_ROWS == space.SUBLANE_ROWS
-    # and both kernels' row pickers delegate to the same function
+    # and the kernel's row picker delegates to the same function
     for n1, n2, bpe in ((32768, 768, 22), (32768, 4096, 22),
                        (4, 768, 22), (32768, 16384, 28)):
         assert fln._pick_rows(n1, n2, bpe) == space.pick_rows(n1, n2, bpe)
-        assert fba._pick_rows(n1, n2, bpe) == space.pick_rows(n1, n2, bpe)
     # width gate equivalence (fused_layer_norm's bwd footprint)
     assert fln._kernel_max_width(4) == space.max_width(3 * 4 + 16)
-    assert fba._kernel_fits(1024, 2) == space.floor_block_fits(1024, 14)
 
 
 def test_space_row_block_candidates_dedupe_clamped_blocks():
@@ -132,7 +129,7 @@ def test_corrupt_cache_falls_back_loudly_once(tune_cache, capsys):
     with open(tune_cache, "w") as f:
         f.write('{"schema": 1, "entries": {TRUNCATED')
     assert store.lookup("fused_layer_norm", 1, "b", path=tune_cache) is None
-    assert store.lookup("bn_relu_residual", 1, "b", path=tune_cache) is None
+    assert store.lookup("xentropy", 1, "b", path=tune_cache) is None
     err = capsys.readouterr().err
     # loudly: the fallback is announced; once: a single line for both
     assert err.count("falling back to built-in default configs") == 1
@@ -318,24 +315,6 @@ def test_layer_norm_dispatch_is_bitwise_with_tuned_config(tune_cache):
     stats = dispatch.dispatch_stats()["by_kernel"]["fused_layer_norm"]
     assert stats["hits"] >= 1 and stats["tuned"]
     np.testing.assert_array_equal(np.asarray(base), np.asarray(tuned))
-
-
-def test_bn_relu_dispatch_is_bitwise_with_tuned_config(tune_cache):
-    from apex_tpu.normalization.fused_bn_act import (TUNE_VERSION,
-                                                     bn_relu_residual,
-                                                     tune_bucket)
-    x = jnp.linspace(-3, 3, 64 * 128, dtype=jnp.float32).reshape(64, 128)
-    z = jnp.flip(x, axis=0)
-    mean = jnp.linspace(-0.2, 0.2, 128)
-    invstd = jnp.linspace(0.8, 1.2, 128)
-    base = bn_relu_residual(x, mean, invstd, z=z, interpret=True)
-    store.put("bn_relu_residual", TUNE_VERSION,
-              tune_bucket(64, 128, 4, True), {"row_block": 8},
-              path=tune_cache)
-    tuned = bn_relu_residual(x, mean, invstd, z=z, interpret=True)
-    np.testing.assert_array_equal(np.asarray(base), np.asarray(tuned))
-    assert dispatch.dispatch_stats()["by_kernel"][
-        "bn_relu_residual"]["tuned"]
 
 
 def test_quantized_matmul_dispatch_is_bitwise_with_tuned_config(tune_cache):

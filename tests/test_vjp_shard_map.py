@@ -8,8 +8,9 @@ the flagship imagenet step down with its default flags while every
 single-device test stayed green.  Each op here is differentiated w.r.t.
 its replicated parameters under ``in_specs=(P(), P("data"))``: on the
 jnp path the gradient must arrive already summed (``out_specs=P()``) and
-equal the single-device one; on the kernel path the same trace must type
-check.  The flagship step itself is lowered with its default flags.
+equal the single-device one; on the kernel path (the ops that have one)
+the same trace must type check.  The flagship step itself is lowered
+with its default flags.
 """
 
 import os
@@ -23,7 +24,6 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu.normalization import bn_relu_residual, fused_layer_norm_affine
-from apex_tpu.ops import conv2d
 from apex_tpu.quant.kernels import quantized_matmul
 
 NDEV = 2
@@ -43,9 +43,10 @@ def _layer_norm(kw):
 
 
 def _bn(kw):
+    """One implementation (jnp under a custom VJP): ``kw`` is not its."""
     def loss(p, xz):
         out = bn_relu_residual(xz[0], p["mean"], p["invstd"], p["scale"],
-                               p["bias"], z=xz[1], **kw)
+                               p["bias"], z=xz[1])
         return jnp.sum(out ** 2)
     params = {"mean": _rand(1, C) * 0.1, "invstd": jnp.abs(_rand(2, C)) + 0.5,
               "scale": _rand(3, C) + 1.0, "bias": _rand(4, C) * 0.1}
@@ -54,8 +55,8 @@ def _bn(kw):
 
 def _bn_nhwc(kw):
     """The BN epilogue as a ResNet site calls it: a 4-D activation
-    sharded on its batch axis.  The XLA side works on it as it is
-    (reductions over axes (0, 1, 2)); only the kernel side reshapes."""
+    sharded on its batch axis, worked on as it is (reductions over axes
+    (0, 1, 2))."""
     loss2d, params, _ = _bn(kw)
     return loss2d, params, (_rand(0, 4, 6, 6, C), _rand(5, 4, 6, 6, C))
 
@@ -67,22 +68,9 @@ def _qmm(kw):
     return loss, {"w": _rand(1, C, C) * 0.1}, _rand(0, ROWS, C)
 
 
-def _conv(kw):
-    o = 16
-
-    def loss(p, x):
-        out = conv2d(x, p["w"], mean=p["mean"], invstd=p["invstd"],
-                     scale=p["scale"], bias=p["bias"], relu=True, **kw)
-        return jnp.sum(out ** 2)
-    params = {"w": _rand(1, 3, 3, 8, o) * 0.2, "mean": _rand(2, o) * 0.1,
-              "invstd": jnp.abs(_rand(3, o)) + 0.5,
-              "scale": _rand(4, o) + 1.0, "bias": _rand(5, o) * 0.1}
-    return loss, params, _rand(0, 4, 8, 8, 8)
-
-
-CASES = pytest.mark.parametrize(
-    "case", [_layer_norm, _bn, _bn_nhwc, _qmm, _conv],
-    ids=lambda f: f.__name__.strip("_"))
+def _cases(*builders):
+    return pytest.mark.parametrize("case", builders,
+                                   ids=lambda f: f.__name__.strip("_"))
 
 
 def _sharded_grad(loss):
@@ -91,7 +79,7 @@ def _sharded_grad(loss):
                      out_specs=P())   # P() out: the grad must carry no vma
 
 
-@CASES
+@_cases(_layer_norm, _bn, _bn_nhwc, _qmm)
 def test_replicated_param_grad_is_summed(case):
     loss, params, x = case({"impl": "jnp"})
     got = jax.jit(_sharded_grad(loss))(params, x)
@@ -102,7 +90,7 @@ def test_replicated_param_grad_is_summed(case):
                                    rtol=2e-5, atol=2e-5, err_msg=name)
 
 
-@CASES
+@_cases(_layer_norm, _qmm)
 def test_kernel_path_types_check_under_vma(case, monkeypatch):
     """The Mosaic path, trace only: ``pallas_call`` binds abstractly, so
     the forward's operand alignment and the backward's cotangent types
@@ -112,8 +100,7 @@ def test_kernel_path_types_check_under_vma(case, monkeypatch):
     The psum that makes the values right is the same ``match_vma`` call
     the jnp path's value test above goes through."""
     import importlib
-    for mod in ("normalization.fused_layer_norm",
-                "normalization.fused_bn_act", "ops.conv", "quant.kernels"):
+    for mod in ("normalization.fused_layer_norm", "quant.kernels"):
         monkeypatch.setattr(importlib.import_module("apex_tpu." + mod),
                             "_use_pallas", lambda: True)
     loss, params, x = case({"impl": "pallas"})
@@ -121,31 +108,10 @@ def test_kernel_path_types_check_under_vma(case, monkeypatch):
     assert "pallas_call" in text and "psum" in text
 
 
-@pytest.mark.parametrize("case", [_bn_nhwc, _conv],
-                         ids=lambda f: f.__name__.strip("_"))
-def test_automatic_dispatch_takes_xla_under_shard_map(case, monkeypatch):
-    """``impl=None`` as if on the TPU, under the mesh: no kernel is
-    traced, and the replicated parameters' gradients arrive summed and
-    equal to the single-device ones (ISSUE 26)."""
-    import importlib
-    for mod in ("normalization.fused_bn_act", "ops.conv"):
-        monkeypatch.setattr(importlib.import_module("apex_tpu." + mod),
-                            "_use_pallas", lambda: True)
-    loss, params, x = case({})
-    assert "pallas_call" not in str(
-        jax.make_jaxpr(_sharded_grad(loss))(params, x))
-    got = jax.jit(_sharded_grad(loss))(params, x)
-    want = jax.grad(loss)(params, x)
-    for name in params:
-        np.testing.assert_allclose(np.asarray(got[name]),
-                                   np.asarray(want[name]),
-                                   rtol=2e-5, atol=2e-5, err_msg=name)
-
-
 def test_imagenet_step_default_flags_lowers_on_mesh(tmp_path):
     """The flagship example's device loop, built by the example itself
-    with its DEFAULT flags (fused BN, Pallas conv, fused loss), lowers
-    under the 8-device mesh.  Trace only — no compile."""
+    with its DEFAULT flags (BN epilogues through the norm, fused loss),
+    lowers under the 8-device mesh.  Trace only — no compile."""
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
                                     "examples", "imagenet"))
     try:

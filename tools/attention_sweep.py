@@ -11,12 +11,12 @@ dispatch rule (and documented in ``docs/attention.md``).
 
 Run on the TPU host (from the sandbox: through ``chiprun``)::
 
-    python tools/attention_sweep.py --out ATTENTION_SWEEP.json
+    python tools/attention_sweep.py --out chiprun_out/attention_sweep.json
 
 Timing policy: min-of-3 passes of ``iters`` fwd+bwd calls, execution
-forced by a scalar fetch of the last output (dispatch is asynchronous —
-see bench.py's honesty contract).  The committed ATTENTION_SWEEP.json
-dates from an earlier installation and has not been re-measured.
+forced by a scalar fetch of the last output (dispatch is asynchronous).
+No sweep of the current installation is on file: the rows the dispatch
+threshold rests on are quoted in ``docs/attention.md``.
 """
 
 from __future__ import annotations
@@ -38,14 +38,16 @@ except ModuleNotFoundError:
 import jax
 import jax.numpy as jnp
 
-# One timing policy, one implementation: reuse bench.py's execution-forcing
-# fetch so the sweep's numbers stay comparable to the bench's.
-from bench import _force  # noqa: E402
+
+def _force(x):
+    """Force execution via one scalar device->host fetch: the device runs
+    enqueued programs in order, so fetching one element of the LAST
+    output drains everything before it."""
+    return float(jnp.ravel(x)[0].astype(jnp.float32))
 
 
 def time_grad(fn, q, k, v, iters=10, reps=3):
-    """Min-of-reps seconds per fwd+bwd call — bench.py's `timed` policy
-    (see _bench_flash_attention) applied to a 3-arg grad."""
+    """Min-of-reps seconds per fwd+bwd call of a 3-arg grad."""
     loss = lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32))
     g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
     out = g(q, k, v)
@@ -56,7 +58,6 @@ def time_grad(fn, q, k, v, iters=10, reps=3):
         for _ in range(iters):
             out = g(q, k, v)
         _force(out[0])
-        # jaxlint: disable=J009 -- fenced by bench._force(out[0]) on the line above; the linter's sync-def resolution is module-local and cannot see through the import
         best = min(best, (time.perf_counter() - t0) / iters)
     return best
 

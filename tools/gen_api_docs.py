@@ -39,8 +39,8 @@ PAGES = {
                  "apex_tpu.parallel.multiproc"],
     "normalization": ["apex_tpu.normalization",
                       "apex_tpu.normalization.fused_bn_act"],
-    "ops": ["apex_tpu.ops.flash_attention", "apex_tpu.ops.conv",
-            "apex_tpu.ops.attention", "apex_tpu.ops.losses"],
+    "ops": ["apex_tpu.ops.flash_attention", "apex_tpu.ops.attention",
+            "apex_tpu.ops.losses"],
     "multi_tensor": ["apex_tpu.multi_tensor"],
     "bf16_utils": ["apex_tpu.bf16_utils"],
     "training": ["apex_tpu.training"],

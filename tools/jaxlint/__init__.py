@@ -14,7 +14,7 @@ J006  Python control flow branching on a traced value under jit
 
 Usage::
 
-    python -m tools.jaxlint apex_tpu examples tools bench.py
+    python -m tools.jaxlint apex_tpu examples tools
 
 Inline waiver (MUST carry a reason)::
 

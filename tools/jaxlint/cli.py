@@ -21,7 +21,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     "docs/jaxlint.md).")
     ap.add_argument("paths", nargs="*",
                     help="files or directory trees to lint "
-                         "(e.g. apex_tpu examples tools bench.py)")
+                         "(e.g. apex_tpu examples tools)")
     ap.add_argument("--list-rules", action="store_true",
                     help="print the rule table and exit")
     ap.add_argument("--select", metavar="CODES", default=None,

@@ -1,8 +1,8 @@
 """jaxlint core — AST rules, waiver handling, and the lint engine.
 
 Rules J001–J015 tuned to this codebase's failure modes (the ones that are
-invisible to pytest and surface as 10x dispatch-floor regressions in
-``bench.py``):
+invisible to pytest and surface as 10x dispatch-floor regressions on
+the chip):
 
 * **J001** host sync in device code: ``jax.device_get`` / ``.item()`` /
   ``.block_until_ready()`` / ``float()/int()/bool()/np.asarray()`` on
@@ -10,7 +10,7 @@ invisible to pytest and surface as 10x dispatch-floor regressions in
   finding unless the enclosing function is on the host-boundary
   allowlist (``state_dict``/``load_state_dict`` — serialization is
   host-side by contract); in driver scripts (``examples/``, ``tools/``,
-  ``bench.py``, ``tests/``) only syncs inside loop bodies are findings
+  ``tests/``) only syncs inside loop bodies are findings
   (a driver legitimately syncs once at the end, but a per-iteration
   sync is the hot-loop stall the ROADMAP's dispatch floors measure).
 * **J002** ``jax.jit`` of a function taking non-array Python args
@@ -109,8 +109,8 @@ invisible to pytest and surface as 10x dispatch-floor regressions in
   time, per-step channel scales are the correct recipe (ISSUE 13).
 * **J015** (advisory) literal block-size overrides at Pallas-kernel
   call sites: a tunable kernel exposing block params
-  (``flash_attention`` / ``bn_relu_residual`` / ``fused_layer_norm`` /
-  ``quantized_matmul``) invoked with an integer LITERAL for
+  (``flash_attention`` / ``fused_layer_norm`` / ``quantized_matmul``)
+  invoked with an integer LITERAL for
   ``block_q``/``block_k``/``block_m``/``block_n``/``row_block``.  The
   literal freezes one sweep's winner for every device kind and shape,
   bypassing the per-device config cache the tune registry dispatches
@@ -1071,9 +1071,8 @@ def _check_j014(tree: ast.Module, path: str) -> List[Finding]:
 #: block override (xentropy is cache-tuned too but its public function
 #: takes no block kwarg, so no literal can appear at a working call
 #: site — listing it would document a parameter that does not exist)
-_J015_KERNEL_CALLS = {"flash_attention", "bn_relu_residual",
-                      "fused_layer_norm", "fused_layer_norm_affine",
-                      "quantized_matmul", "conv2d"}
+_J015_KERNEL_CALLS = {"flash_attention", "fused_layer_norm",
+                      "fused_layer_norm_affine", "quantized_matmul"}
 #: the tuned block-size parameters across the kernel family
 _J015_BLOCK_KWARGS = {"block_q", "block_k", "block_m", "block_n",
                       "row_block"}
@@ -1152,8 +1151,7 @@ def _check_j016(tree: ast.Module, path: str) -> List[Finding]:
                     path, node.lineno, node.col_offset, "J016",
                     "NCHW dimension_numbers at a conv call site — TPUs "
                     "tile the feature axis onto the 128 lanes, so NCHW "
-                    "pays a transpose either side of every conv and "
-                    "walls off the NHWC Pallas conv path; use "
+                    "pays a transpose either side of every conv; use "
                     "('NHWC','HWIO','NHWC')"))
         elif (leaf in _J016_LAX_NCHW_CALLS and len(parts) >= 2
               and parts[-2] == "lax"):
@@ -1163,7 +1161,7 @@ def _check_j016(tree: ast.Module, path: str) -> List[Finding]:
                 f"it has no layout knob and lands the TPU-hostile "
                 f"('NCHW','OIHW','NCHW') spec; call "
                 f"conv_general_dilated with ('NHWC','HWIO','NHWC') or "
-                f"use flax.linen.Conv / apex_tpu.ops.PallasConv"))
+                f"use flax.linen.Conv"))
     return findings
 
 
