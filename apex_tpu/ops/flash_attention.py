@@ -16,12 +16,17 @@ Design:
   ``lse = m + log l`` (one fp32 per row) is what makes the backward
   recompute exact — the same memory trick as the reference's fused
   xentropy kernel (``csrc/xentropy_kernel.cu`` saves max_log_sum_exp).
-* **backward** — two kernels, both recomputing ``p = exp(s - lse)``:
-  ``dq`` iterates KV blocks innermost (accumulating ``ds @ k``), ``dk/dv``
-  iterates Q blocks innermost.  Every matmul is expressed in the natural
+* **backward** — one kernel (``flash_bwd``, ISSUE 30) that recomputes
+  ``p = exp(s - lse)`` and ``ds`` ONCE per live ``(qi, ki)`` tile and
+  takes dq, dk and dv from them: five block products where a dq kernel
+  and a dk/dv kernel, each rebuilding the scores, did seven.  The KV step
+  is innermost (the ``[bq, d]`` dq accumulator's order); dk and dv
+  accumulate in float32 scratch of the whole key length, written once per
+  ``(batch, kv_head)``.  Every matmul is expressed in the natural
   ``[bq, bk]`` orientation with leading-dim contractions where the output
   is K-major, so no operand ever needs a VMEM relayout/transpose.
-  ``delta = rowsum(do * o)`` is a cheap jnp reduction fused by XLA.
+  ``delta = rowsum(do * o)`` is a cheap jnp reduction fused by XLA.  Keys
+  beyond the resident-VMEM budget run the same kernel per KV chunk.
 * causal masking skips fully-masked KV blocks via ``pl.when`` predication,
   and sliding-window local attention goes further with a BOUNDED grid:
   only ``ceil(window/bk)+1`` KV blocks per Q block are even visited
@@ -30,7 +35,7 @@ Design:
   a key-side additive bias ``[batch, kv_len]`` covers padding masks and a
   head-broadcast ``[batch, q_len, kv_len]`` bias covers segment/2-D masks
   and relative-position biases, with its head-summed gradient produced by
-  a dedicated third backward kernel (grid head-innermost so the output
+  a dedicated second backward kernel (grid head-innermost so the output
   block accumulates residently).  A per-head ``[B,H,T,S]`` bias falls
   back to the jnp path.
 * per-row stats (``lse``, ``delta``) travel as ``[B, H, T, 1]`` so kernel
@@ -67,14 +72,25 @@ from ..tune.space import pow2_bucket as _pow2
 NEG_INF = -1e30
 
 #: config-cache version of this kernel family's blocking scheme
-#: (ISSUE 14) — covers the forward AND both backward kernels (they
-#: share block_q/block_k); bump when the grid/block semantics change.
-TUNE_VERSION = 1
-# r4 block-size sweep on the v5e (seq 8k causal fwd+bwd, min-of-3):
-# 512x512 18.45 ms, 1024x512 17.50, 512x1024 16.44, 1024x1024 15.75,
-# 2048x512 17.78, 256x256 27.99 — bigger blocks amortize the per-block
-# mask/softmax epilogue over more MXU work; 1024^2 scores (4 MB fp32)
-# still fit VMEM comfortably beside the operands.
+#: (ISSUE 14) — covers the forward AND the backward kernel (they share
+#: block_q/block_k); bump when the grid/block semantics change.  2: the
+#: fused backward (ISSUE 30).
+TUNE_VERSION = 2
+# Block sweep on this installation's v5e (PR 30; causal bf16, head size
+# 64; device time of one call from the profiler's trace, ms, forward /
+# fused backward, block_q x block_k):
+#   B 8, H 12, T 1,024 (gpt2_small_o2.seq1024's layer):
+#     512x512 0.600 / 0.752   512x1024 0.479 / 0.771
+#     1024x512 0.784 / 0.761  1024x1024 0.334 / 0.777
+#   B 2, H 32 over 8 KV heads, T 4,096 (granite4_h_micro_o2's layer):
+#     512x512 4.34 / 6.04     512x1024 3.10 / 6.01
+#     1024x512 5.05 / 5.70    1024x1024 2.87 / 5.64
+# 256-blocks lose everywhere (host clock: backward +3 to +70%).  The
+# backward is flat within 3% over {512, 1024}^2 at T 1,024 (a 512 grid
+# skips the tile above the diagonal and pays four times the grid steps);
+# the forward is not, and the two share their blocks through the custom
+# VJP's static arguments: 1024 x 1024 wins forward + backward at both
+# shapes (1.11 and 8.51 ms).
 _DEFAULT_BLOCK_Q = 1024
 _DEFAULT_BLOCK_K = 1024
 
@@ -144,7 +160,7 @@ def _causal_block_mask(qi, ki, bq, bk, q_off=0, k_off=0, window=None):
 
 def _block_live(qi, ki, bq, bk, q_off, k_off, window):
     """Whether this (qi, ki) block intersects the causal/window band —
-    the block-skip predicate shared by all four kernels.  Blocks past the
+    the block-skip predicate shared by all three kernels.  Blocks past the
     diagonal AND blocks older than the window are skipped entirely, so
     sliding-window attention costs O(T * window), not O(T^2)."""
     run = q_off + qi * bq + bq - 1 >= k_off + ki * bk        # causal skip
@@ -301,7 +317,7 @@ def _off_arg(offset):
 
 def _off_spec():
     # *_: the offset scalar is grid-invariant for every kernel regardless
-    # of grid rank (the dkv grid is 5-D under GQA, 4-D otherwise).
+    # of grid rank (the backward grid is 5-D under GQA, 4-D otherwise).
     if pltpu is None:  # pragma: no cover
         return pl.BlockSpec((1, 1), lambda *_: (0, 0))
     return pl.BlockSpec((1, 1), lambda *_: (0, 0),
@@ -428,17 +444,56 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kb_ref,
     return p, ds
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   *refs, sm_scale, causal, has_bias, has_bias2, dyn_off,
-                   q_off0, k_off0, window, window_span=None):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                *refs, sm_scale, causal, has_bias, has_bias2, dyn_off,
+                q_off0, k_off0, window, window_span=None, k_blk0=0,
+                has_hg=False):
+    """dq, dk and dv (and the key-bias gradient) from ONE recomputation
+    of ``p`` and ``ds`` per live tile.
+
+    Grid ``(b, h_kv, hg, qi, j)`` with the KV step innermost, which is
+    what the ``[bq, d]`` dq accumulator wants; dk and dv accumulate in
+    float32 scratch of the WHOLE key length (rows ``ki * bk`` onwards per
+    tile), so their ``(1, 1, tk, d)`` output blocks stay resident while
+    ``(b, h_kv)`` is fixed and are written once, after the last
+    ``(hg, qi, j)`` — any visiting order of the KV blocks serves, the
+    bounded sliding-window grid's included.  ``hg`` walks the ``H/H_kv``
+    query heads that share this KV head; plain MHA (``has_hg=False``)
+    drops the dim — grid ``(b, h, qi, j)`` — r4: a singleton grid dim is
+    not free on Mosaic's pipeline.  The refs may hold one chunk of the
+    keys, beginning ``k_blk0`` blocks (``k_off0`` keys) in: the bounded
+    grid walks the band of the WHOLE key length, and the tiles of it that
+    fall outside this chunk are dead."""
     kb_ref, b2_ref, qoff_ref, koff_ref, rest = _opt_refs(
         refs, has_bias, has_bias2, dyn_off)
-    dq_ref, dq_scr = rest
-    j = pl.program_id(3)
-    nk = pl.num_programs(3)
-    qi = pl.program_id(2)
-    ki = j if window_span is None else qi - (window_span - 1) + j
+    if has_bias:
+        dq_ref, dk_ref, dv_ref, db_ref, dq_scr, dk_scr, dv_scr, db_scr = rest
+    else:
+        dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
+        db_ref = db_scr = None
+    ax = 3 if has_hg else 2                      # qi's grid axis
+    qi, j = pl.program_id(ax), pl.program_id(ax + 1)
+    nq, nj = pl.num_programs(ax), pl.num_programs(ax + 1)
+    first = jnp.logical_and(qi == 0, j == 0)
+    last = jnp.logical_and(qi == nq - 1, j == nj - 1)
+    if has_hg:
+        first = jnp.logical_and(first, pl.program_id(2) == 0)
+        last = jnp.logical_and(
+            last, pl.program_id(2) == pl.num_programs(2) - 1)
+    # ki: this tile's KV block WITHIN the chunk
+    ki = j if window_span is None else qi - (window_span - 1) + j - k_blk0
     bq, bk = q_ref.shape[2], k_ref.shape[2]
+    if dk_scr.shape[0] == bk:                    # one KV block: no offset
+        rows = slice(None)
+    else:
+        rows = pl.ds(pl.multiple_of(ki * bk, bk), bk)
+
+    @pl.when(first)
+    def _():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+        if has_bias:
+            db_scr[:] = jnp.zeros_like(db_scr)
 
     @pl.when(j == 0)
     def _():
@@ -448,75 +503,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         qi, ki, bq, bk, causal=causal, dyn_off=dyn_off, qoff_ref=qoff_ref,
         koff_ref=koff_ref, q_off0=q_off0, k_off0=k_off0, window=window,
         window_span=window_span)
-
-    def body(mask):
-        _, ds = _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                delta_ref, kb_ref, b2_ref, mask,
-                                sm_scale=sm_scale, has_bias=has_bias,
-                                has_bias2=has_bias2)
-        dq_scr[:] = dq_scr[:] + _mm(ds.astype(k_ref.dtype), k_ref[0, 0],
-                                    ((1,), (0,)))
-
-    _masked_split(run, body,
-                  lambda: _causal_block_mask(qi, ki, bq, bk, q_off, k_off,
-                                             window))
-
-    @pl.when(j == nk - 1)
-    def _():
-        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    *refs, sm_scale, causal, has_bias, has_bias2, dyn_off,
-                    q_off0, k_off0, window, window_span=None,
-                    n_q_blocks=None, has_hg=False):
-    """Grid ``(b, h_kv, ki, hg, qi)`` under GQA: group member ``hg`` (one
-    of the ``H/H_kv`` query heads sharing this KV head) sweeps OUTSIDE the
-    qi loop, so the (b, h_kv, ki) dk/dv output blocks are revisited only
-    on consecutive steps (resident scratch accumulation over qi AND hg),
-    while the per-q-head db block flushes each time its qi sweep ends.
-    Plain MHA (``has_hg=False``) drops the hg grid dim entirely — grid
-    ``(b, h, ki, qi)`` — r4: a singleton grid dim is not free on Mosaic's
-    pipeline, and the hg predicates fold away statically."""
-    kb_ref, b2_ref, qoff_ref, koff_ref, rest = _opt_refs(
-        refs, has_bias, has_bias2, dyn_off)
-    if has_bias:
-        dk_ref, dv_ref, db_ref, dk_scr, dv_scr, db_scr = rest
-    else:
-        dk_ref, dv_ref, dk_scr, dv_scr = rest
-        db_ref = db_scr = None
-    if has_hg:
-        j = pl.program_id(4)
-        nq = pl.num_programs(4)
-        hg = pl.program_id(3)
-        ng = pl.num_programs(3)
-        first_sweep = jnp.logical_and(j == 0, hg == 0)
-        last_sweep = lambda: jnp.logical_and(j == nq - 1, hg == ng - 1)
-    else:
-        j = pl.program_id(3)
-        nq = pl.num_programs(3)
-        first_sweep = j == 0
-        last_sweep = lambda: j == nq - 1
-    ki = pl.program_id(2)
-    qi = j if window_span is None else ki + j
-    bq, bk = q_ref.shape[2], k_ref.shape[2]
-
-    @pl.when(first_sweep)
-    def _():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    if has_bias:
-        @pl.when(j == 0)
-        def _():
-            db_scr[:] = jnp.zeros_like(db_scr)
-
-    q_off, k_off, run = _offsets_and_predicates(
-        qi, ki, bq, bk, causal=causal, dyn_off=dyn_off, qoff_ref=qoff_ref,
-        koff_ref=koff_ref, q_off0=q_off0, k_off0=k_off0, window=window,
-        window_span=window_span)
-    if causal and window_span is not None:
-        run = jnp.logical_and(run, qi <= n_q_blocks - 1)
+    if window_span is not None:      # the band runs on past this chunk
+        run = jnp.logical_and(run, ki < dk_scr.shape[0] // bk)
 
     def body(mask):
         p, ds = _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -524,29 +512,33 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                 sm_scale=sm_scale, has_bias=has_bias,
                                 has_bias2=has_bias2)
         do = do_ref[0, 0]
+        dq_scr[:] = dq_scr[:] + _mm(ds.astype(k_ref.dtype), k_ref[0, 0],
+                                    ((1,), (0,)))
         # K-major outputs via leading-dim contraction — no transposes.
-        dv_scr[:] = dv_scr[:] + _mm(p.astype(do.dtype), do,
-                                    ((0,), (0,)))            # [bk, d]
-        dk_scr[:] = dk_scr[:] + _mm(ds.astype(q_ref.dtype), q_ref[0, 0],
-                                    ((0,), (0,)))            # [bk, d]
+        dv_scr[rows, :] = dv_scr[rows, :] + _mm(p.astype(do.dtype), do,
+                                                ((0,), (0,)))    # [bk, d]
+        dk_scr[rows, :] = dk_scr[rows, :] + _mm(ds.astype(q_ref.dtype),
+                                                q_ref[0, 0],
+                                                ((0,), (0,)))    # [bk, d]
         if has_bias:
             # d(loss)/d(bias) column-sum: ds carries an extra sm_scale
             # factor (it is dL/ds * sm_scale for the dq/dk matmuls), which
             # the caller divides back out.
-            db_scr[:] = db_scr[:] + jnp.sum(ds, axis=0, keepdims=True)
+            db_scr[ki] = db_scr[ki] + jnp.sum(ds, axis=0, keepdims=True)
 
     _masked_split(run, body,
                   lambda: _causal_block_mask(qi, ki, bq, bk, q_off, k_off,
                                              window))
 
-    @pl.when(last_sweep())
+    @pl.when(j == nj - 1)
+    def _():
+        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+
+    @pl.when(last)
     def _():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
-
-    if has_bias:
-        @pl.when(j == nq - 1)
-        def _():
+        if has_bias:
             db_ref[0, 0] = db_scr[:]
 
 
@@ -558,7 +550,7 @@ def _bwd_db2_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     revisited on consecutive grid steps only, so the VMEM scratch
     accumulates across heads and flushes once — Pallas TPU does not
     re-fetch an output window revisited non-consecutively, which rules out
-    accumulating this in the dkv kernel (whose grid has h outermost)."""
+    accumulating this in ``_bwd_kernel`` (whose grid has h outermost)."""
     kb_ref, b2_ref, qoff_ref, koff_ref, rest = _opt_refs(
         refs, has_bias, True, dyn_off)
     db2_ref, db2_scr = rest
@@ -596,6 +588,36 @@ def _bwd_db2_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         db2_ref[0] = db2_scr[:] * (1.0 / sm_scale)
 
 
+def _vmem_capacity():
+    """VMEM bytes of one core of the device the kernels compile for; off
+    the TPU (interpret mode, a rehearsal compile for a described chip)
+    there is none to ask, and the answer is a v5e's."""
+    try:
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    except Exception:
+        return 128 * 2**20
+
+
+def _bwd_kv_chunk(tk, d, itemsize, block_k):
+    """``(keys, vmem_limit_bytes)`` of one ``flash_bwd`` call.  What a
+    call holds for all of its keys is the two float32 dk/dv accumulators
+    and the two double-buffered dk/dv output blocks, the head dim padded
+    to whole 128-lane rows.  Keys: as many as keep that within a quarter
+    of the core's VMEM (16,384 in bf16 on a v5e's 128 MiB, at head size
+    64 or 128 alike), in whole KV blocks and at least one; more keys than
+    that go through the same kernel one chunk at a time.  Scoped-VMEM
+    limit: those residents plus 12 MiB for the recompute's ``[bq, bk]``
+    tiles and the operand blocks up to 1024 x 1024 (8 MiB is short by 0.6
+    at 16,384 keys; rehearsal compiles for a v5e), never under Mosaic's
+    16 MiB default, which is the limit at T 1,024 (20 MiB at 4,096
+    keys)."""
+    cap = _vmem_capacity()
+    per_block = block_k * (-(-d // 128) * 128) * (2 * 4 + 2 * 2 * itemsize)
+    blocks = min(tk // block_k, max(1, cap // 4 // per_block))
+    limit = max(16 * 2**20, 12 * 2**20 + blocks * per_block)
+    return blocks * block_k, min(cap, limit)
+
+
 def _flash_bwd_pallas(q, k, v, kbias, out, lse, do, *, sm_scale, causal,
                       block_q, block_k, q_offset=0, k_offset=0,
                       delta=None, qk_bias=None, window=None,
@@ -617,26 +639,20 @@ def _flash_bwd_pallas(q, k, v, kbias, out, lse, do, *, sm_scale, causal,
         delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1, keepdims=True)              # [B, H, Tq, 1]
 
-    span = _window_span(window, block_q, block_k, q_offset, k_offset, nk)
-    if span is None:
-        _kc = lambda qi, j: j                      # real == grid index
-        _qc = lambda ki, j: j
-    else:
-        _kc = lambda qi, j: jnp.maximum(qi - (span - 1) + j, 0)
-        _qc = lambda ki, j: jnp.minimum(ki + j, nq - 1)
     _hk = (lambda h: h) if grp == 1 else (lambda h: h // grp)
 
-    # Conditional operand assembly (r4): the plain causal path ships no
-    # bias dummies and no offset scalars.  vma-aligned as in the fwd.
-    ins = [q, k, v, do, lse, delta]
-    if has_bias:
-        ins.append(kbias[:, None, :])
-    if has_bias2:
-        ins.append(qk_bias)
-    if dyn_off:
-        ins += [_off_arg(q_offset), _off_arg(k_offset)]
-    ins = list(_align_vma(*ins))
-    q, k, v = ins[0], ins[1], ins[2]
+    def operands(k, v, kbias, qk_bias, k_offset):
+        """Conditional operand assembly (r4): the plain causal path ships
+        no bias dummies and no offset scalars.  vma-aligned as in the
+        fwd."""
+        ins = [q, k, v, do, lse, delta]
+        if has_bias:
+            ins.append(kbias[:, None, :])
+        if has_bias2:
+            ins.append(qk_bias)
+        if dyn_off:
+            ins += [_off_arg(q_offset), _off_arg(k_offset)]
+        return list(_align_vma(*ins))
 
     def specs(gridargs_to_bqk):
         """Build the common in_specs; ``gridargs_to_bqk`` maps this
@@ -662,81 +678,113 @@ def _flash_bwd_pallas(q, k, v, kbias, out, lse, do, *, sm_scale, causal,
                 (1, block_q, block_k), ix(lambda b, qi, ki, h: (b, qi, ki))))
         if dyn_off:
             out += [_off_spec(), _off_spec()]
-        return out, qix, kix
+        return out, qix
 
-    flags = dict(sm_scale=sm_scale, causal=causal, has_bias=has_bias,
-                 dyn_off=dyn_off, q_off0=q_off0, k_off0=k_off0,
-                 window=window)
-    in_specs, qix, _ = specs(lambda b, h, qi, j: (b, qi, _kc(qi, j), h))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, has_bias2=has_bias2,
-                          window_span=span, **flags),
-        grid=(b, h, nq, span if span is not None else nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, block_q, d), qix),
-        out_shape=_sds((b, h, tq, d), q.dtype, q, k, v, do),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(*ins)
+    # Keys beyond the resident budget: the same kernel per KV chunk, dq
+    # leaving each call in float32 and summed (the sum ring attention
+    # makes over its steps).  One chunk is the common case: the loop runs
+    # once and dq leaves the kernel in its own dtype.
+    chunk, vmem_limit = _bwd_kv_chunk(tk, d, q.dtype.itemsize, block_k)
+    dq_dtype = q.dtype if chunk == tk else jnp.float32
+    # The bounded window grid walks `span` KV blocks of the WHOLE key
+    # length per Q block; a chunk runs the tiles of that band it holds.
+    span = _window_span(window, block_q, block_k, q_offset, k_offset, nk)
 
-    # dkv grid: (b, h_kv, ki, hg, qi) under GQA — the hg dim walks the grp
-    # query heads sharing each KV head; plain MHA drops the singleton hg
-    # dim entirely (r4, see kernel doc).
-    has_hg = grp > 1
-    if has_hg:
-        in_specs, _, kix = specs(
-            lambda b, hk, ki, hg, j: (b, _qc(ki, j), ki, hk * grp + hg))
-        dkv_grid = (b, h_kv, nk, grp, span if span is not None else nq)
-        db_ix = lambda b, hk, ki, hg, j: (b, hk * grp + hg, 0, ki)
-    else:
-        in_specs, _, kix = specs(
-            lambda b, hk, ki, j: (b, _qc(ki, j), ki, hk))
-        dkv_grid = (b, h_kv, nk, span if span is not None else nq)
-        db_ix = lambda b, hk, ki, j: (b, hk, 0, ki)
-    out_specs = [pl.BlockSpec((1, 1, block_k, d), kix),
-                 pl.BlockSpec((1, 1, block_k, d), kix)]
-    out_shape = [_sds((b, h_kv, tk, d), k.dtype, q, k, v, do),
-                 _sds((b, h_kv, tk, d), v.dtype, q, k, v, do)]
-    scratch = [pltpu.VMEM((block_k, d), jnp.float32),
-               pltpu.VMEM((block_k, d), jnp.float32)]
+    def fused(start):
+        """One ``flash_bwd`` call over the ``chunk`` keys from ``start``:
+        (dq, dk, dv, db_part)."""
+        rows = slice(start, min(start + chunk, tk))
+        ins = operands(k[:, :, rows], v[:, :, rows],
+                       kbias[:, rows] if has_bias else None,
+                       qk_bias[:, :, rows] if has_bias2 else None,
+                       k_offset + start)
+        like = ins[:4]
+        tkc = ins[1].shape[2]
+        nkc, k_blk0 = tkc // block_k, start // block_k
+        if span is None:
+            nj = nkc
+            _kc = lambda qi, j: j                  # real == grid index
+        else:           # clamped real block for a virtual or foreign ki
+            nj = span
+            _kc = lambda qi, j: jnp.clip(qi - (span - 1) + j - k_blk0,
+                                         0, nkc - 1)
+        # grid (b, h_kv, hg, qi, j) under GQA — hg walks the grp query
+        # heads sharing each KV head; plain MHA drops the singleton hg
+        # dim entirely (r4, see kernel doc).
+        has_hg = grp > 1
+        if has_hg:
+            grid = (b, h_kv, grp, nq, nj)
+            in_specs, qix = specs(
+                lambda b, hk, hg, qi, j: (b, qi, _kc(qi, j), hk * grp + hg))
+            kvix = lambda b, hk, hg, qi, j: (b, hk, 0, 0)
+            dbix = lambda b, hk, hg, qi, j: (b, hk, 0, 0, 0)
+        else:
+            grid = (b, h, nq, nj)
+            in_specs, qix = specs(
+                lambda b, h, qi, j: (b, qi, _kc(qi, j), h))
+            kvix = lambda b, h, qi, j: (b, h, 0, 0)
+            dbix = lambda b, h, qi, j: (b, h, 0, 0, 0)
+        out_specs = [pl.BlockSpec((1, 1, block_q, d), qix),
+                     pl.BlockSpec((1, 1, tkc, d), kvix),
+                     pl.BlockSpec((1, 1, tkc, d), kvix)]
+        out_shape = [_sds((b, h, tq, d), dq_dtype, *like),
+                     _sds((b, h_kv, tkc, d), k.dtype, *like),
+                     _sds((b, h_kv, tkc, d), v.dtype, *like)]
+        scratch = [pltpu.VMEM((block_q, d), jnp.float32),
+                   pltpu.VMEM((tkc, d), jnp.float32),
+                   pltpu.VMEM((tkc, d), jnp.float32)]
+        if has_bias:
+            # Per-(batch, KV-head) bias-gradient partials, one row per KV
+            # block; summed over heads (and un-scaled) by the caller.
+            out_specs.append(pl.BlockSpec((1, 1, nkc, 1, block_k), dbix))
+            out_shape.append(
+                _sds((b, h_kv, nkc, 1, block_k), jnp.float32, *like))
+            scratch.append(pltpu.VMEM((nkc, 1, block_k), jnp.float32))
+        outs = pl.pallas_call(
+            functools.partial(
+                _bwd_kernel, sm_scale=sm_scale, causal=causal,
+                has_bias=has_bias, has_bias2=has_bias2, dyn_off=dyn_off,
+                q_off0=q_off0, k_off0=k_off0 + start, window=window,
+                window_span=span, k_blk0=k_blk0, has_hg=has_hg),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            interpret=interpret,
+            name="flash_bwd",
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=vmem_limit),
+        )(*ins)
+        return tuple(outs) if has_bias else (*outs, None)
+
+    dqs, dks, dvs, dbs = zip(*(fused(s) for s in range(0, tk, chunk)))
+    dq = functools.reduce(jnp.add, dqs).astype(q.dtype)
+    dk = jnp.concatenate(dks, axis=2)
+    dv = jnp.concatenate(dvs, axis=2)
+    db_part = jnp.concatenate(dbs, axis=2) if has_bias else None
+    dbias = None
     if has_bias:
-        # Per-(batch, q-head) bias-gradient partials; summed over heads
-        # (and un-scaled) by the caller.
-        out_specs.append(pl.BlockSpec((1, 1, 1, block_k), db_ix))
-        out_shape.append(_sds((b, h, 1, tk), jnp.float32, q, k, v, do))
-        scratch.append(pltpu.VMEM((1, block_k), jnp.float32))
-    outs = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, has_bias2=has_bias2,
-                          window_span=span, n_q_blocks=nq, has_hg=has_hg,
-                          **flags),
-        grid=dkv_grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*ins)
-    if has_bias:
-        dk, dv, db_part = outs
-        dbias = (jnp.sum(db_part[:, :, 0, :], axis=1)
+        dbias = (jnp.sum(db_part.reshape(b, h_kv, tk), axis=1)
                  / sm_scale).astype(kbias.dtype)             # [B, S]
-    else:
-        dk, dv = outs
-        dbias = None
 
     dbias2 = None
     if has_bias2:
         # db2 ALWAYS uses the full masked grid: its output is the dense
         # [B, Tq, Tk] bias gradient, and out-of-band blocks must be
         # WRITTEN (as zeros) — a bounded grid would leave them undefined.
-        in_specs, _, _ = specs(lambda b, qi, ki, h: (b, qi, ki, h))
+        in_specs, _ = specs(lambda b, qi, ki, h: (b, qi, ki, h))
+        ins = operands(k, v, kbias, qk_bias, k_offset)
         dbias2 = pl.pallas_call(
-            functools.partial(_bwd_db2_kernel, window_span=None, **flags),
+            functools.partial(
+                _bwd_db2_kernel, sm_scale=sm_scale, causal=causal,
+                has_bias=has_bias, dyn_off=dyn_off, q_off0=q_off0,
+                k_off0=k_off0, window=window, window_span=None),
             grid=(b, nq, nk, h),
             in_specs=in_specs,            # h INNERMOST — see kernel doc
             out_specs=pl.BlockSpec((1, block_q, block_k),
                                    lambda b, qi, ki, h: (b, qi, ki)),
-            out_shape=_sds((b, tq, tk), jnp.float32, q, k, v, do),
+            out_shape=_sds((b, tq, tk), jnp.float32, *ins[:4]),
             scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32)],
             interpret=interpret,
         )(*ins)

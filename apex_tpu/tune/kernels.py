@@ -9,8 +9,8 @@ Per-spec notes:
 
 * **flash_attention** (fwd+bwd) — ``block_q``/``block_k`` over the
   MXU-friendly multiples of 128 that tile the sequence; the tune case
-  runs ``value_and_grad`` through the custom VJP so the dq/dkv backward
-  kernels are half the measured clock, exactly as in training.  The
+  runs ``value_and_grad`` through the custom VJP so the fused backward
+  kernel is most of the measured clock, exactly as in training.  The
   online-softmax recurrence reorders with the KV block, so the oracle
   checks to tolerance, not bitwise.
 * **fused_layer_norm / xentropy** — ``row_block``

@@ -545,3 +545,221 @@ def test_attention_sweep_tool_quick(tmp_path, monkeypatch, capsys):
                                     "flash_128_ms"))
     assert json.loads(capsys.readouterr().out.splitlines()[-1])[
         "n_rows"] == 1
+
+
+# -- the fused backward kernel (ISSUE 30) --------------------------------------
+# dq, dk and dv come from one `flash_bwd` pallas_call: dk/dv accumulate in
+# whole-key-length scratch at row offsets ki * bk, so these cases force
+# several Q and KV tiles (T 512, blocks 128) through it.
+
+def _fa_module():
+    import importlib
+    return importlib.import_module("apex_tpu.ops.flash_attention")
+
+
+def _grads_match_oracle(f, ref, args, atol=5e-4):
+    argnums = tuple(range(len(args)))
+    g1 = jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a))), argnums=argnums)(*args)
+    g2 = jax.grad(lambda *a: jnp.sum(jnp.sin(ref(*a))),
+                  argnums=argnums)(*args)
+    for a, b in zip(g1, g2):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=atol, rtol=atol)
+    return g1
+
+
+def _tiled_case(tq, tk, n_kv, causal, with_bias, **kw):
+    """(f, ref, args) over [1, T, 4, 32] inputs with 128-blocks: the kernel
+    path in interpret mode against the materialized-scores oracle."""
+    B, H, D = 1, 4, 32
+    q = _rand((B, tq, H, D), 0)
+    k = _rand((B, tk, n_kv, D), 1)
+    v = _rand((B, tk, n_kv, D), 2)
+    args = (q, k, v) + ((_rand((B, tk), 3),) if with_bias else ())
+
+    def f(q, k, v, kb=None):
+        return flash_attention(q, k, v, causal=causal, key_padding_bias=kb,
+                               block_q=128, block_k=128, interpret=True,
+                               **kw)
+
+    def ref(q, k, v, kb=None):
+        kr = jnp.repeat(k, H // n_kv, axis=2)
+        vr = jnp.repeat(v, H // n_kv, axis=2)
+        if causal:
+            return _suffix_causal_ref(q, kr, vr, key_padding_bias=kb)
+        bias = None if kb is None else kb[:, None, None, :]
+        return dot_product_attention(q, kr, vr, bias=bias)
+
+    return f, ref, args
+
+
+@pytest.mark.parametrize("with_bias", [False, True])
+@pytest.mark.parametrize("n_kv", [4, 2, 1])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_fused_backward_many_tiles(causal, n_kv, with_bias):
+    """4 x 4 tiles through the fused kernel, MHA / GQA 4:2 / MQA, with and
+    without the key-padding bias: dq, dk, dv and the bias gradient against
+    the oracle."""
+    f, ref, args = _tiled_case(512, 512, n_kv, causal, with_bias)
+    g = _grads_match_oracle(f, ref, args)
+    if with_bias:
+        assert float(jnp.linalg.norm(g[3])) > 0
+
+
+@pytest.mark.parametrize("with_bias", [False, True])
+@pytest.mark.parametrize("n_kv", [4, 2])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_fused_backward_fewer_queries_than_keys(causal, n_kv,
+                                                      with_bias):
+    """q_len 256 < kv_len 512 (2 x 4 tiles; causal = suffix alignment, a
+    static q_offset): the dk/dv rows no query tile reaches stay zero."""
+    f, ref, args = _tiled_case(256, 512, n_kv, causal, with_bias)
+    _grads_match_oracle(f, ref, args)
+
+
+def _shrink_bwd_budget(monkeypatch, keys):
+    """The fused backward keeps at most `keys` keys resident per call."""
+    fa = _fa_module()
+    monkeypatch.setattr(fa, "_bwd_kv_chunk",
+                        lambda tk, *_: (min(tk, keys), 16 * 2**20))
+    return fa
+
+
+@pytest.mark.parametrize("vmem_mib,tk,d,itemsize,want", [
+    (128, 1024, 64, 2, (1024, 16)),      # gpt2_small_o2.seq1024: the default
+    (128, 4096, 64, 2, (4096, 20)),      # granite4_h_micro_o2.b2_seq4096
+    (128, 16384, 128, 2, (16384, 44)),   # the most one call holds on a v5e
+    (128, 32768, 64, 2, (16384, 44)),    # beyond it: two chunks
+    (128, 32768, 64, 4, (10240, 42)),    # float32: 20 bytes a lane
+    (64, 32768, 64, 2, (8192, 28)),      # half the VMEM, half the keys
+    (16, 4096, 64, 2, (2048, 16)),       # never over the core's VMEM
+])
+def test_flash_backward_kv_chunk_follows_vmem(vmem_mib, tk, d, itemsize,
+                                              want, monkeypatch):
+    """Keys per `flash_bwd` call and its scoped-VMEM limit (MiB) from the
+    core's VMEM: a quarter of it for the whole-length residents."""
+    fa = _fa_module()
+    monkeypatch.setattr(fa, "_vmem_capacity", lambda: vmem_mib * 2**20)
+    keys, limit = fa._bwd_kv_chunk(tk, d, itemsize, 1024)
+    assert (keys, limit / 2**20) == want
+
+
+def _count_pallas_calls(jaxpr):
+    n, names = 0, []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n += 1
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            m, more = _count_pallas_calls(sub)
+            n += m
+            names += more
+    return n, names
+
+
+@pytest.mark.parametrize("case,want", [
+    ("plain", 1), ("gqa", 1), ("key_bias", 1), ("window_bounded", 1),
+    ("ring_offsets", 1), ("qk_bias", 2), ("keys_over_budget", 2),
+    ("window_over_budget", 2)])
+def test_flash_backward_pallas_call_count(case, want, monkeypatch):
+    """The backward is ONE pallas_call named flash_bwd on every path (a
+    [B,T,S] bias adds its own gradient kernel); keys beyond the VMEM
+    budget take the same kernel once per KV chunk.  No flag chooses."""
+    fa = _fa_module()
+    B, H, T, D = 1, 4, 512, 32
+    n_kv = 2 if case == "gqa" else H
+    q = jnp.zeros((B, H, T, D))
+    k = v = jnp.zeros((B, n_kv, T, D))
+    lse = jnp.zeros((B, H, T, 1))
+    kw = dict(sm_scale=0.2, causal=True, block_q=128, block_k=128,
+              interpret=True)
+    kb = jnp.zeros((B, T)) if case == "key_bias" else None
+    if case == "qk_bias":
+        kw["qk_bias"] = jnp.zeros((B, T, T))
+    if case.startswith("window"):
+        kw["window"] = 100
+        assert fa._window_span(100, 128, 128, 0, 0, 4) == 2
+    if case.endswith("over_budget"):
+        _shrink_bwd_budget(monkeypatch, 256)
+
+    def bwd(q, k, v, lse, do, qo, ko):
+        if case != "ring_offsets":
+            qo, ko = 0, 0
+        return fa._flash_bwd_pallas(q, k, v, kb, q, lse, do, q_offset=qo,
+                                    k_offset=ko, **kw)
+
+    jaxpr = jax.make_jaxpr(bwd)(q, k, v, lse, q, jnp.int32(0), jnp.int32(0))
+    n, names = _count_pallas_calls(jaxpr.jaxpr)
+    assert n == want
+    assert names.count("flash_bwd") == (1 if case == "qk_bias" else want)
+
+
+@pytest.mark.parametrize("case", ["causal_gqa_bias", "window", "qk_bias",
+                                  "noncausal"])
+def test_flash_backward_kv_chunks_match_oracle(case, monkeypatch):
+    """Keys beyond the resident-VMEM budget: the budget is shrunk to 256
+    keys, so T 512 runs the fused kernel over two KV chunks (the second
+    with a nonzero k_offset) and dq is summed across them."""
+    B, T, H, D = 1, 512, 4, 32
+    _shrink_bwd_budget(monkeypatch, 256)
+    if case in ("causal_gqa_bias", "noncausal"):
+        f, ref, args = _tiled_case(T, T, 2, case != "noncausal", True)
+        _grads_match_oracle(f, ref, args)
+        return
+    q, k, v = (_rand((B, T, H, D), s) for s in range(3))
+    if case == "window":          # span 3 of a chunk's 2: the masked grid
+        _window_grads_match_oracle(q, k, v, 150)
+    else:
+        bias = _rand((B, T, T), 3) * 0.3
+        f = lambda q, k, v, bi: flash_attention(
+            q, k, v, causal=True, bias=bi, block_q=128, block_k=128,
+            interpret=True)
+        ref = lambda q, k, v, bi: dot_product_attention(
+            q, k, v, causal=True, bias=bi[:, None])
+        _grads_match_oracle(f, ref, (q, k, v, bias))
+
+
+def _window_grads_match_oracle(q, k, v, window, n_kv=None, kb=None):
+    fa = _fa_module()
+    T, H = q.shape[1], q.shape[2]
+    pos = jnp.arange(T)
+    band = jnp.where((pos[:, None] - pos[None, :]) < window, 0.0,
+                     fa.NEG_INF)[None, None]
+    args = (q, k, v) + (() if kb is None else (kb,))
+
+    def f(q, k, v, kb=None):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               key_padding_bias=kb, block_q=128,
+                               block_k=128, interpret=True)
+
+    def ref(q, k, v, kb=None):
+        bias = band if kb is None else band + kb[:, None, None, :]
+        rep = H // k.shape[2]
+        return dot_product_attention(q, jnp.repeat(k, rep, axis=2),
+                                     jnp.repeat(v, rep, axis=2),
+                                     causal=True, bias=bias)
+
+    return _grads_match_oracle(f, ref, args)
+
+
+@pytest.mark.parametrize("budget_keys,window,n_kv,with_bias", [
+    (512, 150, 4, False),     # span 3 < a chunk's 4 blocks < nq 8
+    (512, 150, 2, True),      # the same under GQA with the key bias
+    (256, 300, 4, False),     # span 4 > a chunk's 2: a band over 3 chunks
+    (384, 129, 1, True),      # 3-block chunks, the last one short (2)
+])
+def test_flash_backward_bounded_window_across_kv_chunks(
+        budget_keys, window, n_kv, with_bias, monkeypatch):
+    """The bounded sliding-window grid walks the band of the WHOLE key
+    length (T 1,024 in 8 blocks); each KV chunk runs the tiles of it that
+    it holds and skips the rest, those past its end too."""
+    B, T, H, D = 1, 1024, 4, 32
+    fa = _shrink_bwd_budget(monkeypatch, budget_keys)
+    assert fa._window_span(window, 128, 128, 0, 0, 8) is not None
+    q = _rand((B, T, H, D), 0)
+    k, v = (_rand((B, T, n_kv, D), s) for s in (1, 2))
+    kb = _rand((B, T), 3) if with_bias else None
+    g = _window_grads_match_oracle(q, k, v, window, kb=kb)
+    # the band reaches every chunk: no dk rows left at their zero fill
+    assert float(jnp.abs(g[1][:, -128:]).max()) > 0

@@ -1,0 +1,77 @@
+"""Rehearsal compiles of the flash backward for a described TPU v5e.
+
+Interpret mode runs the kernel body under jnp and cannot see what
+Mosaic refuses: a slice off the tiling, more scoped VMEM than the call
+asked for.  The TPU's compiler is installed beside the CPU backend and
+compiles for a chip that is described and not attached, so these cases
+hold ``flash_bwd`` to it at the shapes the benchmark's cells run and at
+the key lengths where its whole-length dk/dv residents set the VMEM
+limit.  Nothing runs: a compile that passes says nothing about results
+or times.
+
+The topology is described inside a fixture (never at import: one
+process at a time may load the TPU's library, and every xdist worker
+imports every test file), and all of these cases live in this one file.
+"""
+
+import importlib
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:     # undone when the module ends
+        if "TPU_LOG_DIR" not in os.environ:
+            mp.setenv("TPU_LOG_DIR", "disabled")         # else logs in /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler here, or its library is held
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+
+
+# (batch, heads, kv_heads, seq, head_dim, block, key-padding bias,
+#  window, flash_bwd calls expected)
+CASES = {
+    "gpt2_small_o2.seq1024": (8, 12, 12, 1024, 64, 1024, False, None, 1),
+    "granite4_h_micro_o2.b2_seq4096": (2, 32, 8, 4096, 64, 1024, False,
+                                       None, 1),
+    "many_tiles_gqa_key_bias": (1, 4, 2, 2048, 64, 256, True, None, 1),
+    "bounded_window_mqa": (1, 4, 1, 8192, 64, 512, True, 1024, 1),
+    "resident_budget_16k_keys_d128": (1, 2, 1, 16384, 128, 1024, False,
+                                      None, 1),
+    "two_kv_chunks_32k_keys": (1, 2, 2, 32768, 64, 1024, False, None, 2),
+    "bounded_window_over_two_kv_chunks": (1, 2, 1, 32768, 64, 1024, True,
+                                          4096, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_flash_bwd_compiles_under_mosaic(one_chip, case):
+    fa = importlib.import_module("apex_tpu.ops.flash_attention")
+    b, h, h_kv, t, d, blk, with_bias, window, calls = CASES[case]
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, kv = sds((b, h, t, d)), sds((b, h_kv, t, d))
+    lse = sds((b, h, t, 1), jnp.float32)
+    kb = sds((b, t), jnp.float32) if with_bias else None
+
+    def bwd(q, k, v, out, lse, do, kb):
+        return fa._flash_bwd_pallas(
+            q, k, v, kb, out, lse, do, sm_scale=d ** -0.5, causal=True,
+            block_q=blk, block_k=blk, window=window)
+
+    # refused here = refused on the chip (VMEM limit, tiling, lowering)
+    compiled = jax.jit(bwd).lower(q, kv, kv, q, lse, q, kb).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == calls

@@ -437,10 +437,10 @@ def test_tp_self_attention_flash_kernel_on_chip():
 
 
 def test_flash_gqa_kernels_on_chip():
-    """Mosaic GQA: the 5-D dkv grid's resident dk/dv accumulation across
-    the group-member dim is TPU-specific — interpret mode cannot validate
-    it.  With key-padding bias so the per-q-head db path is exercised
-    under grouping too."""
+    """Mosaic GQA: the 5-D backward grid's resident dk/dv accumulation
+    across the group-member dim is TPU-specific — interpret mode cannot
+    validate it.  With key-padding bias so the db accumulation is
+    exercised under grouping too."""
     from apex_tpu.ops.attention import dot_product_attention
     from apex_tpu.ops.flash_attention import flash_attention
 
@@ -480,15 +480,24 @@ def test_flash_gqa_kernels_on_chip():
                                        atol=0.2, rtol=0.1)
 
 
-def test_flash_sliding_window_on_chip():
+@pytest.mark.parametrize("chunk_keys", [None, 512])
+def test_flash_sliding_window_on_chip(chunk_keys, monkeypatch):
     """Mosaic: bounded sliding-window grid (virtual-negative KV blocks
     clamped in the index maps, dead steps predicated off) vs the band-bias
-    oracle — fwd + grads."""
+    oracle — fwd + grads.  ``chunk_keys``: the backward's resident-VMEM
+    budget shrunk to so many keys, so that the band crosses four KV
+    chunks (span 3 < a chunk's 4 blocks < 16 Q blocks) and each call
+    skips the tiles of it that lie outside its keys."""
+    import importlib
     from apex_tpu.ops.attention import dot_product_attention
-    from apex_tpu.ops.flash_attention import NEG_INF, flash_attention
+    fa = importlib.import_module("apex_tpu.ops.flash_attention")
+    NEG_INF, flash_attention = fa.NEG_INF, fa.flash_attention
 
     rng = np.random.RandomState(0)
     B, T, H, D, W = 1, 2048, 4, 64, 256
+    if chunk_keys:
+        monkeypatch.setattr(fa, "_bwd_kv_chunk",
+                            lambda tk, *_: (chunk_keys, 16 * 2**20))
     q, k, v = (jnp.asarray(rng.randn(B, T, H, D) * .5, jnp.bfloat16)
                for _ in range(3))
     band = jnp.where(
