@@ -6,3 +6,4 @@ from .bert import BertEncoder, bert_base, bert_tiny         # noqa: F401
 from .dcgan import Generator, Discriminator                 # noqa: F401
 from .gpt import GPT, gpt2_small, gpt_tiny, init_cache      # noqa: F401
 from .granite_hybrid import GraniteHybrid, granite_hybrid_tiny  # noqa: F401
+from .lfm2_moe import Lfm2Moe, lfm2_moe_tiny                # noqa: F401

@@ -16,6 +16,13 @@ gate is applied on the combine side so gradients flow into the router.
 
 Call inside ``shard_map``; one expert per ``ep`` rank (``n_experts ==
 lax.axis_size(axis_name)``).
+
+For routing to more than one expert a token without a capacity or a dropped
+row, and for several experts a rank, see :mod:`apex_tpu.ops.moe`: the expert
+layer that is told which experts it holds, routes over all of them and
+computes its own experts' part with grouped matmuls.  It runs on one chip
+without an exchange; this module's two ``all_to_all``s have not been rebuilt
+on it (``ROADMAP.md``, Reach).
 """
 
 from __future__ import annotations

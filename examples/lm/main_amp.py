@@ -9,7 +9,12 @@ axis and attention runs as ring attention (``--attention ring`` or
 ``ring_flash``).  ``--model granite-hybrid`` trains the Granite-4.0-H block
 instead (``apex_tpu.models.GraniteHybrid``: Mamba-2 state-space layers
 beside GQA attention layers in the published period of ten, SwiGLU, RMSNorm)
-through the same loss, train step and pipeline.
+through the same loss, train step and pipeline, and ``--model lfm2-moe`` the
+LFM2-MoE block (``apex_tpu.models.Lfm2Moe``: gated short convolutions beside
+GQA attention with rotary positions in the published period of four, a dense
+SwiGLU MLP in the first layer and routed experts after it, sigmoid top-4
+without drops; the selection bias and the experts' load counts ride along as
+model state).
 
 The loop runs on :class:`apex_tpu.runtime.StepPipeline`:
 ``--steps-per-call K`` chains K steps into ONE compiled program
@@ -22,6 +27,7 @@ never blocks on a scalar.
     python main_amp.py --synthetic --steps 32 --steps-per-call 8
     python main_amp.py --synthetic --steps 2 --sp 2 --attention ring
     python main_amp.py --synthetic --steps 5 --model granite-hybrid --layers 10
+    python main_amp.py --synthetic --steps 5 --model lfm2-moe --layers 5
 """
 
 import os as _os
@@ -42,7 +48,8 @@ import numpy as np
 
 from apex_tpu import runtime, training
 from apex_tpu.contrib.xentropy import softmax_cross_entropy_loss
-from apex_tpu.models import GPT, GraniteHybrid, granite_hybrid
+from apex_tpu.models import (GPT, GraniteHybrid, Lfm2Moe, granite_hybrid,
+                             lfm2_moe)
 from apex_tpu.training import make_train_step
 
 
@@ -50,11 +57,15 @@ def parse():
     p = argparse.ArgumentParser()
     p.add_argument("--synthetic", action="store_true")
     p.add_argument("--model", type=str, default="gpt",
-                   choices=["gpt", "granite-hybrid"],
+                   choices=["gpt", "granite-hybrid", "lfm2-moe"],
                    help="gpt: GPT-2 blocks; granite-hybrid: Mamba-2 and GQA "
                         "attention layers by the published period (five "
                         "mamba, attention, four mamba), heads of "
-                        "hidden/heads for both kinds of layer")
+                        "hidden/heads for both kinds of layer; lfm2-moe: one "
+                        "dense layer, then gated short convs and GQA+RoPE "
+                        "attention by the published period (attention, three "
+                        "convs), each followed by 8 routed experts of width "
+                        "hidden, 4 a token")
     p.add_argument("--steps", type=int, default=10)
     p.add_argument("-b", "--batch-size", type=int, default=8)
     p.add_argument("--seq-len", type=int, default=256)
@@ -199,11 +210,12 @@ def _train(args):
             "flash", "blockwise", "full"):
         raise SystemExit("--kv-heads needs --attention flash/blockwise/full "
                          "(GQA is shard-local; ring/ulysses paths are MHA)")
-    hybrid = args.model == "granite-hybrid"
+    hybrid, moe = args.model == "granite-hybrid", args.model == "lfm2-moe"
+    if (hybrid or moe) and (sp > 1 or args.window is not None
+                            or args.attention != "flash"):
+        raise SystemExit(f"--model {args.model} runs unsharded with "
+                         f"--attention flash and no --window")
     if hybrid:
-        if sp > 1 or args.window is not None or args.attention != "flash":
-            raise SystemExit("--model granite-hybrid runs unsharded with "
-                             "--attention flash and no --window")
         period = granite_hybrid.PERIOD
         model = GraniteHybrid(
             vocab_size=args.vocab, hidden_size=args.hidden,
@@ -212,6 +224,16 @@ def _train(args):
             num_heads=args.heads, num_kv_heads=args.kv_heads or args.heads,
             mlp_dim=4 * args.hidden, mamba_heads=2 * args.heads,
             mamba_head_dim=args.hidden // args.heads, dtype=jnp.bfloat16)
+    elif moe:
+        period = lfm2_moe.LAYER_TYPES[2:6]
+        model = Lfm2Moe(
+            vocab_size=args.vocab, hidden_size=args.hidden,
+            layer_types=lfm2_moe.LAYER_TYPES[:1] + tuple(
+                period[i % len(period)] for i in range(args.layers - 1)),
+            num_dense_layers=1, num_heads=args.heads,
+            num_kv_heads=args.kv_heads or args.heads,
+            mlp_dim=4 * args.hidden, moe_dim=args.hidden, num_experts=8,
+            experts_held=8, dtype=jnp.bfloat16)
     else:
         model = GPT(vocab_size=args.vocab, hidden_size=args.hidden,
                     num_layers=args.layers, num_heads=args.heads,
@@ -233,16 +255,16 @@ def _train(args):
     if sp > 1 and t_train % sp:
         raise SystemExit(f"--seq-len must be 1 + multiple of --sp "
                          f"(got {args.seq_len}, sp={sp})")
-    params = init_model.init(jax.random.PRNGKey(0), ids[:1, :8])["params"]
+    variables = init_model.init(jax.random.PRNGKey(0), ids[:1, :8])
+    # the expert layers' selection bias and load counts; None for the others
+    params, model_state = variables["params"], variables.get("moe")
     n_params = sum(int(np.prod(l.shape)) for l in
                    jax.tree_util.tree_leaves(params))
     print(f"{type(model).__name__} {args.layers}L/{args.hidden}H  "
           f"{n_params/1e6:.1f}M params  "
           f"attention={args.attention}  opt_level = {args.opt_level}")
 
-    def loss_fn(p, batch):
-        xb, yb = batch
-        logits = model.apply({"params": p}, xb)
+    def token_loss(logits, yb):
         flat = logits.reshape(-1, logits.shape[-1])
         labels = yb.reshape(-1)
         if args.fused_loss:
@@ -261,13 +283,27 @@ def _train(args):
             losses = jnp.where(labels == 0, 0.0, losses)
         return jnp.mean(losses)
 
+    def loss_fn(p, batch):
+        xb, yb = batch
+        return token_loss(model.apply({"params": p}, xb), yb)
+
+    def loss_fn_with_state(p, moe_state, batch):
+        xb, yb = batch
+        logits, new = model.apply({"params": p, "moe": moe_state}, xb,
+                                  mutable=["moe"])
+        return token_loss(logits, yb), new["moe"]
+
+    # O2 keeps float32, beside the norms: the hybrid mixer's A_log, dt_bias
+    # and D; the expert layers' router
+    keep_fp32 = (granite_hybrid.keep_fp32 if hybrid
+                 else lfm2_moe.keep_fp32 if moe else None)
     init_fn, step_fn = make_train_step(
-        loss_fn, training.adam(args.lr, weight_decay=args.weight_decay),
+        loss_fn_with_state if moe else loss_fn,
+        training.adam(args.lr, weight_decay=args.weight_decay),
         opt_level=args.opt_level, loss_scale=loss_scale,
-        axis_name="sp" if sp > 1 else None,
-        # O2 keeps the mixer's A_log, dt_bias and D float32 beside the norms
-        norm_predicate=granite_hybrid.keep_fp32 if hybrid else None)
-    state = init_fn(params)
+        axis_name="sp" if sp > 1 else None, norm_predicate=keep_fp32,
+        has_model_state=moe)
+    state = init_fn(params, model_state)
 
     spc = max(1, args.steps_per_call)
     wrap = None

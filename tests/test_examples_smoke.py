@@ -209,6 +209,22 @@ def test_lm_example_granite_hybrid(monkeypatch, capsys):
             "--synthetic", "--model", "granite-hybrid", "--window", "8"])
 
 
+def test_lm_example_lfm2_moe(monkeypatch, capsys):
+    """The same loop over the LFM2-MoE block: five layers are one dense conv
+    layer, then attention and three convs with routed experts; the model's
+    state (selection bias, load counts) rides through ``make_train_step``."""
+    _run_example(monkeypatch, "examples/lm/main_amp.py", [
+        "--synthetic", "--steps", "2", "-b", "2", "--seq-len", "33",
+        "--hidden", "32", "--layers", "5", "--heads", "2", "--kv-heads", "1",
+        "--vocab", "128", "--opt-level", "O2", "--loss-scale", "dynamic",
+        "--model", "lfm2-moe"])
+    out = capsys.readouterr().out
+    assert "Lfm2Moe 5L/32H" in out and "loss_scale 65536" in out
+    with pytest.raises(SystemExit, match="lfm2-moe runs unsharded"):
+        _run_example(monkeypatch, "examples/lm/main_amp.py", [
+            "--synthetic", "--model", "lfm2-moe", "--window", "8"])
+
+
 def test_lm_example_sequence_parallel(monkeypatch):
     """GPT over a 2-way sp mesh with ring attention."""
     _run_example(monkeypatch, "examples/lm/main_amp.py", [

@@ -75,3 +75,36 @@ def test_flash_bwd_compiles_under_mosaic(one_chip, case):
     compiled = jax.jit(bwd).lower(q, kv, kv, q, lse, q, kb).compile()
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') == calls
+
+
+def test_grouped_matmul_compiles_under_mosaic_at_the_cells_widths(
+        one_chip, monkeypatch):
+    """The expert layer's grouped products at ``lfm2_24b_a2b_o2.b4_seq4096``'s
+    sizes (65,536 sorted rows, 16 experts of 2,048 x 1,536), forward and both
+    gradients: on the TPU ``ops.moe`` takes the grouped-matmul kernel of
+    ``jax.experimental`` (this file lives with the flash cases because every
+    rehearsal compile has to: one process may describe the topology)."""
+    moe = importlib.import_module("apex_tpu.ops.moe")
+    monkeypatch.setattr(moe, "_use_pallas", lambda: True)
+    rows, d, f, g = 65536, 2048, 1536, 16
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(x, w, sizes):
+        out, vjp = jax.vjp(lambda x, w: moe._grouped_matmul(x, w, sizes), x, w)
+        return out, vjp(out)
+
+    # the suite's global "highest" would reach the library kernel's dots,
+    # which take bf16 operands at the default precision as the chip runs them
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(both).lower(
+            sds((rows, d)), sds((g, d, f)), sds((g,), jnp.int32)).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3     # gmm twice, tgmm
+    # rows that do not fill the kernel's row tiles take the compiler's own
+    # grouped matmul
+    with jax.default_matmul_precision("default"):
+        ragged = jax.jit(lambda x, w, s: moe._grouped_matmul(x, w, s)).lower(
+            sds((rows + 8, d)), sds((g, d, f)), sds((g,), jnp.int32)).compile()
+    assert "ragged-dot" in ragged.as_text()

@@ -1,0 +1,220 @@
+"""``models.Lfm2Moe``: the two lists (mixer and feed-forward by position), what
+amp O2 keeps float32, the model's state (selection bias and load counts), and
+the O2 train step at a tiny size: finite, the loss falls, the load counts of a
+step sum to ``tokens x k``, the bias receives no update, and the new scopes
+reach the compiled module under ``apex.forward`` and its transpose.  The
+comparison with the plain reference is in ``tests/benchmark``."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu import models, training
+from apex_tpu.amp import policy
+from apex_tpu.contrib.xentropy import softmax_cross_entropy_loss
+from apex_tpu.models import lfm2_moe
+from apex_tpu.ops import moe
+
+BATCH, SEQ = 2, 33
+
+
+def _ids(batch=BATCH, seq=SEQ + 1, vocab=1024):
+    return jax.random.randint(jax.random.PRNGKey(1), (batch, seq), 1, vocab)
+
+
+def _init(model):
+    return model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+
+
+def test_the_default_is_the_published_model():
+    model = models.Lfm2Moe()
+    kinds = list(model.layer_types)
+    assert len(kinds) == 40 and kinds.count("full_attention") == 10
+    assert [i for i, k in enumerate(kinds) if k == "full_attention"] == list(
+        range(2, 40, 4))
+    assert kinds[:2] == ["conv", "conv"] and model.num_dense_layers == 2
+    assert (model.hidden_size, model.mlp_dim, model.moe_dim, model.vocab_size
+            ) == (2048, 11776, 1536, 65536)
+    assert (model.num_experts, model.experts_held, model.top_k) == (64, 64, 4)
+    assert (model.num_heads, model.num_kv_heads, model.conv_taps) == (32, 8, 3)
+    assert (model.rope_theta, model.eps) == (1e6, 1e-5)
+    # the benchmark's cut: one dense layer, one period, 16 experts, 1/4 vocabulary
+    cut = models.Lfm2Moe(layer_types=kinds[:1] + kinds[2:6], num_dense_layers=1,
+                         experts_held=16, vocab_size=16384)
+    shapes = jax.eval_shape(lambda: _init(cut))
+    count = lambda tree: sum(a.size for a in jax.tree_util.tree_leaves(tree))
+    p = shapes["params"]
+    assert count(p["layer_0"]) == 89_139_200        # conv + dense MLP
+    assert count(p["layer_1"]) == 161_616_000       # attention + 16 experts
+    assert count(p["layer_2"]) == 167_913_472       # conv + 16 experts
+    assert count(p["layer_1"]["experts"]) == 16 * 3 * 2048 * 1536 + 2048 * 64
+    assert count(p) == 788_052_096
+    assert p["layer_1"]["experts"]["w1"].shape == (16, 2048, 1536)
+    assert set(shapes["moe"]) == {"layer_1", "layer_2", "layer_3", "layer_4"}
+    state = shapes["moe"]["layer_3"]["experts"]
+    assert (state["selection_bias"].shape, state["selection_bias"].dtype) == (
+        (64,), jnp.float32)
+    assert (state["load"].shape, state["load"].dtype) == ((64,), jnp.int32)
+
+
+def test_mixer_and_feed_forward_follow_their_lists():
+    model = models.lfm2_moe_tiny(
+        layer_types=("conv", "full_attention", "conv"), num_dense_layers=2)
+    variables = _init(model)
+    params = variables["params"]
+    assert set(params["layer_0"]) == {"operator_norm", "conv", "ffn_norm", "mlp"}
+    assert set(params["layer_1"]) == {"operator_norm", "attention", "ffn_norm",
+                                      "mlp"}
+    assert set(params["layer_2"]) == {"operator_norm", "conv", "ffn_norm",
+                                      "experts"}
+    assert set(variables["moe"]) == {"layer_2"}
+    bad = models.lfm2_moe_tiny(layer_types=("conv", "mamba"))
+    with pytest.raises(ValueError, match="unknown layer kind"):
+        _init(bad)
+
+
+def test_initialisation_is_the_one_on_file():
+    variables = _init(models.lfm2_moe_tiny())
+    params = variables["params"]
+    assert abs(float(jnp.std(params["wte"])) - 0.02) < 2e-3
+    assert abs(float(jnp.std(params["layer_1"]["experts"]["w1"])) - 0.02) < 2e-3
+    taps = params["layer_0"]["conv"]["conv_kernel"]
+    assert taps.shape == (3, 64) and float(jnp.abs(taps).max()) <= 3 ** -0.5
+    for name in ("q_norm", "k_norm"):
+        np.testing.assert_array_equal(params["layer_1"]["attention"][name],
+                                      np.ones(16, np.float32))
+    for state in variables["moe"].values():
+        np.testing.assert_array_equal(state["experts"]["selection_bias"],
+                                      np.zeros(8, np.float32))
+        np.testing.assert_array_equal(state["experts"]["load"],
+                                      np.zeros(8, np.int32))
+
+
+def test_o2_keeps_norms_and_the_router_float32():
+    params = _init(models.lfm2_moe_tiny())["params"]
+    cast = policy.convert_params(params, jnp.bfloat16,
+                                 norm_predicate=lfm2_moe.keep_fp32)
+    flat = {jax.tree_util.keystr(path): leaf.dtype for path, leaf in
+            jax.tree_util.tree_flatten_with_path(cast)[0]}
+    kept = {name for name, dtype in flat.items() if dtype == jnp.float32}
+    assert all(name.endswith(("['scale']", "['q_norm']", "['k_norm']",
+                              "['router']")) for name in kept)
+    assert sum(name.endswith("['router']") for name in kept) == 4
+    assert sum(name.endswith("_norm']") for name in kept) == 2
+    assert flat["['wte']"] == flat["['layer_1']['experts']['w1']"] == flat[
+        "['layer_0']['conv']['conv_kernel']"] == jnp.bfloat16
+    # without the predicate the router is rounded, and the layer says so
+    model = models.lfm2_moe_tiny(dtype=jnp.bfloat16)
+    variables = _init(model)
+    with pytest.raises(TypeError, match="router's weight arrived as bfloat16"
+                                        ".*keep_fp32"):
+        jax.eval_shape(model.apply, {
+            "params": policy.convert_params(variables["params"], jnp.bfloat16),
+            "moe": variables["moe"]}, _ids())
+
+
+def test_a_share_of_the_experts_is_a_constructor_argument():
+    """``experts_held`` of ``num_experts`` from ``expert_offset``: the router
+    keeps its width, the expert leaves shrink, and the selection is that of
+    the model that holds them all."""
+    whole = models.lfm2_moe_tiny()
+    share = models.lfm2_moe_tiny(experts_held=2, expert_offset=4)
+    variables = _init(whole)
+    cut = jax.tree_util.tree_map(lambda a: a, variables["params"])
+    for name in variables["moe"]:
+        for leaf in ("w1", "w3", "w2"):
+            cut[name]["experts"][leaf] = cut[name]["experts"][leaf][4:6]
+    assert jax.eval_shape(lambda: _init(share))["params"]["layer_1"]["experts"][
+        "w1"].shape == (2, 64, 32)
+    ids = _ids()[:, :-1]
+    _, seen = whole.apply(variables, ids, mutable=["intermediates", "moe"])
+    _, seen_cut = share.apply({"params": cut, "moe": variables["moe"]}, ids,
+                              mutable=["intermediates", "moe"])
+    first = "layer_1"       # deeper layers see another residual stream
+    np.testing.assert_array_equal(
+        seen["intermediates"][first]["experts"]["selected"][0],
+        seen_cut["intermediates"][first]["experts"]["selected"][0])
+    np.testing.assert_array_equal(seen["moe"][first]["experts"]["load"],
+                                  seen_cut["moe"][first]["experts"]["load"])
+
+
+@pytest.fixture(scope="module")
+def o2_step():
+    model = models.lfm2_moe_tiny(dtype=jnp.bfloat16, experts_held=4,
+                                 expert_offset=2)
+    variables = _init(model)
+
+    def loss_fn(p, model_state, batch):
+        x, y = batch
+        logits, new = model.apply({"params": p, "moe": model_state}, x,
+                                  mutable=["moe"])
+        assert logits.dtype == jnp.float32
+        return jnp.mean(softmax_cross_entropy_loss(
+            logits.reshape(-1, logits.shape[-1]), y.reshape(-1))), new["moe"]
+
+    init_fn, step_fn = training.make_train_step(
+        loss_fn, training.adam(3e-3, weight_decay=0.1), opt_level="O2",
+        loss_scale="dynamic", norm_predicate=lfm2_moe.keep_fp32,
+        has_model_state=True)
+    ids = _ids()
+    # a bias that is not zero, to see that no step moves it
+    state = variables["moe"]
+    for i, name in enumerate(state):
+        state[name]["experts"]["selection_bias"] = 0.05 * jax.random.normal(
+            jax.random.PRNGKey(i), (8,))
+    return (jax.jit(step_fn), init_fn(variables["params"], state),
+            (ids[:, :-1], ids[:, 1:]))
+
+
+def test_o2_step_is_finite_the_loss_falls_and_the_state_is_kept(o2_step):
+    step, state, batch = o2_step
+    bias0 = {name: np.asarray(s["experts"]["selection_bias"])
+             for name, s in state.model_state.items()}
+    losses, loads = [], []
+    for _ in range(6):
+        state, metrics = step(state, batch)
+        assert not bool(metrics["overflow"])
+        losses.append(float(metrics["loss"]))
+        loads.append({name: np.asarray(s["experts"]["load"])
+                      for name, s in state.model_state.items()})
+    assert np.isfinite(losses).all()
+    assert losses[-1] < losses[0] - 0.1
+    assert all(np.isfinite(np.asarray(leaf, np.float32)).all()
+               for leaf in jax.tree_util.tree_leaves(state.params))
+    # the load counter: every (token, slot) pair of the step, over all 8
+    # experts, in each of the four expert layers; it moves as the router trains
+    for load in loads:
+        assert set(load) == {"layer_1", "layer_2", "layer_3", "layer_4"}
+        assert all(c.dtype == np.int32 and c.shape == (8,)
+                   and c.sum() == BATCH * SEQ * 4 for c in load.values())
+    assert any(not np.array_equal(loads[0][n], loads[-1][n]) for n in loads[0])
+    # the selection bias is state, not a parameter: no update reaches it
+    for name, s in state.model_state.items():
+        np.testing.assert_array_equal(s["experts"]["selection_bias"], bias0[name])
+    assert "selection_bias" not in str(jax.tree_util.tree_structure(state.params))
+
+
+def test_the_new_scopes_reach_the_compiled_step(o2_step):
+    step, state, batch = o2_step
+    compiled = step.lower(state, batch).compile().as_text()
+    assert (lfm2_moe.SCONV_SCOPE, lfm2_moe.ROPE_SCOPE) == ("apex.sconv",
+                                                           "apex.rope")
+    assert lfm2_moe.MOE_SCOPES is moe.MOE_SCOPES
+    under = "/jvp(apex.forward)/Lfm2Moe/"
+    for scope in moe.MOE_SCOPES[1:]:
+        assert f"{under}layer_1/experts/apex.moe/{scope}/" in compiled, scope
+        # the weighted sum back is the layer's last operation: its backward
+        # rule runs, its recomputed forward is needed by nothing
+        assert any("transpose(jvp(apex.forward))" in line
+                   and f"/apex.moe/{scope}/" in line
+                   and ("rematted_computation" in line
+                        or scope == "apex.moe.combine")
+                   for line in compiled.splitlines()), scope
+    assert f"{under}layer_0/conv/apex.sconv/" in compiled
+    assert f"{under}layer_1/attention/apex.rope/" in compiled
+    for scope in ("apex.sconv", "apex.rope"):
+        assert any("transpose(jvp(apex.forward))" in line and f"/{scope}/" in line
+                   for line in compiled.splitlines()), scope
+    assert "/layer_0/experts/" not in compiled and "/layer_1/mlp/" not in compiled
+    assert "/layer_1/conv/" not in compiled and "/layer_2/attention/" not in compiled

@@ -1,0 +1,178 @@
+"""``ops.moe_layer`` (sigmoid router, top-k with a selection bias, pairs sorted
+by expert, grouped products over the experts held, no capacity) against a
+dense loop over the experts written here, forward and every gradient: under a
+balanced routing, one that sends **every** token to one held expert, one that
+sends none to a held expert, and with a bias that changes the selection but
+not the weights; the four shares of a layer add up to the uncut layer."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu import ops
+from apex_tpu.ops import moe
+
+N, D, F, E, K = 48, 16, 12, 8, 4
+
+
+def dense_loop(x, w_gate, bias, w1, w3, w2, offset):
+    """Every held expert applied to every token, times that token's weight
+    for it: 0 where the expert was not selected."""
+    scores = jax.nn.sigmoid(x @ w_gate)
+    _, sel = jax.lax.top_k(scores + bias, K)
+    picked = jnp.take_along_axis(scores, sel, -1)
+    picked = picked / (picked.sum(-1, keepdims=True) + 1e-6)
+    out = jnp.zeros_like(x)
+    for i in range(w1.shape[0]):
+        mine = (sel == offset + i).astype(x.dtype)
+        y = (jax.nn.silu(x @ w1[i]) * (x @ w3[i])) @ w2[i]
+        out = out + (picked * mine).sum(-1, keepdims=True) * y
+    return out, sel
+
+
+def _weights(held=E, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return dict(x=jax.random.normal(k[0], (N, D)),
+                w_gate=jax.random.normal(k[1], (D, E)) * 0.5,
+                w1=jax.random.normal(k[2], (held, D, F)) * 0.3,
+                w3=jax.random.normal(k[3], (held, D, F)) * 0.3,
+                w2=jax.random.normal(k[4], (held, F, D)) * 0.3,
+                cot=jax.random.normal(k[5], (N, D)))
+
+
+def _both(p, bias, offset):
+    """(output, gradients, selection, counts) of the layer and of the loop."""
+    names = ("x", "w_gate", "w1", "w3", "w2")
+    args = [p[n] for n in names]
+    ours = lambda *a: ops.moe_layer(a[0], a[1], bias, *a[2:], top_k=K,
+                                    expert_offset=offset)
+    loop = lambda *a: dense_loop(a[0], a[1], bias, *a[2:], offset)
+    out = {}
+    for name, f in (("ours", ours), ("loop", loop)):
+        y, *rest = f(*args)
+        grads = jax.grad(lambda *a: jnp.sum(f(*a)[0] * p["cot"]),
+                         argnums=tuple(range(5)))(*args)
+        out[name] = (y, dict(zip(names, grads)), rest)
+    return out
+
+
+def _assert_equal(result, zero=()):
+    (y, grads, (counts, sel)), (want, want_grads, (want_sel,)) = (
+        result["ours"], result["loop"])
+    np.testing.assert_array_equal(np.sort(sel, -1), np.sort(want_sel, -1))
+    np.testing.assert_array_equal(
+        counts, np.bincount(np.asarray(sel).ravel(), minlength=E))
+    scale = max(float(jnp.abs(want).max()), 1e-6)
+    np.testing.assert_allclose(y, want, atol=2e-5 * scale + 1e-7)
+    for name, g in grads.items():
+        w = want_grads[name]
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        np.testing.assert_allclose(
+            g, w, atol=3e-5 * max(float(jnp.abs(w).max()), 1e-6) + 1e-7,
+            err_msg=name)
+        if name in zero:
+            assert float(jnp.abs(g).max()) == 0.0, name
+    return sel, counts
+
+
+@pytest.mark.parametrize("held,offset", [(8, 0), (2, 0), (2, 4), (3, 5)])
+def test_balanced_routing_equals_the_dense_loop(held, offset):
+    sel, counts = _assert_equal(_both(_weights(held), jnp.zeros((E,)), offset))
+    assert counts.sum() == N * K and counts.dtype == jnp.int32
+    assert sel.shape == (N, K) and len(np.unique(np.asarray(sel))) == E
+
+
+def test_every_token_to_one_held_expert_and_no_row_is_dropped():
+    """One column of the router towers over the rest: all 48 tokens select
+    expert 5, which is held, and every one of its rows is computed."""
+    p = _weights(held=2)
+    p["w_gate"] = p["w_gate"].at[:, 5].set(0.0)
+    p["x"] = p["x"].at[:, 0].set(30.0)
+    p["w_gate"] = p["w_gate"].at[0, 5].set(1.0)
+    sel, counts = _assert_equal(_both(p, jnp.zeros((E,)), offset=4))
+    assert counts[5] == N and (np.asarray(sel) == 5).sum() == N
+    # the loop's output with expert 5 alone is not zero: rows were computed
+    y = ops.moe_layer(p["x"], p["w_gate"], jnp.zeros((E,)), p["w1"], p["w3"],
+                      p["w2"], top_k=K, expert_offset=4)[0]
+    assert float(jnp.abs(y).min(-1).max()) > 0 and bool(
+        (jnp.abs(y).sum(-1) > 0).all())
+
+
+def test_no_token_to_a_held_expert_gives_zero_and_zero_gradients():
+    """The two held experts' columns are far below the rest: no pair is held,
+    every group is empty, output and the experts' gradients are exact zeros."""
+    p = _weights(held=2)
+    p["x"] = p["x"].at[:, 0].set(30.0)
+    p["w_gate"] = p["w_gate"].at[0].set(0.0).at[0, 4:6].set(-1.0)
+    result = _both(p, jnp.zeros((E,)), offset=4)
+    sel, counts = _assert_equal(result, zero=("w1", "w3", "w2", "x", "w_gate"))
+    assert counts[4] == counts[5] == 0 and counts.sum() == N * K
+    assert float(jnp.abs(result["ours"][0]).max()) == 0.0
+
+
+def test_a_bias_changes_the_selection_but_not_the_weights():
+    """A bias of +-2 on two experts moves them into and out of every token's
+    selection; the weights are still the unbiased scores of the selected,
+    and no gradient reaches the bias."""
+    p, offset = _weights(held=4), 2
+    bias = jnp.zeros((E,)).at[3].set(2.0).at[6].set(-2.0)
+    sel, counts = _assert_equal(_both(p, bias, offset))
+    plain_sel = _both(p, jnp.zeros((E,)), offset)["ours"][2][1]
+    assert counts[3] == N and counts[6] == 0
+    assert not np.array_equal(np.sort(sel, -1), np.sort(plain_sel, -1))
+    _, weights, _ = moe.route(p["x"], p["w_gate"], bias, top_k=K)
+    scores = jax.nn.sigmoid(p["x"] @ p["w_gate"])
+    picked = jnp.take_along_axis(scores, sel, -1)
+    np.testing.assert_allclose(
+        weights, picked / (picked.sum(-1, keepdims=True) + 1e-6), rtol=1e-6)
+    d_bias = jax.grad(lambda b: jnp.sum(ops.moe_layer(
+        p["x"], p["w_gate"], b, p["w1"], p["w3"], p["w2"], top_k=K,
+        expert_offset=offset)[0] * p["cot"]))(bias)
+    assert float(jnp.abs(d_bias).max()) == 0.0
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """Offsets 0, 2, 4, 6 of 8, two experts each: outputs and input gradients
+    of the four shares sum to those of the layer that holds all eight."""
+    p = _weights(held=E)
+    bias = 0.1 * jax.random.normal(jax.random.PRNGKey(3), (E,))
+
+    def share(x, offset, held):
+        cut = lambda w: w[offset:offset + held]
+        return ops.moe_layer(x, p["w_gate"], bias, cut(p["w1"]), cut(p["w3"]),
+                             cut(p["w2"]), top_k=K, expert_offset=offset)
+    whole, whole_counts, whole_sel = share(p["x"], 0, E)
+    parts = [share(p["x"], offset, 2) for offset in (0, 2, 4, 6)]
+    np.testing.assert_allclose(sum(y for y, _, _ in parts), whole, atol=2e-5)
+    for _, counts, sel in parts:        # every share routes over all eight
+        np.testing.assert_array_equal(counts, whole_counts)
+        np.testing.assert_array_equal(sel, whole_sel)
+    d_x = lambda offset, held: jax.grad(
+        lambda x: jnp.sum(share(x, offset, held)[0] * p["cot"]))(p["x"])
+    # the router's part of the input gradient is in every share's: the
+    # weights of a token's held experts; it adds up like the rest
+    np.testing.assert_allclose(sum(d_x(o, 2) for o in (0, 2, 4, 6)),
+                               d_x(0, E), atol=5e-5)
+
+
+def test_leading_axes_dtypes_and_refusals():
+    p = _weights(held=2)
+    x3 = p["x"].reshape(4, 12, D)
+    y, counts, sel = ops.moe_layer(x3, p["w_gate"], jnp.zeros((E,)), p["w1"],
+                                   p["w3"], p["w2"], top_k=K, expert_offset=4)
+    assert y.shape == x3.shape and sel.shape == (N, K) and counts.shape == (E,)
+    cast = lambda a: a.astype(jnp.bfloat16)
+    yb, _, _ = ops.moe_layer(cast(x3), p["w_gate"], jnp.zeros((E,)),
+                             cast(p["w1"]), cast(p["w3"]), cast(p["w2"]),
+                             top_k=K, expert_offset=4)
+    assert yb.dtype == jnp.bfloat16
+    np.testing.assert_allclose(yb.astype(jnp.float32), y, atol=0.1)
+    with pytest.raises(TypeError, match="router's weight arrived as bfloat16"):
+        ops.moe_layer(cast(x3), cast(p["w_gate"]), jnp.zeros((E,)), p["w1"],
+                      p["w3"], p["w2"], top_k=K)
+    with pytest.raises(ValueError, match="not among the router's 8"):
+        ops.moe_layer(x3, p["w_gate"], jnp.zeros((E,)), p["w1"], p["w3"],
+                      p["w2"], top_k=K, expert_offset=7)
+    assert moe.MOE_SCOPES == ("apex.moe", "apex.moe.route", "apex.moe.experts",
+                              "apex.moe.combine")

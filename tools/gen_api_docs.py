@@ -40,7 +40,8 @@ PAGES = {
     "normalization": ["apex_tpu.normalization",
                       "apex_tpu.normalization.fused_bn_act"],
     "ops": ["apex_tpu.ops.flash_attention", "apex_tpu.ops.attention",
-            "apex_tpu.ops.losses"],
+            "apex_tpu.ops.losses", "apex_tpu.ops.moe", "apex_tpu.ops.rope",
+            "apex_tpu.ops.short_conv"],
     "multi_tensor": ["apex_tpu.multi_tensor"],
     "bf16_utils": ["apex_tpu.bf16_utils"],
     "training": ["apex_tpu.training"],
