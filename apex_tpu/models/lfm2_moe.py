@@ -174,7 +174,7 @@ class RoutedExperts(nn.Module):
         load = self.variable("moe", "load", jnp.zeros, (self.num_experts,),
                              jnp.int32)
         with jax.named_scope(MOE_SCOPES[0]):
-            y, counts, sel = moe_layer(
+            y, counts, sel, walked = moe_layer(
                 x.astype(self.dtype), router, bias.value,
                 *(w.astype(self.dtype) for w in (w1, w3, w2)),
                 top_k=self.top_k, expert_offset=self.expert_offset,
@@ -184,6 +184,7 @@ class RoutedExperts(nn.Module):
             load.value = counts
         # read by a caller that asks for "intermediates"; nothing otherwise
         self.sow("intermediates", "selected", sel)
+        self.sow("intermediates", "rows_walked", walked)
         return y
 
 

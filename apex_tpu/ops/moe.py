@@ -21,10 +21,28 @@ sort; pairs whose expert is elsewhere sort to the tail), the rows of ``u``
 are gathered in that order, and the three products run as grouped matmuls
 over the sorted rows (``_grouped_matmul``: a kernel that walks only the tiles
 of rows that belong to a group, so the tail costs no expert FLOPs).  The
-static row bound is the worst case, every pair held here.  The result goes
-back as a weighted sum over each token's slots.  Both directions of both row
-movements are gathers (by the sort's permutation one way, by its inverse the
-other): no scatter-add on the path.
+result goes back as a weighted sum over each token's slots.  Both directions
+of both row movements are gathers (by the sort's permutation one way, by its
+inverse the other): no scatter-add on the path.
+
+**The row bound and what is walked.**  The arrays in the sorted order have
+the static worst case of rows, every pair held here (``N k``); a step holds
+``group_sizes.sum()`` of them, a quarter where the layer holds a quarter of
+the experts.  The kernels skip the tail by themselves.  Every other pass
+over such an array (``_walk_rows``) runs over blocks of ``_ROW_BLOCK`` rows
+under a loop whose trip count is the rows held this call, rounded up to a
+block, and leaves the blocks past them as they are (not zeros: every caller
+masks the tail, as it masks the kernels' tail): the gather of the sorted
+rows, ``silu(gate) * up`` and its backward, the backward of the weighted sum
+(the gather of ``dy`` by the sort, its product with the weights, the row
+dots) and the sum of the rows' two gradients through ``w1`` and ``w3``.
+Each is a custom VJP, since such a loop has no reverse-mode rule; the
+backward passes write in place of an operand they have read.  The kernels
+are called once, outside every loop.  Where the bound is within one block,
+or no whole number of blocks, the same functions run on the whole arrays.
+**Not walked:** the passes indexed by token (one gather of ``[N, D]`` a slot
+on the way back and in the gradient to ``u``, the scalars gathered by
+``pos``) and the router.  ``moe_layer`` returns the rows it walked.
 
 Gradients flow through the gathers, the grouped products (input and weight
 gradients by group, the kernels' own backward rules) and the weights ``w``
@@ -49,6 +67,10 @@ __all__ = ["MOE_SCOPES", "route", "moe_layer"]
 #: rows a tile of the grouped-matmul kernel: a group's edge inside a tile
 #: costs the tile twice, so smaller tiles lose less to uneven groups
 _ROW_TILE = 256
+
+#: rows a block of the walked passes (``_walk_rows``), a multiple of
+#: ``_ROW_TILE`` so that a tile the kernels touch lies in walked rows
+_ROW_BLOCK = 8 * _ROW_TILE
 
 #: the scopes of the expert layer, outermost first
 MOE_SCOPES = ("apex.moe", "apex.moe.route", "apex.moe.experts",
@@ -117,49 +139,169 @@ def _from_slots(rows, pos, held, weights=None):
     return total
 
 
+def _unwritten(shape, dtype, after):
+    """What a walk's result holds before its blocks are written, and after
+    them in the blocks the walk never reaches: an allocation, no fill
+    (``AllocateBuffer`` in the TPU's compiled module), made once ``after``, a
+    traced scalar that is never negative, is known.  The branch is what holds
+    it there: an allocation with no operand is moved to the head of the
+    compiled step, where the sixteen of a step were live at once (3.5 GiB; a
+    rehearsal compile of the LFM2 cell's step, ``PERF.md``, PR 32)."""
+    return jax.lax.cond(after >= 0, lambda: jax.lax.empty(shape, dtype),
+                        lambda: jnp.zeros(shape, dtype))
+
+
+def _whole(bound):
+    """Whether arrays of ``bound`` rows are passed over whole: within one
+    block, or no whole number of blocks (a decision by shape)."""
+    return bound <= _ROW_BLOCK or bound % _ROW_BLOCK != 0
+
+
+def _rows_walked(n_rows, bound):
+    """The rows of ``bound`` that ``_walk_rows`` goes over for ``n_rows``
+    held (a traced count): whole blocks, or all of them."""
+    if _whole(bound):
+        return jnp.int32(bound)
+    return (n_rows + _ROW_BLOCK - 1) // _ROW_BLOCK * _ROW_BLOCK
+
+
+def _walk_rows(fn, n_rows, *row_arrays, in_place=0):
+    """``fn(*row_arrays)``, a tuple of arrays whose row ``r`` depends on row
+    ``r`` of each argument alone, computed a block of ``_ROW_BLOCK`` rows at a
+    time over the blocks that hold the first ``n_rows`` rows (a traced
+    count).  The first ``in_place`` results take the place of the first
+    ``in_place`` arguments, of their shape and dtype, block by block; the
+    others are written into ``_unwritten`` arrays.  The blocks past
+    ``n_rows`` are left as they were: the tail's contract of
+    ``_grouped_matmul``.  A loop with a traced trip count has no reverse-mode
+    rule, so every caller is a custom VJP.  Where the arrays are no longer
+    than one block, or not whole blocks, ``fn`` runs on them whole."""
+    bound = row_arrays[0].shape[0]
+    if _whole(bound):
+        return fn(*row_arrays)
+    shapes = jax.eval_shape(fn, *(
+        jax.ShapeDtypeStruct((_ROW_BLOCK,) + a.shape[1:], a.dtype)
+        for a in row_arrays))
+
+    def body(i, results):
+        start = i * _ROW_BLOCK
+        blocks = fn(*(jax.lax.dynamic_slice_in_dim(a, start, _ROW_BLOCK)
+                      for a in results[:in_place] + row_arrays[in_place:]))
+        return tuple(jax.lax.dynamic_update_slice_in_dim(r, b, start, 0)
+                     for r, b in zip(results, blocks))
+    return jax.lax.fori_loop(
+        0, _rows_walked(n_rows, bound) // _ROW_BLOCK, body,
+        row_arrays[:in_place] + tuple(
+            _unwritten((bound,) + s.shape[1:], s.dtype, n_rows)
+            for s in shapes[in_place:]))
+
+
 @jax.custom_vjp
-def _sorted_rows(x, order, pos, held):
+def _sorted_rows(x, order, pos, held, n_rows):
     """Row ``r`` of the result is the token of pair ``order[r]``: ``x[order //
-    k]`` for ``x``: ``[N, D]`` and ``N k`` pairs.  Backward: the inverse
-    permutation, as gathers, summed over a token's slots."""
-    return x[order // held.shape[1]]
+    k]`` for ``x``: ``[N, D]`` and ``N k`` pairs, over the first ``n_rows``
+    rows.  Backward: the inverse permutation, as gathers, summed over a
+    token's slots."""
+    k = held.shape[1]
+    return _walk_rows(lambda o: (x[o // k],), n_rows, order)[0]
 
 
-def _sorted_rows_fwd(x, order, pos, held):
-    return _sorted_rows(x, order, pos, held), (pos, held)
+def _sorted_rows_fwd(x, order, pos, held, n_rows):
+    return _sorted_rows(x, order, pos, held, n_rows), (pos, held)
 
 
 def _sorted_rows_bwd(res, g):
     pos, held = res
-    return _from_slots(g, pos, held).astype(g.dtype), None, None, None
+    return _from_slots(g, pos, held).astype(g.dtype), None, None, None, None
 
 
 _sorted_rows.defvjp(_sorted_rows_fwd, _sorted_rows_bwd)
 
 
 @jax.custom_vjp
-def _combine(rows, weights, order, pos, held):
+def _gate_up(rows, w1, w3, group_sizes, n_rows):
+    """``(rows @ w1, rows @ w3)`` by group.  Backward: the kernels' own
+    rules; the two gradients of ``rows`` are added over the first ``n_rows``
+    rows, where autodiff would add the whole bound."""
+    return (_grouped_matmul(rows, w1, group_sizes),
+            _grouped_matmul(rows, w3, group_sizes))
+
+
+def _gate_up_fwd(rows, w1, w3, group_sizes, n_rows):
+    product = lambda r, w: _grouped_matmul(r, w, group_sizes)
+    gate, gate_vjp = jax.vjp(product, rows, w1)
+    up, up_vjp = jax.vjp(product, rows, w3)
+    return (gate, up), (gate_vjp, up_vjp, n_rows)
+
+
+def _gate_up_bwd(res, g):
+    gate_vjp, up_vjp, n_rows = res
+    (by_gate, d_w1), (by_up, d_w3) = gate_vjp(g[0]), up_vjp(g[1])
+    d_rows, = _walk_rows(lambda a, b: (a + b,), n_rows, by_gate, by_up,
+                         in_place=1)
+    return d_rows, d_w1, d_w3, None, None
+
+
+_gate_up.defvjp(_gate_up_fwd, _gate_up_bwd)
+
+
+def _silu_gate(gate, up):
+    """``(silu(gate) * up,)`` in float32, rounded once: a tuple, as
+    ``_walk_rows`` takes its functions."""
+    return (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+            ).astype(gate.dtype),
+
+
+@jax.custom_vjp
+def _activation(gate, up, n_rows):
+    """``silu(gate) * up`` in float32, rounded once, over the first ``n_rows``
+    rows; backward over the same rows, from ``gate`` and ``up``."""
+    return _walk_rows(_silu_gate, n_rows, gate, up)[0]
+
+
+def _activation_fwd(gate, up, n_rows):
+    return _activation(gate, up, n_rows), (gate, up, n_rows)
+
+
+def _activation_bwd(res, d_act):
+    gate, up, n_rows = res
+    block = lambda g, u, d: jax.vjp(_silu_gate, g, u)[1]((d,))
+    return _walk_rows(block, n_rows, gate, up, d_act, in_place=2) + (None,)
+
+
+_activation.defvjp(_activation_fwd, _activation_bwd)
+
+
+@jax.custom_vjp
+def _combine(rows, weights, order, pos, held, n_rows):
     """``y[n] = sum_s weights[n, s] rows[pos[n, s]]`` over the slots that are
-    held, float32 sums rounded once.  Backward, in the sorted order: one
-    gather of ``dy`` by ``order`` serves ``d rows[r] = weights[pair r] dy[token
-    of r]`` and the weights' gradient ``<dy[token of r], rows[r]>``, which
-    goes back to its pair as a gather of scalars."""
+    held, float32 sums rounded once.  Backward, in the sorted order over the
+    first ``n_rows`` rows: one gather of ``dy`` by ``order`` serves ``d
+    rows[r] = weights[pair r] dy[token of r]`` and the weights' gradient
+    ``<dy[token of r], rows[r]>``, which goes back to its pair as a gather of
+    scalars."""
     return _from_slots(rows, pos, held, weights).astype(rows.dtype)
 
 
-def _combine_fwd(rows, weights, order, pos, held):
-    return (_combine(rows, weights, order, pos, held),
-            (rows, weights, order, pos, held))
+def _combine_fwd(rows, weights, order, pos, held, n_rows):
+    return (_combine(rows, weights, order, pos, held, n_rows),
+            (rows, weights, order, pos, held, n_rows))
 
 
 def _combine_bwd(res, dy):
-    rows, weights, order, pos, held = res
-    dy_rows = dy[order // held.shape[1]].astype(jnp.float32)
-    # weights are zero where a pair is not held, so the tail's rows get zero;
-    # the tail of ``rows`` is whatever no group wrote, and is masked
-    d_rows = (dy_rows * weights.reshape(-1)[order][:, None]).astype(rows.dtype)
-    dots = (dy_rows * rows.astype(jnp.float32)).sum(-1)
-    return d_rows, jnp.where(held, dots[pos], 0), None, None, None
+    rows, weights, order, pos, held, n_rows = res
+    k, by_pair = held.shape[1], weights.reshape(-1)
+
+    def block(rows, order):
+        dy_rows = dy[order // k].astype(jnp.float32)
+        # weights are zero where a pair is not held, so the rows between the
+        # last one held and the block's end get zero
+        return ((dy_rows * by_pair[order][:, None]).astype(rows.dtype),
+                (dy_rows * rows.astype(jnp.float32)).sum(-1))
+    # the tail of ``rows`` is whatever no group wrote, the tail of ``dots``
+    # what no block wrote: masked
+    d_rows, dots = _walk_rows(block, n_rows, rows, order, in_place=1)
+    return (d_rows, jnp.where(held, dots[pos], 0), None, None, None, None)
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -173,9 +315,12 @@ def moe_layer(x, w_gate, bias, w1, w3, w2, *, top_k: int,
     ``x``: ``[..., D]`` in the compute dtype; ``w_gate``: ``[D, E]`` and
     ``bias``: ``[E]``, float32; ``w1``, ``w3``: ``[G, D, F]`` and ``w2``:
     ``[G, F, D]``, the experts ``expert_offset .. expert_offset + G`` of the
-    ``E`` the router knows.  Returns ``(y, counts, sel)``: ``y`` of ``x``'s
-    shape and dtype, ``counts``: ``[E]`` int32 rows sent to each of the ``E``
-    experts by these tokens, ``sel``: ``[N, top_k]`` the selection."""
+    ``E`` the router knows.  Returns ``(y, counts, sel, rows_walked)``: ``y``
+    of ``x``'s shape and dtype, ``counts``: ``[E]`` int32 rows sent to each of
+    the ``E`` experts by these tokens, ``sel``: ``[N, top_k]`` the selection,
+    ``rows_walked``: int32, the rows of the ``N top_k`` the row passes went
+    over (the rows held, rounded up to a block; all of them where they are
+    within one block or not whole blocks)."""
     lead, d = x.shape[:-1], x.shape[-1]
     e, g = w_gate.shape[1], w1.shape[0]
     if not 0 <= expert_offset <= e - g:
@@ -198,13 +343,12 @@ def moe_layer(x, w_gate, bias, w1, w3, w2, *, top_k: int,
             jnp.arange(n * top_k, dtype=jnp.int32), unique_indices=True
         ).reshape(n, top_k)
         group_sizes = jax.lax.dynamic_slice_in_dim(counts, expert_offset, g)
+        n_rows = group_sizes.sum()
     with jax.named_scope(_EXPERTS):
-        rows = _sorted_rows(x, order, pos, held)
-        gate = _grouped_matmul(rows, w1, group_sizes)
-        up = _grouped_matmul(rows, w3, group_sizes)
-        act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-               ).astype(x.dtype)
-        out = _grouped_matmul(act, w2, group_sizes)
+        rows = _sorted_rows(x, order, pos, held, n_rows)
+        gate, up = _gate_up(rows, w1, w3, group_sizes, n_rows)
+        out = _grouped_matmul(_activation(gate, up, n_rows), w2, group_sizes)
     with jax.named_scope(_COMBINE):
-        y = _combine(out, weights, order, pos, held)
-    return y.reshape(lead + (d,)), counts, sel
+        y = _combine(out, weights, order, pos, held, n_rows)
+    return (y.reshape(lead + (d,)), counts, sel,
+            _rows_walked(n_rows, n * top_k))

@@ -2,8 +2,10 @@
 amp O2 keeps float32, the model's state (selection bias and load counts), and
 the O2 train step at a tiny size: finite, the loss falls, the load counts of a
 step sum to ``tokens x k``, the bias receives no update, and the new scopes
-reach the compiled module under ``apex.forward`` and its transpose.  The
-comparison with the plain reference is in ``tests/benchmark``."""
+reach the compiled module under ``apex.forward`` and its transpose; with a
+block small enough for the tiny size, the expert layer's row passes are loops
+in the compiled step and the layer says how far they went.  The comparison
+with the plain reference is in ``tests/benchmark``."""
 
 import jax
 import jax.numpy as jnp
@@ -139,8 +141,7 @@ def test_a_share_of_the_experts_is_a_constructor_argument():
                                   seen_cut["moe"][first]["experts"]["load"])
 
 
-@pytest.fixture(scope="module")
-def o2_step():
+def _o2_step():
     model = models.lfm2_moe_tiny(dtype=jnp.bfloat16, experts_held=4,
                                  expert_offset=2)
     variables = _init(model)
@@ -165,6 +166,9 @@ def o2_step():
             jax.random.PRNGKey(i), (8,))
     return (jax.jit(step_fn), init_fn(variables["params"], state),
             (ids[:, :-1], ids[:, 1:]))
+
+
+o2_step = pytest.fixture(scope="module")(_o2_step)
 
 
 def test_o2_step_is_finite_the_loss_falls_and_the_state_is_kept(o2_step):
@@ -218,3 +222,61 @@ def test_the_new_scopes_reach_the_compiled_step(o2_step):
                    for line in compiled.splitlines()), scope
     assert "/layer_0/experts/" not in compiled and "/layer_1/mlp/" not in compiled
     assert "/layer_1/conv/" not in compiled and "/layer_2/attention/" not in compiled
+
+
+#: 2 x 33 tokens x 4 slots = 264 rows a layer: three blocks of 88
+WALKED_BLOCK = 88
+
+
+def test_the_walked_step_holds_loops_under_the_experts_scope(monkeypatch):
+    """The O2 step of the tiny model with a block that its 264 rows fill
+    three times: the row passes are ``while`` loops under
+    ``apex.moe.experts`` and ``apex.moe.combine``, forward, recomputed and
+    backward; with the block as shipped the same step has none."""
+    loops = lambda text: [line for line in text.splitlines()
+                          if " while(" in line and "/apex.moe/" in line]
+    step, state, batch = _o2_step()
+    assert not loops(step.lower(state, batch).compile().as_text())
+    monkeypatch.setattr(moe, "_ROW_BLOCK", WALKED_BLOCK)
+    step, state, batch = _o2_step()
+    found = loops(step.lower(state, batch).compile().as_text())
+    experts = [line for line in found if "/apex.moe.experts/" in line]
+    assert any("/jvp(apex.forward)/Lfm2Moe/layer_1/" in line
+               for line in experts)
+    assert any("transpose(jvp(apex.forward))" in line
+               and "rematted_computation" in line for line in experts)
+    assert any("transpose(jvp(apex.forward))" in line
+               and "rematted_computation" not in line for line in experts)
+    assert any("transpose(jvp(apex.forward))" in line
+               and "/apex.moe.combine/" in line for line in found)
+    assert not any("/apex.moe.route/" in line for line in found)
+    state, metrics = step(state, batch)
+    assert np.isfinite(float(metrics["loss"])) and not bool(
+        metrics["overflow"])
+
+
+@pytest.mark.parametrize("block", [WALKED_BLOCK, None],
+                         ids=["walked", "as_shipped"])
+def test_rows_walked_is_an_intermediate_and_the_state_keeps_its_keys(
+        block, monkeypatch):
+    if block:
+        monkeypatch.setattr(moe, "_ROW_BLOCK", block)
+    model = models.lfm2_moe_tiny(experts_held=4, expert_offset=2)
+    variables = _init(model)
+    ids = _ids()[:, :-1]
+    _, seen = model.apply(variables, ids, mutable=["intermediates", "moe"])
+    assert set(seen["moe"]) == set(seen["intermediates"]) == {
+        "layer_1", "layer_2", "layer_3", "layer_4"}
+    bound = BATCH * SEQ * 4
+    for name, state in seen["moe"].items():
+        assert set(state["experts"]) == {"selection_bias", "load"}
+        sown = seen["intermediates"][name]["experts"]
+        assert set(sown) == {"selected", "rows_walked"}
+        (walked,) = sown["rows_walked"]
+        held = int(state["experts"]["load"][2:6].sum())
+        assert walked.dtype == jnp.int32 and 0 < held < bound
+        assert int(walked) == (-(-held // block) * block if block else bound)
+    # nothing is sown, and the state is what it was, for a caller that
+    # does not ask
+    _, quiet = model.apply(variables, ids, mutable=["moe"])
+    assert set(quiet) == {"moe"}

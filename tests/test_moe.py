@@ -3,7 +3,11 @@ by expert, grouped products over the experts held, no capacity) against a
 dense loop over the experts written here, forward and every gradient: under a
 balanced routing, one that sends **every** token to one held expert, one that
 sends none to a held expert, and with a bias that changes the selection but
-not the weights; the four shares of a layer add up to the uncut layer."""
+not the weights; the four shares of a layer add up to the uncut layer.  Every
+one of those on both paths of the row passes: whole arrays (the bound of 192
+rows is within one block) and walked, with a block of 16 rows.  Then the
+walked path against the straight one bit for bit, at six loads, with the
+blocks it does not reach poisoned, and the rows it says it walked."""
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +18,18 @@ from apex_tpu import ops
 from apex_tpu.ops import moe
 
 N, D, F, E, K = 48, 16, 12, 8, 4
+BLOCK = 16
+
+
+@pytest.fixture(params=["straight", "walked"])
+def path(request, monkeypatch):
+    if request.param == "walked":
+        monkeypatch.setattr(moe, "_ROW_BLOCK", BLOCK)
+    return request.param
+
+
+#: the six tests of the layer run on both paths of ``_walk_rows``
+both_paths = pytest.mark.usefixtures("path")
 
 
 def dense_loop(x, w_gate, bias, w1, w3, w2, offset):
@@ -58,7 +74,7 @@ def _both(p, bias, offset):
 
 
 def _assert_equal(result, zero=()):
-    (y, grads, (counts, sel)), (want, want_grads, (want_sel,)) = (
+    (y, grads, (counts, sel, _)), (want, want_grads, (want_sel,)) = (
         result["ours"], result["loop"])
     np.testing.assert_array_equal(np.sort(sel, -1), np.sort(want_sel, -1))
     np.testing.assert_array_equal(
@@ -76,6 +92,7 @@ def _assert_equal(result, zero=()):
     return sel, counts
 
 
+@both_paths
 @pytest.mark.parametrize("held,offset", [(8, 0), (2, 0), (2, 4), (3, 5)])
 def test_balanced_routing_equals_the_dense_loop(held, offset):
     sel, counts = _assert_equal(_both(_weights(held), jnp.zeros((E,)), offset))
@@ -83,6 +100,7 @@ def test_balanced_routing_equals_the_dense_loop(held, offset):
     assert sel.shape == (N, K) and len(np.unique(np.asarray(sel))) == E
 
 
+@both_paths
 def test_every_token_to_one_held_expert_and_no_row_is_dropped():
     """One column of the router towers over the rest: all 48 tokens select
     expert 5, which is held, and every one of its rows is computed."""
@@ -99,6 +117,7 @@ def test_every_token_to_one_held_expert_and_no_row_is_dropped():
         (jnp.abs(y).sum(-1) > 0).all())
 
 
+@both_paths
 def test_no_token_to_a_held_expert_gives_zero_and_zero_gradients():
     """The two held experts' columns are far below the rest: no pair is held,
     every group is empty, output and the experts' gradients are exact zeros."""
@@ -111,6 +130,7 @@ def test_no_token_to_a_held_expert_gives_zero_and_zero_gradients():
     assert float(jnp.abs(result["ours"][0]).max()) == 0.0
 
 
+@both_paths
 def test_a_bias_changes_the_selection_but_not_the_weights():
     """A bias of +-2 on two experts moves them into and out of every token's
     selection; the weights are still the unbiased scores of the selected,
@@ -132,6 +152,7 @@ def test_a_bias_changes_the_selection_but_not_the_weights():
     assert float(jnp.abs(d_bias).max()) == 0.0
 
 
+@both_paths
 def test_the_four_shares_add_up_to_the_uncut_layer():
     """Offsets 0, 2, 4, 6 of 8, two experts each: outputs and input gradients
     of the four shares sum to those of the layer that holds all eight."""
@@ -142,10 +163,10 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
         cut = lambda w: w[offset:offset + held]
         return ops.moe_layer(x, p["w_gate"], bias, cut(p["w1"]), cut(p["w3"]),
                              cut(p["w2"]), top_k=K, expert_offset=offset)
-    whole, whole_counts, whole_sel = share(p["x"], 0, E)
+    whole, whole_counts, whole_sel, _ = share(p["x"], 0, E)
     parts = [share(p["x"], offset, 2) for offset in (0, 2, 4, 6)]
-    np.testing.assert_allclose(sum(y for y, _, _ in parts), whole, atol=2e-5)
-    for _, counts, sel in parts:        # every share routes over all eight
+    np.testing.assert_allclose(sum(y for y, *_ in parts), whole, atol=2e-5)
+    for _, counts, sel, _ in parts:     # every share routes over all eight
         np.testing.assert_array_equal(counts, whole_counts)
         np.testing.assert_array_equal(sel, whole_sel)
     d_x = lambda offset, held: jax.grad(
@@ -156,16 +177,19 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
                                d_x(0, E), atol=5e-5)
 
 
+@both_paths
 def test_leading_axes_dtypes_and_refusals():
     p = _weights(held=2)
     x3 = p["x"].reshape(4, 12, D)
-    y, counts, sel = ops.moe_layer(x3, p["w_gate"], jnp.zeros((E,)), p["w1"],
-                                   p["w3"], p["w2"], top_k=K, expert_offset=4)
+    y, counts, sel, walked = ops.moe_layer(
+        x3, p["w_gate"], jnp.zeros((E,)), p["w1"], p["w3"], p["w2"], top_k=K,
+        expert_offset=4)
     assert y.shape == x3.shape and sel.shape == (N, K) and counts.shape == (E,)
+    assert walked.shape == () and walked.dtype == jnp.int32
     cast = lambda a: a.astype(jnp.bfloat16)
-    yb, _, _ = ops.moe_layer(cast(x3), p["w_gate"], jnp.zeros((E,)),
-                             cast(p["w1"]), cast(p["w3"]), cast(p["w2"]),
-                             top_k=K, expert_offset=4)
+    yb, *_ = ops.moe_layer(cast(x3), p["w_gate"], jnp.zeros((E,)),
+                           cast(p["w1"]), cast(p["w3"]), cast(p["w2"]),
+                           top_k=K, expert_offset=4)
     assert yb.dtype == jnp.bfloat16
     np.testing.assert_allclose(yb.astype(jnp.float32), y, atol=0.1)
     with pytest.raises(TypeError, match="router's weight arrived as bfloat16"):
@@ -176,3 +200,110 @@ def test_leading_axes_dtypes_and_refusals():
                       p["w2"], top_k=K, expert_offset=7)
     assert moe.MOE_SCOPES == ("apex.moe", "apex.moe.route", "apex.moe.experts",
                               "apex.moe.combine")
+
+
+#: name -> (experts held, offset, tokens steered to the one held expert or
+#: None for the router's own choice, rows held)
+LOADS = {"no_row": (1, 7, 0, 0), "one_row": (1, 7, 1, 1),
+         "a_block": (1, 7, BLOCK, BLOCK),
+         "a_block_and_one": (1, 7, BLOCK + 1, BLOCK + 1),
+         "ragged_middle": (3, 5, None, 71), "every_pair": (E, 0, None, N * K)}
+
+
+def _steered(held, offset, tokens):
+    """The layer's arguments with the first ``tokens`` tokens sent to the one
+    held expert and no other token (a feature of +-4 that only that expert's
+    column reads, against scores of the others that lie well inside (0, 1))."""
+    p = _weights(held)
+    if tokens is not None:
+        p["x"] = p["x"].at[:, 0].set(
+            jnp.where(jnp.arange(N) < tokens, 4., -4.))
+        p["w_gate"] = p["w_gate"].at[0].set(0.0).at[0, offset].set(2.0)
+    return p
+
+
+def _jitted(p, offset, dtype=jnp.float32):
+    """(y, counts, sel, rows walked) and the five gradients, as one program."""
+    cast = lambda n: p[n].astype(dtype)
+    args = (cast("x"), p["w_gate"], cast("w1"), cast("w3"), cast("w2"))
+    layer = lambda *a: ops.moe_layer(a[0], a[1], jnp.zeros((E,)), *a[2:],
+                                     top_k=K, expert_offset=offset)
+    loss = lambda *a: jnp.sum(layer(*a)[0].astype(jnp.float32) * p["cot"])
+    return jax.jit(lambda *a: (layer(*a), jax.grad(loss, range(5))(*a)))(*args)
+
+
+def _assert_bitwise(got, want):
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want), strict=True):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("load", LOADS)
+def test_walked_equals_straight_bit_for_bit(load, dtype, monkeypatch):
+    """Output and the gradients to x, the router and w1, w3, w2 on the walked
+    path are those of the straight path to the last bit (the tail is masked
+    in both, so every value compared is of a held row), and the layer says
+    how far it walked."""
+    held, offset, tokens, rows = LOADS[load]
+    p = _steered(held, offset, tokens)
+    assert moe._ROW_BLOCK > N * K           # as shipped: whole arrays
+    (*straight, bound), straight_grads = _jitted(p, offset, dtype)
+    assert int(straight[1][offset:offset + held].sum()) == rows
+    assert int(bound) == N * K
+    monkeypatch.setattr(moe, "_ROW_BLOCK", BLOCK)
+    (*walked, n_walked), walked_grads = _jitted(p, offset, dtype)
+    assert int(n_walked) == -(-rows // BLOCK) * BLOCK
+    _assert_bitwise((walked, walked_grads), (straight, straight_grads))
+    if rows:
+        assert float(jnp.abs(walked[0].astype(jnp.float32)).max()) > 0
+
+
+def _poisoned(shape, dtype, after):
+    """In place of ``moe._unwritten``: NaN where no block is written."""
+    return jnp.full(shape, jnp.nan, dtype)
+
+
+@pytest.mark.parametrize("load", LOADS)
+def test_a_poisoned_tail_changes_nothing(load, monkeypatch):
+    """The blocks a walk does not reach hold NaN in place of whatever the
+    allocation held: output and gradients are the same bits."""
+    held, offset, tokens, _ = LOADS[load]
+    p = _steered(held, offset, tokens)
+    monkeypatch.setattr(moe, "_ROW_BLOCK", BLOCK)
+    clean = _jitted(p, offset)
+    monkeypatch.setattr(moe, "_unwritten", _poisoned)
+    _assert_bitwise(_jitted(p, offset), clean)
+    assert all(bool(jnp.isfinite(a).all())
+               for a in jax.tree_util.tree_leaves(clean[1]))
+
+
+@pytest.mark.parametrize("bound,n_rows", [(48, 48), (48, 33), (48, 16),
+                                          (48, 5), (48, 0), (40, 7), (16, 3)])
+def test_walk_rows_alone(bound, n_rows, monkeypatch):
+    """The helper: results written into arrays of their own hold NaN (the
+    test's ``_unwritten``) wherever no block was written; results written in
+    place of an argument hold the argument there; a bound within one block,
+    or of no whole blocks, is computed whole."""
+    monkeypatch.setattr(moe, "_ROW_BLOCK", BLOCK)
+    monkeypatch.setattr(moe, "_unwritten", _poisoned)
+    a = jnp.arange(bound * 3, dtype=jnp.float32).reshape(bound, 3)
+    b = jnp.arange(bound, dtype=jnp.int32)
+    fn = lambda a, b: (a * 2 + b[:, None], (b + 1).astype(jnp.float32))
+    want_twice, want_plus = fn(a, b)
+    whole = bound <= BLOCK or bound % BLOCK
+    written = bound if whole else -(-n_rows // BLOCK) * BLOCK
+    assert int(moe._rows_walked(jnp.int32(n_rows), bound)) == written
+    for in_place in (0, 1):
+        twice, plus = jax.jit(lambda n: moe._walk_rows(
+            fn, n, a, b, in_place=in_place))(n_rows)
+        np.testing.assert_array_equal(twice[:written], want_twice[:written])
+        np.testing.assert_array_equal(plus[:written], want_plus[:written])
+        assert bool(jnp.isnan(plus[written:]).all())
+        if in_place:
+            np.testing.assert_array_equal(twice[written:], a[written:])
+        else:
+            assert bool(jnp.isnan(twice[written:]).all())
