@@ -15,7 +15,8 @@ Mamba-2 mixer: ``[z | xBC | dt] = in_proj(x)``; ``xBC = silu(causal
 depthwise conv(xBC) + b)``; ``[x | B | C] = split(xBC)``; ``dt = softplus(dt
 + dt_bias)``; ``A = -exp(A_log)``; ``y = ssd(x, dt, A, B, C, D)``
 (:mod:`apex_tpu.ops.ssd`); ``y = RMSNorm(y * silu(z))`` over all of
-``d_inner``; ``out_proj(y)``.  Between its two projections the mixer keeps
+``d_inner`` (over each group's channels where ``n_groups > 1``);
+``out_proj(y)``.  Between its two projections the mixer keeps
 every array as ``[batch, chunk, channels, tokens of the chunk]``, which is
 what the scan's products read and write: the conv reads a chunk's first
 tokens from the chunk before, nothing is re-tiled, and what crosses HBM is
@@ -103,7 +104,22 @@ class _Leaf(nn.Module):
                           jnp.float32)
 
 
+def _grouped_gated_norm(y, z, scale, groups, eps):
+    """``RMSNorm(y * silu(z))`` over each of ``groups`` runs of channels
+    (axis 2 of ``[batch, chunk, channels, tokens]``), float32 between the
+    load and the store."""
+    gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    by_group = gated.reshape(gated.shape[:2] + (groups, -1) + gated.shape[3:])
+    by_group = by_group * jax.lax.rsqrt(
+        jnp.mean(by_group * by_group, axis=3, keepdims=True) + eps)
+    return (by_group.reshape(gated.shape) * scale[:, None]).astype(y.dtype)
+
+
 class Mamba2Mixer(nn.Module):
+    """``n_groups`` groups of B and C, each read by ``num_heads / n_groups``
+    heads; with more than one group the gated norm is over each group's
+    ``num_heads * head_dim / n_groups`` channels (a tensor-parallel rank that
+    holds whole groups norms locally), with one over all of them."""
     num_heads: int = 64
     head_dim: int = 64
     state_size: int = 128
@@ -166,10 +182,17 @@ class Mamba2Mixer(nn.Module):
             with jax.named_scope(_SSM_NORM):
                 scale = _Leaf("scale", nn.initializers.ones, (d_inner,),
                               name="norm")()
-                y, inv_rms = gated_rms_norm_factors(
-                    y.reshape(lead + (d_inner, q)), z, scale, self.eps, axis=2)
+                y = y.reshape(lead + (d_inner, q))
+                if g == 1:
+                    y, inv_rms = gated_rms_norm_factors(y, z, scale, self.eps,
+                                                        axis=2)
+                else:
+                    y = _grouped_gated_norm(y, z, scale, g, self.eps)
             w_out = _Leaf("kernel", _dense_init, (d_inner, d),
                           name="out_proj")().astype(self.dtype)
+            if g > 1:       # a factor a group does not commute with out_proj
+                out = jnp.einsum("bceq,ed->bcqd", y, w_out)
+                return out.astype(self.dtype).reshape(b, t + tail, d)[:, :t]
             # the norm's factor of a token commutes with the product over the
             # channels: it scales the float32 sums, one pass over y earlier
             out = jnp.einsum("bceq,ed->bcqd", y, w_out,

@@ -13,7 +13,7 @@ from .flash_attention import flash_attention  # noqa: F401
 from .ssd import causal_conv_silu, ssd_chunked, ssd_scan  # noqa: F401
 from .short_conv import gated_short_conv  # noqa: F401
 from .rope import apply_rope, qk_norm_rope, rope_angles  # noqa: F401
-from .moe import moe_layer  # noqa: F401
+from .moe import latent_moe_layer, moe_layer  # noqa: F401
 from . import losses  # noqa: F401
 from .losses import (binary_cross_entropy,  # noqa: F401
                      binary_cross_entropy_with_logits)

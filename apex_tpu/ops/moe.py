@@ -53,6 +53,20 @@ Named scopes (metadata, like ``training.PHASE_SCOPES``): ``apex.moe.route``
 (scores, top-k, weights, sort, counts), ``apex.moe.experts`` (gather, grouped
 products, activation), ``apex.moe.combine``; the caller puts ``apex.moe``
 around the call.
+
+**The latent layer** (:func:`latent_moe_layer`) is the same layer for experts
+that live in a latent space: the router reads one array (the hidden state)
+and the experts another (its projection), and an expert is two products
+around ``relu(.) ** 2`` with no gate.  It shares the router and the sort, and
+**its row arrays follow the load**: with 22 experts a token and 8 of 512 held
+the static worst case is 64 times the rows a step holds, so the sorted rows
+are run in waves of ``_WAVE_ROWS`` rows under a loop whose trip count is the
+rows held, rounded up to a wave.  A wave gathers its rows, runs the two
+grouped products over its part of every group, and adds its weighted rows to
+their tokens; no array has more rows than a wave, and still no pair is
+dropped at any load (every pair held here is ``N k / _WAVE_ROWS`` waves).
+The loop has no reverse-mode rule: the chain is a custom VJP that keeps its
+inputs and runs the waves again backward, each recomputing its own forward.
 """
 
 from __future__ import annotations
@@ -62,7 +76,8 @@ import jax.numpy as jnp
 
 from ..normalization.fused_layer_norm import _use_pallas
 
-__all__ = ["MOE_SCOPES", "route", "moe_layer"]
+__all__ = ["MOE_SCOPES", "LATENT_SCOPES", "route", "moe_layer",
+           "latent_moe_layer"]
 
 #: rows a tile of the grouped-matmul kernel: a group's edge inside a tile
 #: costs the tile twice, so smaller tiles lose less to uneven groups
@@ -77,6 +92,14 @@ MOE_SCOPES = ("apex.moe", "apex.moe.route", "apex.moe.experts",
               "apex.moe.combine")
 _ROUTE, _EXPERTS, _COMBINE = MOE_SCOPES[1:]
 
+#: what a latent layer's caller adds inside ``apex.moe``: the projections
+#: into and out of the latent space, and the shared expert
+LATENT_SCOPES = ("apex.moe.latent", "apex.moe.shared")
+
+#: rows a wave of the latent layer's expert chain, a multiple of ``_ROW_TILE``:
+#: two to three times the rows a layer holds where it holds 8 of 512 experts
+#: for 16,384 tokens at 22 a token, so that an uneven router stays one wave
+_WAVE_ROWS = 64 * _ROW_TILE
 
 def route(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool = True,
           scaling: float = 1.0):
@@ -99,10 +122,16 @@ def route(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool = True,
     scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), w_gate,
                                     precision=jax.lax.Precision.HIGHEST))
     _, sel = jax.lax.top_k(jax.lax.stop_gradient(scores) + bias, top_k)
-    weights = jnp.take_along_axis(scores, sel, axis=-1)
+    experts = jnp.arange(w_gate.shape[1], dtype=sel.dtype)
+    # scores[sel] through a mask: the same numbers (the other terms of a sum
+    # are zeros) as one pass of compares, and its transpose another, where a
+    # gather's is a scatter-add of N k scalars into [N, E] (on the v5e 4 ns
+    # an element and twice that: 1.55 and 3.1 ms at 16,384 tokens, 22 of 512;
+    # ``PERF.md``, PR 33)
+    weights = jnp.where(sel[..., None] == experts, scores[:, None, :],
+                        0).sum(-1)
     if norm_topk_prob:
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
-    experts = jnp.arange(w_gate.shape[1], dtype=sel.dtype)
     counts = (sel[..., None] == experts).sum((0, 1), dtype=jnp.int32)
     return sel, weights * scaling, counts
 
@@ -307,6 +336,29 @@ def _combine_bwd(res, dy):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _route_and_sort(x, w_gate, bias, g, *, top_k, expert_offset,
+                    norm_topk_prob, scaling):
+    """:func:`route`, then the (token, slot) pairs by expert held: ``(sel,
+    weights, counts, held, order, group_sizes)`` with ``weights`` zero where
+    a pair's expert is elsewhere, ``order``: ``[N top_k]`` the pairs by expert
+    held, in token order within an expert, the pairs whose expert is
+    elsewhere last, and ``group_sizes``: ``[g]`` the rows of each expert
+    held."""
+    e = w_gate.shape[1]
+    if not 0 <= expert_offset <= e - g:
+        raise ValueError(f"moe_layer: experts {expert_offset} .. "
+                         f"{expert_offset + g} are not among the router's {e}")
+    sel, weights, counts = route(x, w_gate, bias, top_k=top_k,
+                                 norm_topk_prob=norm_topk_prob,
+                                 scaling=scaling)
+    local = sel - expert_offset
+    held = (local >= 0) & (local < g)
+    order = jnp.argsort(jnp.where(held, local, g).reshape(-1), stable=True
+                        ).astype(jnp.int32)
+    return (sel, jnp.where(held, weights, 0), counts, held, order,
+            jax.lax.dynamic_slice_in_dim(counts, expert_offset, g))
+
+
 def moe_layer(x, w_gate, bias, w1, w3, w2, *, top_k: int,
               expert_offset: int = 0, norm_topk_prob: bool = True,
               routed_scaling_factor: float = 1.0):
@@ -322,27 +374,16 @@ def moe_layer(x, w_gate, bias, w1, w3, w2, *, top_k: int,
     over (the rows held, rounded up to a block; all of them where they are
     within one block or not whole blocks)."""
     lead, d = x.shape[:-1], x.shape[-1]
-    e, g = w_gate.shape[1], w1.shape[0]
-    if not 0 <= expert_offset <= e - g:
-        raise ValueError(f"moe_layer: experts {expert_offset} .. "
-                         f"{expert_offset + g} are not among the router's {e}")
+    g = w1.shape[0]
     x = x.reshape(-1, d)
     n = x.shape[0]
     with jax.named_scope(_ROUTE):
-        sel, weights, counts = route(x, w_gate, bias, top_k=top_k,
-                                     norm_topk_prob=norm_topk_prob,
-                                     scaling=routed_scaling_factor)
-        local = sel - expert_offset
-        held = (local >= 0) & (local < g)
-        weights = jnp.where(held, weights, 0)
-        # pairs by expert held, in token order within an expert; the pairs
-        # whose expert is elsewhere last
-        order = jnp.argsort(jnp.where(held, local, g).reshape(-1), stable=True
-                            ).astype(jnp.int32)
+        sel, weights, counts, held, order, group_sizes = _route_and_sort(
+            x, w_gate, bias, g, top_k=top_k, expert_offset=expert_offset,
+            norm_topk_prob=norm_topk_prob, scaling=routed_scaling_factor)
         pos = jnp.zeros_like(order).at[order].set(
             jnp.arange(n * top_k, dtype=jnp.int32), unique_indices=True
         ).reshape(n, top_k)
-        group_sizes = jax.lax.dynamic_slice_in_dim(counts, expert_offset, g)
         n_rows = group_sizes.sum()
     with jax.named_scope(_EXPERTS):
         rows = _sorted_rows(x, order, pos, held, n_rows)
@@ -352,3 +393,139 @@ def moe_layer(x, w_gate, bias, w1, w3, w2, *, top_k: int,
         y = _combine(out, weights, order, pos, held, n_rows)
     return (y.reshape(lead + (d,)), counts, sel,
             _rows_walked(n_rows, n * top_k))
+
+
+def _relu2_chain(rows, w1, w2, group_sizes):
+    """``relu(rows @ w1) ** 2 @ w2`` by group, the square in float32, rounded
+    once.  The rows past the last group are whatever no group wrote."""
+    hidden = _grouped_matmul(rows, w1, group_sizes)
+    act = jnp.square(jax.nn.relu(hidden.astype(jnp.float32)))
+    return _grouped_matmul(act.astype(rows.dtype), w2, group_sizes)
+
+
+def _wave(i, wave, order, group_sizes, k):
+    """What wave ``i`` of ``wave`` sorted rows holds: the pairs of its rows
+    (``order`` has whole waves), which of them are rows held (the others are
+    the tail's), their tokens, and its part of every group."""
+    start = i * wave
+    pairs = jax.lax.dynamic_slice_in_dim(order, start, wave)
+    ends = jnp.cumsum(group_sizes)
+    live = start + jnp.arange(wave, dtype=jnp.int32) < ends[-1]
+    inside = lambda edge: jnp.clip(edge - start, 0, wave)
+    return pairs, live, pairs // k, inside(ends) - inside(ends - group_sizes)
+
+
+def _n_waves(group_sizes, wave):
+    return (group_sizes.sum() + wave - 1) // wave
+
+
+@jax.custom_vjp
+def _latent_chain(latent, weights, w1, w2, order, group_sizes):
+    """``y[n] = sum_s weights[n, s] relu(latent[n] @ w1[e]) ** 2 @ w2[e]``
+    over the slots ``s`` of token ``n`` whose expert ``e`` is held (the
+    others have weight zero), float32 sums rounded once, computed a wave of
+    ``order``'s rows at a time over the waves that hold rows.  ``order``
+    comes as whole waves."""
+    k = weights.shape[1]
+    wave = min(_WAVE_ROWS, order.shape[0])
+    by_pair = weights.reshape(-1)
+
+    def body(i, total):
+        pairs, live, tokens, sizes = _wave(i, wave, order, group_sizes, k)
+        with jax.named_scope(_EXPERTS):
+            out = _relu2_chain(latent[tokens], w1, w2, sizes)
+        with jax.named_scope(_COMBINE):
+            # the tail holds what no group wrote: masked, not multiplied
+            out = jnp.where(live[:, None], out.astype(jnp.float32)
+                            * by_pair[pairs][:, None], 0)
+            return total.at[tokens].add(out)
+    total = jax.lax.fori_loop(0, _n_waves(group_sizes, wave), body,
+                              jnp.zeros(latent.shape, jnp.float32))
+    return total.astype(latent.dtype)
+
+
+def _latent_chain_fwd(latent, weights, w1, w2, order, group_sizes):
+    return (_latent_chain(latent, weights, w1, w2, order, group_sizes),
+            (latent, weights, w1, w2, order, group_sizes))
+
+
+def _latent_chain_bwd(res, dy):
+    """The waves again: each recomputes its rows' forward, takes ``dy`` of
+    its rows' tokens, and adds to the gradients of ``latent`` (by token), of
+    the two weights (by group) and of the pairs' weights."""
+    latent, weights, w1, w2, order, group_sizes = res
+    k = weights.shape[1]
+    wave = min(_WAVE_ROWS, order.shape[0])
+    by_pair = weights.reshape(-1)
+
+    def body(i, sums):
+        d_latent, d_sorted, d_w1, d_w2 = sums
+        pairs, live, tokens, sizes = _wave(i, wave, order, group_sizes, k)
+        with jax.named_scope(_EXPERTS):
+            out, chain_vjp = jax.vjp(
+                lambda r, a, b: _relu2_chain(r, a, b, sizes),
+                latent[tokens], w1, w2)
+        with jax.named_scope(_COMBINE):
+            dy_rows = dy[tokens].astype(jnp.float32)
+            dots = jnp.where(live, (dy_rows * out.astype(jnp.float32)).sum(-1),
+                             0)
+            d_out = jnp.where(live[:, None], dy_rows * by_pair[pairs][:, None],
+                              0).astype(out.dtype)
+        with jax.named_scope(_EXPERTS):
+            d_rows, by_w1, by_w2 = chain_vjp(d_out)
+            d_rows = jnp.where(live[:, None], d_rows.astype(jnp.float32), 0)
+        return (d_latent.at[tokens].add(d_rows),
+                jax.lax.dynamic_update_slice_in_dim(d_sorted, dots, i * wave,
+                                                    0),
+                d_w1 + by_w1.astype(jnp.float32),
+                d_w2 + by_w2.astype(jnp.float32))
+    zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
+    d_latent, d_sorted, d_w1, d_w2 = jax.lax.fori_loop(
+        0, _n_waves(group_sizes, wave), body,
+        (zeros(latent), zeros(order), zeros(w1), zeros(w2)))
+    # back to the pairs' own order, once: a pair appears once in ``order``,
+    # and the rows no wave reached hold their zeros
+    d_pairs = zeros(order).at[order].set(d_sorted, unique_indices=True)
+    return (d_latent.astype(latent.dtype),
+            d_pairs[:weights.size].reshape(weights.shape).astype(weights.dtype),
+            d_w1.astype(w1.dtype), d_w2.astype(w2.dtype), None, None)
+
+
+_latent_chain.defvjp(_latent_chain_fwd, _latent_chain_bwd)
+
+
+def latent_moe_layer(x, latent, w_gate, bias, w1, w2, *, top_k: int,
+                     expert_offset: int = 0, norm_topk_prob: bool = True,
+                     routed_scaling_factor: float = 1.0):
+    """The held experts' part of a routed layer of non-gated ``relu ** 2``
+    experts that live in a latent space, between its two latent projections
+    (the caller's).
+
+    ``x``: ``[..., D]``, what the router reads; ``latent``: ``[..., L]``, what
+    the experts read, both in the compute dtype; ``w_gate``: ``[D, E]`` and
+    ``bias``: ``[E]``, float32; ``w1``: ``[G, L, F]`` and ``w2``: ``[G, F,
+    L]``, the experts ``expert_offset .. expert_offset + G`` of the ``E`` the
+    router knows.  Returns ``(y, counts, sel, (rows_held, rows_computed))``:
+    ``y`` of ``latent``'s shape and dtype, ``counts``: ``[E]`` int32 rows sent
+    to each of the ``E`` experts by these tokens, ``sel``: ``[N, top_k]`` the
+    selection, and the rows of the ``N top_k`` that are held here and that
+    the waves went over (the rows held, rounded up to a wave), both int32."""
+    lead, n_lat = latent.shape[:-1], latent.shape[-1]
+    if x.shape[:-1] != lead:
+        raise ValueError(f"latent_moe_layer: the router reads {x.shape} and "
+                         f"the experts {latent.shape}: not the same tokens")
+    x, latent = x.reshape(-1, x.shape[-1]), latent.reshape(-1, n_lat)
+    with jax.named_scope(_ROUTE):
+        sel, weights, counts, _, order, group_sizes = _route_and_sort(
+            x, w_gate, bias, w1.shape[0], top_k=top_k,
+            expert_offset=expert_offset, norm_topk_prob=norm_topk_prob,
+            scaling=routed_scaling_factor)
+        wave = min(_WAVE_ROWS, order.shape[0])
+        # whole waves: the pairs past the last are no token's (a gather
+        # clips them, a scatter-add adds their zeros) and never live
+        order = jnp.concatenate([order, order.shape[0] + jnp.arange(
+            -order.shape[0] % wave, dtype=order.dtype)])
+    y = _latent_chain(latent, weights, w1, w2, order, group_sizes)
+    n_rows = group_sizes.sum()
+    return (y.reshape(lead + (n_lat,)), counts, sel,
+            (n_rows, _n_waves(group_sizes, wave) * wave))

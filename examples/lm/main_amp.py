@@ -14,7 +14,11 @@ LFM2-MoE block (``apex_tpu.models.Lfm2Moe``: gated short convolutions beside
 GQA attention with rotary positions in the published period of four, a dense
 SwiGLU MLP in the first layer and routed experts after it, sigmoid top-4
 without drops; the selection bias and the experts' load counts ride along as
-model state).
+model state), and ``--model nemotron-h`` the Nemotron-H block
+(``apex_tpu.models.NemotronH``: layers that are each a Mamba-2 mixer, a GQA
+attention without positions or a latent expert layer alone, by the published
+string of letters; 16 ``relu ** 2`` experts in a latent space of hidden / 2,
+4 a token, beside one shared expert).
 
 The loop runs on :class:`apex_tpu.runtime.StepPipeline`:
 ``--steps-per-call K`` chains K steps into ONE compiled program
@@ -28,6 +32,7 @@ never blocks on a scalar.
     python main_amp.py --synthetic --steps 2 --sp 2 --attention ring
     python main_amp.py --synthetic --steps 5 --model granite-hybrid --layers 10
     python main_amp.py --synthetic --steps 5 --model lfm2-moe --layers 5
+    python main_amp.py --synthetic --steps 5 --model nemotron-h --layers 11
 """
 
 import os as _os
@@ -48,8 +53,8 @@ import numpy as np
 
 from apex_tpu import runtime, training
 from apex_tpu.contrib.xentropy import softmax_cross_entropy_loss
-from apex_tpu.models import (GPT, GraniteHybrid, Lfm2Moe, granite_hybrid,
-                             lfm2_moe)
+from apex_tpu.models import (GPT, GraniteHybrid, Lfm2Moe, NemotronH,
+                             granite_hybrid, lfm2_moe, nemotron_h)
 from apex_tpu.training import make_train_step
 
 
@@ -57,7 +62,8 @@ def parse():
     p = argparse.ArgumentParser()
     p.add_argument("--synthetic", action="store_true")
     p.add_argument("--model", type=str, default="gpt",
-                   choices=["gpt", "granite-hybrid", "lfm2-moe"],
+                   choices=["gpt", "granite-hybrid", "lfm2-moe",
+                            "nemotron-h"],
                    help="gpt: GPT-2 blocks; granite-hybrid: Mamba-2 and GQA "
                         "attention layers by the published period (five "
                         "mamba, attention, four mamba), heads of "
@@ -65,7 +71,10 @@ def parse():
                         "dense layer, then gated short convs and GQA+RoPE "
                         "attention by the published period (attention, three "
                         "convs), each followed by 8 routed experts of width "
-                        "hidden, 4 a token")
+                        "hidden, 4 a token; nemotron-h: the first --layers "
+                        "letters of the published pattern (MEMEMEM*EME...: a "
+                        "Mamba-2 mixer, a latent expert layer or an attention "
+                        "alone), 16 latent experts, 4 a token, one shared")
     p.add_argument("--steps", type=int, default=10)
     p.add_argument("-b", "--batch-size", type=int, default=8)
     p.add_argument("--seq-len", type=int, default=256)
@@ -211,8 +220,9 @@ def _train(args):
         raise SystemExit("--kv-heads needs --attention flash/blockwise/full "
                          "(GQA is shard-local; ring/ulysses paths are MHA)")
     hybrid, moe = args.model == "granite-hybrid", args.model == "lfm2-moe"
-    if (hybrid or moe) and (sp > 1 or args.window is not None
-                            or args.attention != "flash"):
+    latent = args.model == "nemotron-h"
+    if (hybrid or moe or latent) and (sp > 1 or args.window is not None
+                                      or args.attention != "flash"):
         raise SystemExit(f"--model {args.model} runs unsharded with "
                          f"--attention flash and no --window")
     if hybrid:
@@ -234,6 +244,17 @@ def _train(args):
             num_kv_heads=args.kv_heads or args.heads,
             mlp_dim=4 * args.hidden, moe_dim=args.hidden, num_experts=8,
             experts_held=8, dtype=jnp.bfloat16)
+    elif latent:
+        model = NemotronH(
+            vocab_size=args.vocab, hidden_size=args.hidden,
+            pattern=nemotron_h.PATTERN[:args.layers],
+            mamba_heads=2 * args.heads,
+            mamba_head_dim=args.hidden // args.heads, mamba_groups=2,
+            num_heads=args.heads, num_kv_heads=args.kv_heads or args.heads,
+            head_dim=args.hidden // args.heads,
+            latent_size=args.hidden // 2, moe_dim=args.hidden,
+            shared_dim=2 * args.hidden, num_experts=16, experts_held=16,
+            top_k=4, dtype=jnp.bfloat16)
     else:
         model = GPT(vocab_size=args.vocab, hidden_size=args.hidden,
                     num_layers=args.layers, num_heads=args.heads,
@@ -296,7 +317,9 @@ def _train(args):
     # O2 keeps float32, beside the norms: the hybrid mixer's A_log, dt_bias
     # and D; the expert layers' router
     keep_fp32 = (granite_hybrid.keep_fp32 if hybrid
-                 else lfm2_moe.keep_fp32 if moe else None)
+                 else lfm2_moe.keep_fp32 if moe
+                 else nemotron_h.keep_fp32 if latent else None)
+    moe = moe or latent         # both carry their expert layers' state
     init_fn, step_fn = make_train_step(
         loss_fn_with_state if moe else loss_fn,
         training.adam(args.lr, weight_decay=args.weight_decay),

@@ -225,6 +225,23 @@ def test_lm_example_lfm2_moe(monkeypatch, capsys):
             "--synthetic", "--model", "lfm2-moe", "--window", "8"])
 
 
+def test_lm_example_nemotron_h(monkeypatch, capsys):
+    """The same loop over the Nemotron-H block: eleven layers are five
+    Mamba-2 mixers, five latent expert layers and one attention, each alone
+    in its layer; the model's state (correction bias, load counts, rows
+    computed) rides through ``make_train_step``."""
+    _run_example(monkeypatch, "examples/lm/main_amp.py", [
+        "--synthetic", "--steps", "2", "-b", "2", "--seq-len", "33",
+        "--hidden", "32", "--layers", "11", "--heads", "2", "--kv-heads", "1",
+        "--vocab", "128", "--opt-level", "O2", "--loss-scale", "dynamic",
+        "--model", "nemotron-h"])
+    out = capsys.readouterr().out
+    assert "NemotronH 11L/32H" in out and "loss_scale 65536" in out
+    with pytest.raises(SystemExit, match="nemotron-h runs unsharded"):
+        _run_example(monkeypatch, "examples/lm/main_amp.py", [
+            "--synthetic", "--model", "nemotron-h", "--window", "8"])
+
+
 def test_lm_example_sequence_parallel(monkeypatch):
     """GPT over a 2-way sp mesh with ring attention."""
     _run_example(monkeypatch, "examples/lm/main_amp.py", [

@@ -108,3 +108,41 @@ def test_grouped_matmul_compiles_under_mosaic_at_the_cells_widths(
         ragged = jax.jit(lambda x, w, s: moe._grouped_matmul(x, w, s)).lower(
             sds((rows + 8, d)), sds((g, d, f)), sds((g,), jnp.int32)).compile()
     assert "ragged-dot" in ragged.as_text()
+
+
+def test_latent_layer_compiles_under_mosaic_and_no_array_has_every_pair(
+        one_chip, monkeypatch):
+    """``ops.latent_moe_layer`` at ``nemotron3_super_120b_o2.b2_seq8192``'s
+    sizes (16,384 tokens, 22 of 512 experts a token, 8 held, latent 1,024,
+    expert width 2,688), forward and backward: the waves' loops hold the
+    grouped-matmul kernels, and of the 360,448 (token, slot) pairs the
+    compiled program holds vectors only, no array of rows."""
+    import re
+
+    moe = importlib.import_module("apex_tpu.ops.moe")
+    monkeypatch.setattr(moe, "_use_pallas", lambda: True)
+    n, d, lat, f, e, g, k = 16384, 4096, 1024, 2688, 512, 8, 22
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(x, latent, w_gate, bias, w1, w2):
+        layer = lambda x, latent, w_gate, w1, w2: moe.latent_moe_layer(
+            x, latent, w_gate, bias, w1, w2, top_k=k,
+            routed_scaling_factor=5.0)[0]
+        out, vjp = jax.vjp(layer, x, latent, w_gate, w1, w2)
+        return out, vjp(out)
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(both).lower(
+            sds((n, d)), sds((n, lat)), sds((d, e), jnp.float32),
+            sds((e,), jnp.float32), sds((g, lat, f)),
+            sds((g, f, lat))).compile().as_text()
+    # forward: two products; backward: those again, their two input
+    # gradients and two weight gradients
+    assert text.count('custom_call_target="tpu_custom_call"') == 8
+    # the two loops of waves (the compiler adds loops of its own)
+    assert len(re.findall(r" while\(", text)) >= 2
+    assert re.search(r"\[%d\]" % (n * k), text)
+    assert not re.search(r"\[%d,\d" % (n * k), text)
+    assert f"[{moe._WAVE_ROWS},{f}]" in text

@@ -307,3 +307,175 @@ def test_walk_rows_alone(bound, n_rows, monkeypatch):
             np.testing.assert_array_equal(twice[written:], a[written:])
         else:
             assert bool(jnp.isnan(twice[written:]).all())
+
+
+# ---------------------------------------------------------------------------
+# ``ops.latent_moe_layer``: the router reads one array and the experts
+# another, an expert is two products around ``relu(.) ** 2``, and the sorted
+# rows run in waves whose number follows the load.
+
+LAT = 10        # the latent width: the experts' input is not the router's
+
+
+def latent_loop(x, latent, w_gate, bias, w1, w2, offset, scaling=1.0):
+    """Every held expert applied to every token's latent row, times that
+    token's weight for it: 0 where the expert was not selected."""
+    scores = jax.nn.sigmoid(x @ w_gate)
+    _, sel = jax.lax.top_k(scores + bias, K)
+    picked = jnp.take_along_axis(scores, sel, -1)
+    picked = picked / (picked.sum(-1, keepdims=True) + 1e-6) * scaling
+    out = jnp.zeros_like(latent)
+    for i in range(w1.shape[0]):
+        mine = (sel == offset + i).astype(x.dtype)
+        y = jnp.square(jax.nn.relu(latent @ w1[i])) @ w2[i]
+        out = out + (picked * mine).sum(-1, keepdims=True) * y
+    return out, sel
+
+
+def _latent_weights(held=E, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return dict(x=jax.random.normal(k[0], (N, D)),
+                latent=jax.random.normal(k[1], (N, LAT)),
+                w_gate=jax.random.normal(k[2], (D, E)) * 0.5,
+                w1=jax.random.normal(k[3], (held, LAT, F)) * 0.3,
+                w2=jax.random.normal(k[4], (held, F, LAT)) * 0.3,
+                cot=jax.random.normal(k[5], (N, LAT)))
+
+
+def _latent_both(p, bias, offset, scaling=1.0):
+    names = ("x", "latent", "w_gate", "w1", "w2")
+    args = [p[n] for n in names]
+    ours = lambda *a: ops.latent_moe_layer(
+        a[0], a[1], a[2], bias, *a[3:], top_k=K, expert_offset=offset,
+        routed_scaling_factor=scaling)
+    loop = lambda *a: latent_loop(a[0], a[1], a[2], bias, *a[3:], offset,
+                                  scaling)
+    out = {}
+    for name, f in (("ours", ours), ("loop", loop)):
+        y, *rest = f(*args)
+        grads = jax.grad(lambda *a: jnp.sum(f(*a)[0] * p["cot"]),
+                         argnums=tuple(range(5)))(*args)
+        out[name] = (y, dict(zip(names, grads)), rest)
+    return out
+
+
+#: rows a wave: more than the layer ever holds (one wave, the pairs padded
+#: to it), the 192 pairs exactly, and 2, 4 and 12 waves of them
+WAVES = [256, 192, 96, 48, 16]
+
+
+@pytest.fixture(params=WAVES)
+def wave(request, monkeypatch):
+    monkeypatch.setattr(moe, "_WAVE_ROWS", request.param)
+    return request.param
+
+
+@pytest.mark.parametrize("held,offset", [(8, 0), (2, 0), (2, 4), (3, 5)])
+def test_latent_layer_equals_the_dense_loop(held, offset, wave):
+    """Forward and every gradient, the router's through the weights among
+    them, at every number of waves; with all 8 experts held every pair is
+    held here (192 rows: one to twelve waves) and the answer is the dense
+    one."""
+    result = _latent_both(_latent_weights(held), jnp.zeros((E,)), offset, 2.5)
+    (y, _, (counts, sel, (rows_held, computed))) = result["ours"]
+    sel, counts = _assert_equal(
+        {"ours": result["ours"][:2] + ((counts, sel, None),),
+         "loop": result["loop"]})
+    held_rows = int(counts[offset:offset + held].sum())
+    assert int(rows_held) == held_rows
+    assert int(computed) == -(-held_rows // min(wave, N * K)) * min(wave, N * K)
+    if held == E:
+        assert held_rows == N * K
+    assert y.shape == (N, LAT)
+
+
+def test_latent_router_reads_x_and_the_experts_read_latent(wave):
+    """Changing the experts' input leaves selection and counts alone;
+    changing the router's input changes them and leaves an expert's own
+    product alone."""
+    p = _latent_weights(held=4)
+    f = lambda x, latent: ops.latent_moe_layer(
+        x, latent, p["w_gate"], jnp.zeros((E,)), p["w1"], p["w2"], top_k=K)
+    y, counts, sel, _ = f(p["x"], p["latent"])
+    y2, counts2, sel2, _ = f(p["x"], 2 * p["latent"])
+    np.testing.assert_array_equal(sel, sel2)
+    np.testing.assert_array_equal(counts, counts2)
+    # relu(2 l W1)^2 W2 = 4 relu(l W1)^2 W2
+    np.testing.assert_allclose(y2, 4 * y, rtol=1e-5, atol=1e-6)
+    _, counts3, sel3, _ = f(p["x"][::-1], p["latent"])
+    np.testing.assert_array_equal(sel3, np.asarray(sel)[::-1])
+    with pytest.raises(ValueError, match="not the same tokens"):
+        f(p["x"][:5], p["latent"])
+
+
+@pytest.mark.parametrize("load", [0, 1, 15, 16, 17, 48])
+def test_latent_loads_under_at_and_over_one_wave(load, monkeypatch):
+    """``load`` tokens select the one held expert (a wave is 16 rows: none,
+    under, at and over one wave, and three whole waves): the rows held, the
+    rows the waves went over, and the dense answer with its gradients."""
+    monkeypatch.setattr(moe, "_WAVE_ROWS", 16)
+    p = _latent_weights(held=1)
+    # expert 5's score is 1 for the first ``load`` tokens and 0 elsewhere
+    p["w_gate"] = p["w_gate"].at[:, 5].set(0.0).at[0, 5].set(1.0)
+    p["x"] = p["x"].at[:, 0].set(jnp.where(jnp.arange(N) < load, 40.0, -40.0))
+    result = _latent_both(p, jnp.zeros((E,)), offset=5)
+    y, _, (counts, sel, (rows_held, computed)) = result["ours"]
+    _assert_equal({"ours": result["ours"][:2] + ((counts, sel, None),),
+                   "loop": result["loop"]})
+    assert int(counts[5]) == load == int(rows_held)
+    assert int(computed) == -(-load // 16) * 16
+    assert bool((jnp.abs(y[load:]).sum(-1) == 0).all())
+    if load:
+        assert bool((jnp.abs(y[:load]).sum(-1) > 0).all())
+
+
+def test_latent_shares_add_up_and_bf16_runs(wave):
+    """The four shares of two experts add up to the uncut layer; in bf16
+    with a float32 router the layer keeps the latent dtype."""
+    p = _latent_weights()
+    bias = jnp.zeros((E,))
+    whole = ops.latent_moe_layer(p["x"], p["latent"], p["w_gate"], bias,
+                                 p["w1"], p["w2"], top_k=K)[0]
+    parts = sum(ops.latent_moe_layer(
+        p["x"], p["latent"], p["w_gate"], bias, p["w1"][i:i + 2],
+        p["w2"][i:i + 2], top_k=K, expert_offset=i)[0] for i in range(0, E, 2))
+    np.testing.assert_allclose(parts, whole, atol=2e-5)
+    half = lambda a: a.astype(jnp.bfloat16)
+    y = ops.latent_moe_layer(half(p["x"]), half(p["latent"]), p["w_gate"],
+                             bias, half(p["w1"]), half(p["w2"]), top_k=K)[0]
+    assert y.dtype == jnp.bfloat16
+    np.testing.assert_allclose(y.astype(jnp.float32), whole, atol=0.1,
+                               rtol=0.1)
+    with pytest.raises(TypeError, match="float32"):
+        ops.latent_moe_layer(p["x"], p["latent"], half(p["w_gate"]), bias,
+                             p["w1"], p["w2"], top_k=K)
+    with pytest.raises(ValueError, match="not among the router's"):
+        ops.latent_moe_layer(p["x"], p["latent"], p["w_gate"], bias,
+                             p["w1"][:2], p["w2"][:2], top_k=K,
+                             expert_offset=7)
+
+
+@pytest.mark.parametrize("experts,top_k", [(64, 4), (256, 8)])
+def test_route_reads_the_selected_scores_by_mask(experts, top_k):
+    """``route`` reads the selected scores through a mask: the weights and
+    the gradient to the router that a gather of them gives, bit for bit one
+    way, and no gather in the lowered form."""
+    k = jax.random.split(jax.random.PRNGKey(7), 3)
+    x = jax.random.normal(k[0], (40, D))
+    w_gate = jax.random.normal(k[1], (D, experts)) * 0.5
+    cot = jax.random.normal(k[2], (40, top_k))
+    route = lambda w: moe.route(x, w, jnp.zeros((experts,)), top_k=top_k,
+                                scaling=5.0)
+
+    def gathered(w):
+        scores = jax.nn.sigmoid(jnp.dot(x, w, precision="highest"))
+        picked = jnp.take_along_axis(scores, route(w)[0], axis=-1)
+        return picked / (picked.sum(-1, keepdims=True) + 1e-6) * 5.0
+
+    np.testing.assert_array_equal(route(w_gate)[1], gathered(w_gate))
+    grad = lambda f: jax.grad(lambda w: jnp.sum(f(w) * cot))(w_gate)
+    np.testing.assert_allclose(grad(lambda w: route(w)[1]), grad(gathered),
+                               rtol=1e-6, atol=1e-7)
+    assert "gather" not in jax.jit(lambda w: route(w)[1]).lower(
+        w_gate).as_text()
+    assert "gather" in jax.jit(gathered).lower(w_gate).as_text()
