@@ -34,8 +34,12 @@ this module never updates (zeros, as the published code initialises it; its
 balancing rule is a training recipe), and ``load`` ``[num_experts]`` int32,
 the rows each expert was sent in the last step.
 
-Every layer is a ``jax.checkpoint`` that saves its input only.  bf16 matmuls
-with float32 norms, router scores, rotations and loss.
+Every layer is a ``jax.checkpoint`` that saves its input and, of an expert
+layer, what its backward reads of the route (``ops.moe.ROUTED``: the
+selection, the selected scores, the weights, the pairs' order and its
+inverse and the rows of each expert held, 1.4 MB at 16,384 tokens), so that
+it routes once a step and not twice.  bf16 matmuls with float32 norms, router
+scores, rotations and loss.
 
 Named scopes (metadata, like ``training.PHASE_SCOPES``): ``apex.moe`` around
 the expert layer with ``apex.moe.route``, ``apex.moe.experts`` and
@@ -55,7 +59,7 @@ import jax.numpy as jnp
 from ..amp.policy import default_norm_predicate
 from ..normalization import RMSNorm
 from ..ops.flash_attention import flash_attention
-from ..ops.moe import MOE_SCOPES, moe_layer
+from ..ops.moe import MOE_SCOPES, ROUTED, moe_layer
 from ..ops.rope import qk_norm_rope
 from ..ops.short_conv import gated_short_conv
 
@@ -254,7 +258,10 @@ class Lfm2Moe(nn.Module):
             top_k=self.top_k, norm_topk_prob=self.norm_topk_prob,
             routed_scaling_factor=self.routed_scaling_factor)
         h = wte[input_ids].astype(self.dtype)
-        layer = nn.remat(Lfm2Layer)         # saves the layer's input only
+        # saves the layer's input and what an expert layer's backward reads
+        # of the route
+        layer = nn.remat(Lfm2Layer, policy=(
+            jax.checkpoint_policies.save_only_these_names(ROUTED)))
         for i, kind in enumerate(self.layer_types):
             routed = i >= self.num_dense_layers
             h = layer(kind, operators.get(kind), routed,

@@ -49,6 +49,21 @@ gradients by group, the kernels' own backward rules) and the weights ``w``
 into the router; none through the selection, and none to the bias, which is
 the caller's state.
 
+**Routing once a step.**  A caller that wraps the layer in a checkpoint saves
+its input only, and its backward then routes a second time: scores, top-k and
+sort, none of which carries a gradient.  Everything the backward reads of the
+route carries the name ``ROUTED`` (``jax.ad_checkpoint.checkpoint_name``), so
+a checkpoint whose policy is ``save_only_these_names(ROUTED)`` keeps it and
+recomputes the layer from after the route: the selection and the selected
+scores, which is all ``route``'s own backward rule reads of the ``[N, E]``
+score matrix (autodiff would want all of it), the weights with the pairs
+held elsewhere zeroed, which pairs are held, the sort's permutation (and its
+inverse in ``moe_layer``) and the rows of each expert held.  That is ``N k``
+values six or seven times over and ``G`` counts: 6.1 MB a layer at 16,384
+tokens and 22 a token, 1.4 MB at 4 a token, against the 32 MB of one
+``[16384, 512]`` score matrix.  Under any other checkpoint, or none, the
+names do nothing.
+
 Named scopes (metadata, like ``training.PHASE_SCOPES``): ``apex.moe.route``
 (scores, top-k, weights, sort, counts), ``apex.moe.experts`` (gather, grouped
 products, activation), ``apex.moe.combine``; the caller puts ``apex.moe``
@@ -71,12 +86,15 @@ inputs and runs the waves again backward, each recomputing its own forward.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..normalization.fused_layer_norm import _use_pallas
 
-__all__ = ["MOE_SCOPES", "LATENT_SCOPES", "route", "moe_layer",
+__all__ = ["MOE_SCOPES", "LATENT_SCOPES", "ROUTED", "route", "moe_layer",
            "latent_moe_layer"]
 
 #: rows a tile of the grouped-matmul kernel: a group's edge inside a tile
@@ -96,10 +114,72 @@ _ROUTE, _EXPERTS, _COMBINE = MOE_SCOPES[1:]
 #: into and out of the latent space, and the shared expert
 LATENT_SCOPES = ("apex.moe.latent", "apex.moe.shared")
 
+#: the name (``jax.ad_checkpoint.checkpoint_name``) of what a layer's backward
+#: reads of the route: a checkpoint around the layer whose policy saves it
+#: (``save_only_these_names(ROUTED)``) recomputes no score, top-k or sort
+ROUTED = "apex.moe.routed"
+_kept = functools.partial(checkpoint_name, name=ROUTED)
+
 #: rows a wave of the latent layer's expert chain, a multiple of ``_ROW_TILE``:
 #: two to three times the rows a layer holds where it holds 8 of 512 experts
 #: for 16,384 tokens at 22 a token, so that an uneven router stays one wave
 _WAVE_ROWS = 64 * _ROW_TILE
+
+
+def _logits(x, w_gate):
+    return jnp.dot(x.astype(jnp.float32), w_gate,
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+def _normalised(picked, norm_topk_prob, scaling):
+    if norm_topk_prob:
+        picked = picked / (picked.sum(-1, keepdims=True) + 1e-6)
+    return picked * scaling
+
+
+def _chosen(sel, experts):
+    """``sel == expert`` as ``[N, k, E]``."""
+    return sel[..., None] == jnp.arange(experts, dtype=sel.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _route(x, w_gate, bias, top_k, norm_topk_prob, scaling):
+    return _route_fwd(x, w_gate, bias, top_k, norm_topk_prob, scaling)[0]
+
+
+def _route_fwd(x, w_gate, bias, top_k, norm_topk_prob, scaling):
+    scores = jax.nn.sigmoid(_logits(x, w_gate))
+    _, sel = jax.lax.top_k(scores + bias, top_k)
+    mask = _chosen(sel, w_gate.shape[1])
+    # scores[sel] through a mask: the same numbers (the other terms of a sum
+    # are zeros) as one pass of compares, and its transpose another, where a
+    # gather's is a scatter-add of N k scalars into [N, E] (on the v5e 4 ns
+    # an element and twice that: 1.55 and 3.1 ms at 16,384 tokens, 22 of 512;
+    # ``PERF.md``, PR 33)
+    picked = jnp.where(mask, scores[:, None, :], 0).sum(-1)
+    # what the backward reads of the scores: k a token, not E
+    sel, picked = _kept(sel), _kept(picked)
+    return ((sel, _normalised(picked, norm_topk_prob, scaling),
+             mask.sum((0, 1), dtype=jnp.int32)), (x, w_gate, sel, picked))
+
+
+def _route_bwd(top_k, norm_topk_prob, scaling, res, cotangents):
+    """Autodiff's gradient to the bit, from the selected scores alone: a
+    token's ``k`` experts are distinct, so every sum the mask makes, there
+    and here, has one term that is not zero."""
+    x, w_gate, sel, picked = res
+    d_picked, = jax.vjp(lambda p: _normalised(p, norm_topk_prob, scaling),
+                        picked)[1](cotangents[1])
+    d_picked = d_picked * (picked * (1 - picked))       # the sigmoid's
+    d_logits = jnp.where(_chosen(sel, w_gate.shape[1]), d_picked[..., None],
+                         0).sum(1)
+    d_x, = jax.linear_transpose(lambda a: _logits(a, w_gate), x)(d_logits)
+    d_w, = jax.linear_transpose(lambda w: _logits(x, w), w_gate)(d_logits)
+    return d_x, d_w, None
+
+
+_route.defvjp(_route_fwd, _route_bwd)
+
 
 def route(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool = True,
           scaling: float = 1.0):
@@ -112,28 +192,16 @@ def route(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool = True,
     ``weights``: ``[N, top_k]`` float32, the unbiased scores of the selected
     experts, normalised over the ``top_k`` when ``norm_topk_prob``, times
     ``scaling``; ``counts``: ``[E]`` int32, the rows each expert was sent.
-    The selection carries no gradient."""
+    The selection carries no gradient, and the weights' gradient is a rule
+    of its own that keeps ``sel`` and the ``top_k`` selected scores a token
+    (under ``ROUTED``) where autodiff would keep all ``E``."""
     if w_gate.dtype != jnp.float32 or bias.dtype != jnp.float32:
         raise TypeError(
             f"moe.route: the router's weight arrived as {w_gate.dtype} and "
             f"the selection bias as {bias.dtype}; the scores are float32 "
             f"whatever the compute dtype (keep the router out of the amp "
             f"cast: models.lfm2_moe.keep_fp32)")
-    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), w_gate,
-                                    precision=jax.lax.Precision.HIGHEST))
-    _, sel = jax.lax.top_k(jax.lax.stop_gradient(scores) + bias, top_k)
-    experts = jnp.arange(w_gate.shape[1], dtype=sel.dtype)
-    # scores[sel] through a mask: the same numbers (the other terms of a sum
-    # are zeros) as one pass of compares, and its transpose another, where a
-    # gather's is a scatter-add of N k scalars into [N, E] (on the v5e 4 ns
-    # an element and twice that: 1.55 and 3.1 ms at 16,384 tokens, 22 of 512;
-    # ``PERF.md``, PR 33)
-    weights = jnp.where(sel[..., None] == experts, scores[:, None, :],
-                        0).sum(-1)
-    if norm_topk_prob:
-        weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
-    counts = (sel[..., None] == experts).sum((0, 1), dtype=jnp.int32)
-    return sel, weights * scaling, counts
+    return _route(x, w_gate, bias, top_k, norm_topk_prob, scaling)
 
 
 def _grouped_matmul(rows, weights, group_sizes):
@@ -337,13 +405,14 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _route_and_sort(x, w_gate, bias, g, *, top_k, expert_offset,
-                    norm_topk_prob, scaling):
+                    norm_topk_prob, scaling, whole=1):
     """:func:`route`, then the (token, slot) pairs by expert held: ``(sel,
     weights, counts, held, order, group_sizes)`` with ``weights`` zero where
-    a pair's expert is elsewhere, ``order``: ``[N top_k]`` the pairs by expert
-    held, in token order within an expert, the pairs whose expert is
-    elsewhere last, and ``group_sizes``: ``[g]`` the rows of each expert
-    held."""
+    a pair's expert is elsewhere, ``order`` the pairs by expert held, in
+    token order within an expert, the pairs whose expert is elsewhere last,
+    then pairs that are no token's up to a multiple of ``whole``, and
+    ``group_sizes``: ``[g]`` the rows of each expert held.  The last four
+    are what a layer's backward reads of all this, and carry ``ROUTED``."""
     e = w_gate.shape[1]
     if not 0 <= expert_offset <= e - g:
         raise ValueError(f"moe_layer: experts {expert_offset} .. "
@@ -352,11 +421,14 @@ def _route_and_sort(x, w_gate, bias, g, *, top_k, expert_offset,
                                  norm_topk_prob=norm_topk_prob,
                                  scaling=scaling)
     local = sel - expert_offset
-    held = (local >= 0) & (local < g)
+    held = _kept((local >= 0) & (local < g))
     order = jnp.argsort(jnp.where(held, local, g).reshape(-1), stable=True
                         ).astype(jnp.int32)
-    return (sel, jnp.where(held, weights, 0), counts, held, order,
-            jax.lax.dynamic_slice_in_dim(counts, expert_offset, g))
+    order = jnp.concatenate([order, order.shape[0] + jnp.arange(
+        -order.shape[0] % whole, dtype=order.dtype)])
+    weights = jax.lax.select(held, weights, jnp.zeros_like(weights))
+    return (sel, _kept(weights), counts, held, _kept(order),
+            _kept(jax.lax.dynamic_slice_in_dim(counts, expert_offset, g)))
 
 
 def moe_layer(x, w_gate, bias, w1, w3, w2, *, top_k: int,
@@ -381,9 +453,9 @@ def moe_layer(x, w_gate, bias, w1, w3, w2, *, top_k: int,
         sel, weights, counts, held, order, group_sizes = _route_and_sort(
             x, w_gate, bias, g, top_k=top_k, expert_offset=expert_offset,
             norm_topk_prob=norm_topk_prob, scaling=routed_scaling_factor)
-        pos = jnp.zeros_like(order).at[order].set(
+        pos = _kept(jnp.zeros_like(order).at[order].set(
             jnp.arange(n * top_k, dtype=jnp.int32), unique_indices=True
-        ).reshape(n, top_k)
+        ).reshape(n, top_k))
         n_rows = group_sizes.sum()
     with jax.named_scope(_EXPERTS):
         rows = _sorted_rows(x, order, pos, held, n_rows)
@@ -515,16 +587,14 @@ def latent_moe_layer(x, latent, w_gate, bias, w1, w2, *, top_k: int,
         raise ValueError(f"latent_moe_layer: the router reads {x.shape} and "
                          f"the experts {latent.shape}: not the same tokens")
     x, latent = x.reshape(-1, x.shape[-1]), latent.reshape(-1, n_lat)
+    wave = min(_WAVE_ROWS, x.shape[0] * top_k)
     with jax.named_scope(_ROUTE):
+        # whole waves: the pairs past the last are no token's (a gather
+        # clips them, a scatter-add adds their zeros) and never live
         sel, weights, counts, _, order, group_sizes = _route_and_sort(
             x, w_gate, bias, w1.shape[0], top_k=top_k,
             expert_offset=expert_offset, norm_topk_prob=norm_topk_prob,
-            scaling=routed_scaling_factor)
-        wave = min(_WAVE_ROWS, order.shape[0])
-        # whole waves: the pairs past the last are no token's (a gather
-        # clips them, a scatter-add adds their zeros) and never live
-        order = jnp.concatenate([order, order.shape[0] + jnp.arange(
-            -order.shape[0] % wave, dtype=order.dtype)])
+            scaling=routed_scaling_factor, whole=wave)
     y = _latent_chain(latent, weights, w1, w2, order, group_sizes)
     n_rows = group_sizes.sum()
     return (y.reshape(lead + (n_lat,)), counts, sel,

@@ -110,13 +110,17 @@ def test_grouped_matmul_compiles_under_mosaic_at_the_cells_widths(
     assert "ragged-dot" in ragged.as_text()
 
 
+@pytest.mark.parametrize("checkpoint", ["none", "keeps_routed", "input_only"])
 def test_latent_layer_compiles_under_mosaic_and_no_array_has_every_pair(
-        one_chip, monkeypatch):
+        one_chip, monkeypatch, checkpoint):
     """``ops.latent_moe_layer`` at ``nemotron3_super_120b_o2.b2_seq8192``'s
     sizes (16,384 tokens, 22 of 512 experts a token, 8 held, latent 1,024,
     expert width 2,688), forward and backward: the waves' loops hold the
     grouped-matmul kernels, and of the 360,448 (token, slot) pairs the
-    compiled program holds vectors only, no array of rows."""
+    compiled program holds vectors only, no array of rows.  Under a
+    checkpoint that keeps ``ROUTED``, as the model's layers are, the program
+    sorts the ``[16384, 512]`` scores (top-22) and the pairs once, as it
+    does without a checkpoint; under one that keeps the input only, twice."""
     import re
 
     moe = importlib.import_module("apex_tpu.ops.moe")
@@ -130,6 +134,10 @@ def test_latent_layer_compiles_under_mosaic_and_no_array_has_every_pair(
         layer = lambda x, latent, w_gate, w1, w2: moe.latent_moe_layer(
             x, latent, w_gate, bias, w1, w2, top_k=k,
             routed_scaling_factor=5.0)[0]
+        if checkpoint != "none":
+            layer = jax.checkpoint(layer, policy=(
+                jax.checkpoint_policies.save_only_these_names(moe.ROUTED)
+                if checkpoint == "keeps_routed" else None))
         out, vjp = jax.vjp(layer, x, latent, w_gate, w1, w2)
         return out, vjp(out)
 
@@ -146,3 +154,9 @@ def test_latent_layer_compiles_under_mosaic_and_no_array_has_every_pair(
     assert re.search(r"\[%d\]" % (n * k), text)
     assert not re.search(r"\[%d,\d" % (n * k), text)
     assert f"[{moe._WAVE_ROWS},{f}]" in text
+    sorts = [m.group(1) for m in (
+        re.search(r"= \((\w+\[[\d,]+\])", line)
+        for line in text.splitlines() if " sort(" in line) if m]
+    routes = 2 if checkpoint == "input_only" else 1
+    assert sorts.count(f"f32[{n},{e}]") == routes       # top-22 of 512
+    assert sorts.count(f"s32[{n * k}]") == routes       # the pairs by expert

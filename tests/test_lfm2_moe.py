@@ -17,6 +17,7 @@ from apex_tpu.amp import policy
 from apex_tpu.contrib.xentropy import softmax_cross_entropy_loss
 from apex_tpu.models import lfm2_moe
 from apex_tpu.ops import moe
+from test_moe import plain_route, routing_ops
 
 BATCH, SEQ = 2, 33
 
@@ -208,13 +209,16 @@ def test_the_new_scopes_reach_the_compiled_step(o2_step):
     under = "/jvp(apex.forward)/Lfm2Moe/"
     for scope in moe.MOE_SCOPES[1:]:
         assert f"{under}layer_1/experts/apex.moe/{scope}/" in compiled, scope
-        # the weighted sum back is the layer's last operation: its backward
-        # rule runs, its recomputed forward is needed by nothing
-        assert any("transpose(jvp(apex.forward))" in line
-                   and f"/apex.moe/{scope}/" in line
-                   and ("rematted_computation" in line
-                        or scope == "apex.moe.combine")
-                   for line in compiled.splitlines()), scope
+        # backward, the experts' recomputed forward runs beside their rules;
+        # the route's results are kept by the layer's checkpoint, so its
+        # rule runs and nothing of it is recomputed; the weighted sum back
+        # is the layer's last operation, whose forward nothing needs again
+        backward = [line for line in compiled.splitlines()
+                    if "transpose(jvp(apex.forward))" in line
+                    and f"/apex.moe/{scope}/" in line]
+        assert any("rematted_computation" not in line for line in backward)
+        assert any("rematted_computation" in line for line in backward) == (
+            scope == "apex.moe.experts"), scope
     assert f"{under}layer_0/conv/apex.sconv/" in compiled
     assert f"{under}layer_1/attention/apex.rope/" in compiled
     for scope in ("apex.sconv", "apex.rope"):
@@ -280,3 +284,88 @@ def test_rows_walked_is_an_intermediate_and_the_state_keeps_its_keys(
     # does not ask
     _, quiet = model.apply(variables, ids, mutable=["moe"])
     assert set(quiet) == {"moe"}
+
+
+def _share(dtype=jnp.float32):
+    """The tiny model holding 4 of its 8 experts, its variables and ids."""
+    model = models.lfm2_moe_tiny(experts_held=4, expert_offset=2, dtype=dtype)
+    return model, _init(model), _ids()
+
+
+def _loss_of_a_share(dtype=jnp.float32):
+    """``(loss, new state)`` as a function of the parameters."""
+    model, variables, ids = _share(dtype)
+
+    def loss(p):
+        logits, new = model.apply({"params": p, "moe": variables["moe"]},
+                                  ids[:, :-1], mutable=["moe"])
+        return -jnp.take_along_axis(jax.nn.log_softmax(logits),
+                                    ids[:, 1:, None], -1).mean(), new["moe"]
+    return loss, variables["params"]
+
+
+@pytest.mark.parametrize("route", ["rule", "plain"])
+def test_a_differentiated_step_routes_once_a_layer(route, monkeypatch):
+    """In the gradient of the loss every one of the four expert layers
+    selects once, sorts once and makes its scores once, with the router's
+    two gradients behind them; with a plain function for ``route`` the
+    recomputed forward makes the scores and selects again, as every step did
+    while the checkpoints kept the layer's input only."""
+    if route == "plain":
+        monkeypatch.setattr(moe, "route", plain_route)
+    loss, params = _loss_of_a_share()
+    again = 2 if route == "plain" else 1
+    assert routing_ops(jax.grad(lambda p: loss(p)[0]), params,
+                       tokens=BATCH * SEQ, experts=8) == {
+        "top_k": 4 * again, "sort": 4, "scores": 4 * again,
+        "score_gradients": 8}
+
+
+def test_the_checkpoints_keep_inputs_and_the_route():
+    """What the five checkpoints save: every layer's input and, of an expert
+    layer under ``ROUTED``, seven arrays of the (token, slot) pairs' size at
+    most: no score matrix, no array with the router's 8 experts as an
+    axis."""
+    from jax._src.ad_checkpoint import saved_residuals
+    loss, params = _loss_of_a_share()
+    kept = [(aval, why) for aval, why in saved_residuals(
+        lambda p: loss(p)[0], params) if "argument" not in why]
+    shapes = [aval.shape for aval, _ in kept]
+    assert not any(8 in shape for shape in shapes), shapes
+    pairs, of_pairs = (BATCH * SEQ, 4), ((BATCH * SEQ, 4),
+                                         (BATCH * SEQ * 4,), (4,))
+    routed = [(aval, why) for aval, why in kept if aval.shape in of_pairs]
+    assert all("apex_tpu/ops/moe.py" in why for _, why in routed)
+    # sel, the selected scores, the weights, held and pos; order; group_sizes
+    assert [sum(aval.shape == shape for aval, _ in routed)
+            for shape in of_pairs] == [20, 4, 4]
+    assert any(moe.ROUTED in why for _, why in routed)
+    rest = [shape for shape in shapes if shape not in of_pairs]
+    # [batch, tokens, ..] of the layers and of the loss, the last norm's scale
+    assert all(len(shape) >= 3 for shape in rest), rest
+    assert rest.count((BATCH, SEQ, 64)) >= 5            # the layers' inputs
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_loss_gradients_and_state_are_the_plain_routes_bit_for_bit(
+        dtype, monkeypatch):
+    """``route``'s own rule under checkpoints that keep its results, and the
+    plain function under autodiff, which routes twice, as every step did
+    before: loss, every gradient and the model's state are the same bits.
+    Op by op: inside one compiled program XLA's fusions choose the last
+    digits, and two programs of one formula differ there."""
+    loss, params = _loss_of_a_share(dtype)
+
+    def op_by_op():
+        with jax.disable_jit():
+            return jax.value_and_grad(loss, has_aux=True)(params)
+    ours = op_by_op()
+    monkeypatch.setattr(moe, "route", plain_route)
+    for got, want in zip(jax.tree_util.tree_leaves(ours),
+                         jax.tree_util.tree_leaves(op_by_op()), strict=True):
+        np.testing.assert_array_equal(got, want)
+    (_, state), grads = ours
+    assert all(int(s["experts"]["load"][2:6].sum()) for s in state.values())
+    assert all(float(jnp.abs(grads[name]["experts"]["router"]).max()) > 0
+               for name in state)

@@ -9,6 +9,8 @@ rows is within one block) and walked, with a block of 16 rows.  Then the
 walked path against the straight one bit for bit, at six loads, with the
 blocks it does not reach poisoned, and the rows it says it walked."""
 
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -479,3 +481,159 @@ def test_route_reads_the_selected_scores_by_mask(experts, top_k):
     assert "gather" not in jax.jit(lambda w: route(w)[1]).lower(
         w_gate).as_text()
     assert "gather" in jax.jit(gathered).lower(w_gate).as_text()
+
+
+# ---------------------------------------------------------------------------
+# ``route``'s own backward rule, and what a checkpoint around a layer keeps
+# of the route under ``moe.ROUTED``.
+
+def routing_ops(f, *args, tokens, experts):
+    """How often the program ``f(*args)`` selects (``top_k``), sorts, and
+    multiplies at ``Precision.HIGHEST`` into (``scores``) or out of
+    (``score_gradients``) a ``[tokens, experts]`` array, over its jaxpr and
+    every jaxpr inside it: two of the last are a router's backward."""
+    from jax._src import core
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for inner in core.jaxprs_in_params(eqn.params):
+                yield from walk(inner)
+    seen = collections.Counter()
+    highest = (jax.lax.Precision.HIGHEST,) * 2
+    for eqn in walk(jax.make_jaxpr(f)(*args).jaxpr):
+        if eqn.primitive.name in ("top_k", "sort"):
+            seen[eqn.primitive.name] += 1
+        elif (eqn.primitive.name == "dot_general"
+              and eqn.params["precision"] == highest):
+            if eqn.outvars[0].aval.shape == (tokens, experts):
+                seen["scores"] += 1
+            elif (tokens, experts) in [v.aval.shape for v in eqn.invars]:
+                seen["score_gradients"] += 1
+    return dict(seen)
+
+
+def plain_route(x, w_gate, bias, *, top_k, norm_topk_prob=True, scaling=1.0):
+    """The formula under autodiff, which keeps the ``[N, E]`` scores: the
+    oracle of ``route``'s rule, and what the benchmark's controls put in
+    ``moe.route``'s place."""
+    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), w_gate,
+                                    precision=jax.lax.Precision.HIGHEST))
+    _, sel = jax.lax.top_k(jax.lax.stop_gradient(scores) + bias, top_k)
+    experts = jnp.arange(w_gate.shape[1], dtype=sel.dtype)
+    weights = jnp.where(sel[..., None] == experts, scores[:, None, :],
+                        0).sum(-1)
+    if norm_topk_prob:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
+    counts = (sel[..., None] == experts).sum((0, 1), dtype=jnp.int32)
+    return sel, weights * scaling, counts
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("norm_topk_prob", [True, False],
+                         ids=["normalised", "unnormalised"])
+@pytest.mark.parametrize("top_k,experts", [(4, 64), (22, 512), (1, 8)])
+def test_the_route_rule_equals_autodiff_bit_for_bit(top_k, experts,
+                                                    norm_topk_prob, dtype):
+    """Selection, weights, counts and the gradients to ``x`` and ``w_gate``
+    of the rule that keeps ``[N, k]`` selected scores are those of autodiff
+    through the ``[N, E]`` score matrix, to the last bit, op by op and as one
+    program."""
+    k = jax.random.split(jax.random.PRNGKey(top_k), 4)
+    x = jax.random.normal(k[0], (96, 2 * D)).astype(dtype)
+    w_gate = jax.random.normal(k[1], (2 * D, experts)) * 0.5
+    bias = 0.3 * jax.random.normal(k[2], (experts,))
+    cot = jax.random.normal(k[3], (96, top_k))
+
+    def run(route, jit):
+        f = lambda x, w: route(x, w, bias, top_k=top_k, scaling=5.0,
+                               norm_topk_prob=norm_topk_prob)
+        both = lambda x, w: (f(x, w), jax.grad(
+            lambda *a: jnp.sum(f(*a)[1] * cot), (0, 1))(x, w))
+        return (jax.jit(both) if jit else both)(x, w_gate)
+    for jit in (False, True):
+        got, want = run(moe.route, jit), run(plain_route, jit)
+        _assert_bitwise(got, want)
+        (sel, _, counts), (d_x, d_w) = got
+        assert d_x.dtype == dtype and d_w.dtype == jnp.float32
+        assert float(jnp.abs(d_w).max()) > 0
+        assert not np.array_equal(
+            sel, plain_route(x, w_gate, 0 * bias, top_k=top_k)[0])
+        assert int(counts.sum()) == 96 * top_k
+    d_bias = jax.grad(lambda b: jnp.sum(moe.route(
+        x, w_gate, b, top_k=top_k)[1] * cot))(bias)
+    assert d_bias.shape == bias.shape and float(jnp.abs(d_bias).max()) == 0.0
+    with pytest.raises(TypeError, match="router's weight arrived as bfloat16"):
+        moe.route(x, w_gate.astype(jnp.bfloat16), bias, top_k=top_k)
+    with pytest.raises(TypeError, match="selection bias as bfloat16"):
+        moe.route(x, w_gate, bias.astype(jnp.bfloat16), top_k=top_k)
+
+
+def _layer_under_test(layer):
+    """``(f, arguments)``: the layer's output for a bias that is not zero,
+    two of eight experts held, as a function of what a gradient reaches."""
+    bias = jnp.zeros((E,)).at[3].set(.2).at[6].set(-.2)
+    if layer == "moe_layer":
+        p, names = _weights(held=2), ("x", "w_gate", "w1", "w3", "w2")
+        f = lambda *a: ops.moe_layer(a[0], a[1], bias, *a[2:], top_k=K,
+                                     expert_offset=2)[0]
+    else:
+        p, names = _latent_weights(held=2), ("x", "latent", "w_gate", "w1",
+                                             "w2")
+        f = lambda *a: ops.latent_moe_layer(a[0], a[1], a[2], bias, *a[3:],
+                                            top_k=K, expert_offset=2,
+                                            routed_scaling_factor=2.5)[0]
+    return f, [p[n] for n in names], p["cot"]
+
+
+@pytest.mark.parametrize("route", ["rule", "plain"])
+@pytest.mark.parametrize("layer", ["moe_layer", "latent_moe_layer"])
+def test_a_checkpoint_that_keeps_routed_gives_the_same_gradients(
+        layer, route, monkeypatch):
+    """Under ``save_only_these_names(ROUTED)`` the layers give the gradients
+    they give without a checkpoint, with ``route``'s own rule and with a
+    plain function in its place, as the benchmark's controls replace it.
+    The checkpoint's backward sorts nothing again, and with the rule selects
+    nothing again (autodiff of the plain function reads the score matrix,
+    which is not kept, and so routes twice)."""
+    if route == "plain":
+        monkeypatch.setattr(moe, "route", plain_route)
+    f, args, cot = _layer_under_test(layer)
+    kept = jax.checkpoint(f, policy=(
+        jax.checkpoint_policies.save_only_these_names(moe.ROUTED)))
+    grad = lambda f: jax.jit(jax.grad(lambda *a: jnp.sum(f(*a) * cot),
+                                      tuple(range(len(args)))))
+    for got, want in zip(grad(kept)(*args), grad(f)(*args), strict=True):
+        # two programs: the last digits are the compiler's to choose
+        scale = float(jnp.abs(want).max())
+        assert scale > 0
+        np.testing.assert_allclose(got, want, atol=2e-6 * scale)
+    count = lambda f: routing_ops(grad(f), *args, tokens=N, experts=E)
+    twice = {"top_k": 2, "sort": 2, "scores": 2, "score_gradients": 2}
+    assert count(kept) == (
+        {"top_k": 1, "sort": 1, "scores": 1, "score_gradients": 2}
+        if route == "rule" else dict(twice, sort=1))
+    # a checkpoint without the name: as before
+    assert count(jax.checkpoint(f)) == twice
+
+
+@pytest.mark.parametrize("layer", ["moe_layer", "latent_moe_layer"])
+def test_what_a_layer_keeps_of_the_route_has_no_axis_of_experts(layer):
+    """The residuals of a checkpoint that keeps ``ROUTED``: the arguments
+    and arrays of the pairs' size at most, none ``[.., E]``."""
+    from jax._src.ad_checkpoint import saved_residuals
+    f, args, cot = _layer_under_test(layer)
+    kept = jax.checkpoint(f, policy=(
+        jax.checkpoint_policies.save_only_these_names(moe.ROUTED)))
+    made = [aval for aval, why in saved_residuals(
+        lambda *a: jnp.sum(kept(*a) * cot), *args)
+        if "argument" not in why and "constant" not in why]
+    assert made and all(aval.size <= N * K and E not in aval.shape[1:]
+                        for aval in made), made
+    shapes = {(aval.shape, str(aval.dtype)) for aval in made}
+    assert shapes == {((N, K), "int32"), ((N, K), "float32"), ((N, K), "bool"),
+                      ((N * K,), "int32"), ((2,), "int32")}
+    # sel, the selected scores, the weights, held, order, group_sizes, and
+    # the SwiGLU layer's pos
+    assert len(made) == (7 if layer == "moe_layer" else 6)

@@ -20,6 +20,7 @@ from apex_tpu.contrib.xentropy import softmax_cross_entropy_loss
 from apex_tpu.models import nemotron_h
 from apex_tpu.ops import moe
 from benchmark.reference import nemotron_h as reference
+from test_moe import plain_route, routing_ops
 
 BATCH, SEQ = 2, 33
 #: what the reference reads of a configuration, at the tiny preset's sizes
@@ -283,6 +284,90 @@ def test_the_scopes_reach_the_compiled_step_and_the_waves_are_loops(o2_step):
              if " while(" in line and "/apex.moe/" in line]
     assert any(under + "layer_1/" in line for line in loops)
     assert any("transpose(jvp(apex.forward))" in line for line in loops)
+
+
+def _loss_of_a_share(**kw):
+    """The tiny model holding 4 of its 16 experts: ``(loss, new state)`` as
+    a function of the parameters, and the parameters."""
+    model = models.nemotron_h_tiny(experts_held=4, expert_offset=2, **kw)
+    variables = _init(model)
+    ids = _ids()
+
+    def loss(p):
+        logits, new = model.apply({"params": p, "moe": variables["moe"]},
+                                  ids[:, :-1], mutable=["moe"])
+        return -jnp.take_along_axis(jax.nn.log_softmax(logits),
+                                    ids[:, 1:, None], -1).mean(), new["moe"]
+    return loss, variables["params"]
+
+
+@pytest.mark.parametrize("route", ["rule", "plain"])
+def test_a_differentiated_step_routes_once_a_layer(route, monkeypatch):
+    """In the gradient of the loss every one of the five latent layers
+    selects once, sorts once and makes its scores once, with the router's
+    two gradients behind them; with a plain function for ``route`` the
+    recomputed forward makes the scores and selects again, as every step did
+    before the route's results were kept."""
+    if route == "plain":
+        monkeypatch.setattr(moe, "route", plain_route)
+    loss, params = _loss_of_a_share()
+    again = 2 if route == "plain" else 1
+    assert routing_ops(jax.grad(lambda p: loss(p)[0]), params,
+                       tokens=BATCH * SEQ, experts=16) == {
+        "top_k": 5 * again, "sort": 5, "scores": 5 * again,
+        "score_gradients": 10}
+
+
+def test_the_checkpoints_keep_inputs_the_routed_result_and_the_route():
+    """What the eleven checkpoints save: every layer's input, of a latent
+    layer the routed result ``[B, T, latent]`` and, under ``ROUTED``, six
+    arrays of the (token, slot) pairs' size at most: no score matrix, no
+    array with the router's 16 experts as an axis."""
+    from jax._src.ad_checkpoint import saved_residuals
+    loss, params = _loss_of_a_share()
+    kept = [(aval, why) for aval, why in saved_residuals(
+        lambda p: loss(p)[0], params) if "argument" not in why]
+    shapes = [aval.shape for aval, _ in kept]
+    assert not any(16 in shape for shape in shapes), shapes
+    pairs = (BATCH * SEQ, 4)
+    routed = [(aval, why) for aval, why in kept
+              if aval.shape in (pairs, (BATCH * SEQ * 4,), (4,))]
+    assert all("apex_tpu/ops/moe.py" in why for _, why in routed)
+    # sel, the selected scores, the weights and held; order; group_sizes
+    assert [sum(aval.shape == shape for aval, _ in routed)
+            for shape in (pairs, (BATCH * SEQ * 4,), (4,))] == [20, 5, 5]
+    assert any(moe.ROUTED in why for _, why in routed)
+    rest = [shape for shape in shapes
+            if shape not in (pairs, (BATCH * SEQ * 4,), (4,))]
+    # [batch, tokens, ..] of the layers and of the loss, the last norm's scale
+    assert all(len(shape) >= 3 for shape in rest), rest
+    assert rest.count((BATCH, SEQ, 32)) == 5            # MIXED
+    assert rest.count((BATCH, SEQ, 64)) >= 11           # the layers' inputs
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_loss_gradients_and_state_are_the_plain_routes_bit_for_bit(
+        dtype, monkeypatch):
+    """``route``'s own rule under checkpoints that keep its results, and the
+    plain function under autodiff, which routes twice, as every step did
+    before: loss, every gradient and the model's state are the same bits.
+    Op by op: inside one compiled program XLA's fusions choose the last
+    digits, and two programs of one formula differ there."""
+    loss, params = _loss_of_a_share(pattern="ME*E", dtype=dtype)
+
+    def op_by_op():
+        with jax.disable_jit():
+            return jax.value_and_grad(loss, has_aux=True)(params)
+    ours = op_by_op()
+    monkeypatch.setattr(moe, "route", plain_route)
+    for got, want in zip(jax.tree_util.tree_leaves(ours),
+                         jax.tree_util.tree_leaves(op_by_op()), strict=True):
+        np.testing.assert_array_equal(got, want)
+    (_, state), grads = ours
+    assert all(int(s["experts"]["load"][2:6].sum()) for s in state.values())
+    assert all(float(jnp.abs(layer["experts"]["router"]).max()) > 0
+               for layer in (grads["layer_1"], grads["layer_3"]))
 
 
 def test_a_share_of_every_layer_is_a_constructor_argument():
