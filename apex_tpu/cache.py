@@ -47,6 +47,8 @@ from typing import Any, Optional, Tuple
 
 import jax
 
+from .telemetry import events as _events
+
 __all__ = ["enable", "resolve_dir", "is_enabled", "cache_dir",
            "abstractify", "signature", "warmup"]
 
@@ -67,7 +69,7 @@ def resolve_dir(path: Optional[str] = None) -> str:
     """THE decision of where the persistent compilation cache lives:
     ``$JAX_COMPILATION_CACHE_DIR`` when set, else an explicit ``path``,
     else ``<checkout>/.jax_cache``.  Everything that wants a cache
-    (``bench.py``, ``chip_smoke.py``, the examples) goes through
+    (``benchmark/run.py``, ``chip_smoke.py``, the examples) goes through
     :func:`enable`, which asks here."""
     env = os.environ.get(ENV_VAR)
     return os.path.abspath(os.path.expanduser(
@@ -175,5 +177,24 @@ def warmup(jitted, *args) -> Any:
     with concrete arrays of the same signature to bypass tracing
     entirely.  With the persistent cache :func:`enable`-d, the backend
     compile inside is itself a disk hit on the second process start.
+
+    With a telemetry recorder active, one ``warmup`` event says what the
+    three stages cost and whether the persistent cache held the program
+    (:func:`apex_tpu.telemetry.events.warmup_begins`).  The stages are
+    told apart by the recorder's ``jax.monitoring`` listener, not by
+    code here, and this frame holds nothing but its arguments, on
+    purpose.  Tracing and lowering recurse a hundred frames deep, and
+    CPython 3.12 keeps frames in 16 KiB chunks that it unmaps and maps
+    again for every call that straddles a chunk's end; which calls
+    straddle follows from the size of every frame below.  Twelve more
+    locals here took ResNet-50's lowering from 1.2 s to 17 to 36 s on
+    the chip, the same program (``PERF.md`` section 6, PR 35).  Until
+    the warm-up is made immune to that (``ROADMAP.md`` S13), this frame
+    keeps the size it had: ``tests/test_setup_telemetry.py`` holds it to
+    the one-liner's.
     """
-    return jitted.lower(*abstractify(args)).compile()
+    _events.warmup_begins(jitted)
+    try:
+        return jitted.lower(*abstractify(args)).compile()
+    finally:
+        _events.warmup_ends()

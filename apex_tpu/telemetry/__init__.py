@@ -8,7 +8,9 @@ metric reads (zero extra host syncs per window).
 
 * :class:`Recorder` / :func:`start` — thread-safe JSONL event stream
   (step windows, dispatch gaps, loader stage/stall, loss-scale
-  skip/growth, retraces, per-psum collective bytes).
+  skip/growth, retraces, per-psum collective bytes, and set-up: every
+  lowering, backend compile and compile-cache hit, the stages of an
+  AOT warm-up, the process's age at the stream's start).
 * :class:`MetricsRegistry` — counters / gauges / reservoir-percentile
   histograms; a strict no-op when disabled.
 * :class:`Watchdog` (:mod:`~apex_tpu.telemetry.watchdog`) — run-health
@@ -21,8 +23,9 @@ metric reads (zero extra host syncs per window).
 
 Instrumented subsystems discover the active recorder through
 :func:`get_recorder`; with none installed the hot paths reduce to one
-global read — the disabled path dispatches bit-identically to an
-uninstrumented build (``bench.py`` gates this).
+global read and ``jax.monitoring`` holds no listener of ours — the
+disabled path compiles the same program as an uninstrumented build
+(``tests/test_setup_telemetry.py`` holds both).
 
 See ``docs/telemetry.md`` for the event schema and overhead model.
 """

@@ -16,6 +16,10 @@ boundaries the runtime already crosses:
   the window's shape signature)
 * per-psum collective bytes            (recorded at TRACE time from the
   static avals — zero runtime cost)
+* lowerings, backend compiles and persistent-cache hits
+  (``jax.monitoring`` listeners that live from :func:`start` to
+  :meth:`Recorder.close`), and the three stages of an AOT warm-up
+  (:func:`apex_tpu.cache.warmup`)
 
 :class:`Recorder` writes one JSON object per line (JSONL): ``tail -f``
 it in production, feed it to the offline analyzer
@@ -27,9 +31,9 @@ Overhead model: every event is one small dict + one ``json.dumps`` + one
 buffered write (~single-digit microseconds); the hot loop emits 2-3
 events per WINDOW (not per step) and the loader a couple per batch on
 its own threads.  With no recorder installed the instrumented call sites
-reduce to one global read returning ``None`` — the disabled path
-dispatches bit-identically to an uninstrumented build (gated by
-``bench.py`` self-validation).
+reduce to one global read returning ``None`` and ``jax.monitoring`` holds no
+listener of ours — the disabled path compiles the same program as an
+uninstrumented build (``tests/test_setup_telemetry.py`` holds both).
 
 Usage::
 
@@ -56,7 +60,8 @@ from typing import Any, Dict, IO, List, Optional, Union
 from .metrics import MetricsRegistry
 
 __all__ = ["Recorder", "get_recorder", "set_recorder", "start",
-           "start_from_env", "to_chrome_trace", "expand_stream_paths"]
+           "start_from_env", "to_chrome_trace", "expand_stream_paths",
+           "warmup_begins", "warmup_ends"]
 
 _active: Optional["Recorder"] = None
 _active_lock = threading.Lock()
@@ -100,13 +105,19 @@ def start(path: Optional[str] = None, watchdog: Optional[bool] = None,
     Keyword args land in the stream's leading ``run`` event.
 
     ``path=None`` reads ``APEX_TPU_TELEMETRY`` — any entrypoint (the
-    docker matrix, ``bench.py``, a user script) can be instrumented by
+    docker matrix, a user script) can be instrumented by
     exporting the env var instead of plumbing a flag (ISSUE 10
     satellite); with neither a ``ValueError`` says so.  ``watchdog``
     likewise defaults from ``APEX_TPU_WATCHDOG`` (``0``/``1``), and the
     export knobs from ``APEX_TPU_METRICS_TEXTFILE`` /
     ``APEX_TPU_METRICS_PORT``.  See :func:`start_from_env` for the
     quiet does-nothing-when-unconfigured variant.
+
+    For the recorder's life one ``jax.monitoring`` event listener and
+    one duration listener write a ``compile`` event for every program
+    jax lowers, compiles or reads from the persistent cache
+    (:class:`_CompileListener`); :meth:`Recorder.close` unregisters
+    them.  A bare ``Recorder(file)`` registers none.
 
     ``watchdog=True`` also attaches the run-health rule engine
     (:mod:`apex_tpu.telemetry.watchdog`): events are folded online on
@@ -178,6 +189,7 @@ def start(path: Optional[str] = None, watchdog: Optional[bool] = None,
     if slo is not None:
         from .slo import attach as attach_slo
         attach_slo(rec, slo)
+    rec._compile_listener = _CompileListener.register(rec)
     set_recorder(rec)
     return rec
 
@@ -186,9 +198,9 @@ def start_from_env(**meta) -> Optional["Recorder"]:
     """:func:`start` driven purely by env vars — returns the installed
     :class:`Recorder` when ``APEX_TPU_TELEMETRY`` names a stream path,
     else ``None`` without side effects.  The hook entrypoints call when
-    they have no telemetry flags of their own (``bench.py``, the docker
-    matrix): ``APEX_TPU_TELEMETRY=/tmp/run.jsonl APEX_TPU_WATCHDOG=1
-    python bench.py`` instruments the whole run."""
+    they have no telemetry flags of their own (the docker matrix):
+    ``APEX_TPU_TELEMETRY=/tmp/run.jsonl APEX_TPU_WATCHDOG=1 python
+    train.py`` instruments the whole run."""
     if not (os.environ.get("APEX_TPU_TELEMETRY") or "").strip():
         return None
     return start(**meta)
@@ -227,6 +239,157 @@ def _process_identity() -> tuple:
     return 0, 1
 
 
+def _process_age_s() -> Optional[float]:
+    """Seconds since this process started: ``/proc/self/stat``'s start
+    time (field 22, clock ticks after boot) against ``CLOCK_BOOTTIME``.
+    None where there is no ``/proc``."""
+    try:
+        with open("/proc/self/stat", encoding="ascii") as f:
+            # the command (field 2) may hold spaces: count from its ")"
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        return (time.clock_gettime(time.CLOCK_BOOTTIME)
+                - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, AttributeError, ValueError, IndexError):
+        return None
+
+
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: what jax's persistent cache says, in the compiling thread, before the
+#: backend event of the same program: a request is a miss until it hits
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_misses": "miss",
+    "/jax/compilation_cache/cache_hits": "hit"}
+
+
+class _PendingCompile(threading.local):
+    """What the cache has said of the program this thread is compiling."""
+    cache = "off"
+    read_s = None
+
+
+class _Warming(threading.local):
+    """The warm-up this thread is in (:func:`warmup_begins`), or None."""
+    note = None
+
+
+_warming = _Warming()
+
+
+class _WarmupNote:
+    """One ``cache.warmup`` in progress: when it began, and what the
+    compile listener has seen of its program since."""
+
+    def __init__(self, rec: "Recorder", program: Optional[str]):
+        self.rec, self.program = rec, program
+        self.t0 = time.perf_counter()
+        self.lower_s = self.lower_end = None
+        self.cache = "off"
+
+    def write(self) -> None:
+        end = time.perf_counter()
+        fields = {"program": self.program}
+        if self.lower_end is not None:
+            # the program's own lowering is the last one before its
+            # backend compile; what lies before it is the trace (small
+            # programs compiled on the way included), what lies after
+            # it the backend compile or the cache's read
+            fields.update(
+                trace_s=round(self.lower_end - self.lower_s - self.t0, 6),
+                lower_s=round(self.lower_s, 6),
+                compile_s=round(end - self.lower_end, 6))
+        self.rec.event("warmup", dur=round(end - self.t0, 6),
+                       cache=self.cache, **fields)
+
+
+def warmup_begins(jitted) -> None:
+    """Called by :func:`apex_tpu.cache.warmup` before it lowers and
+    compiles ``jitted``: with an active recorder, the ``warmup`` event
+    that :func:`warmup_ends` writes counts from here.  One global read
+    when there is none."""
+    rec = get_recorder()
+    _warming.note = None if rec is None else _WarmupNote(
+        rec, getattr(jitted, "__name__", None))
+
+
+def warmup_ends() -> None:
+    """Write the ``warmup`` event of the warm-up this thread began: its
+    length, and its three stages where the recorder's compile listener
+    saw the program lowered (a bare ``Recorder`` has no listener)."""
+    note, _warming.note = _warming.note, None
+    if note is not None:
+        note.write()
+
+
+class _CompileListener:
+    """The two ``jax.monitoring`` listeners of one recorder: a ``compile``
+    event for every program jax lowers (``stage="lower"``: jaxpr to
+    StableHLO, Mosaic kernels lowered inside it) and for every backend
+    compile or persistent-cache read (``stage="backend"``).  jax's trace
+    durations are not written: they nest, the outer function's holds
+    every inner ``jit``'s.  It also tells a warm-up in progress in the
+    same thread where its stages end."""
+
+    def __init__(self, rec: "Recorder", monitoring):
+        self._rec, self._monitoring = rec, monitoring
+        self._pending = _PendingCompile()
+
+    @classmethod
+    def register(cls, rec: "Recorder") -> Optional["_CompileListener"]:
+        try:
+            import jax.monitoring as monitoring
+        except ImportError:         # a stream-analysis box: nothing compiles
+            return None
+        self = cls(rec, monitoring)
+        monitoring.register_event_listener(self._on_event)
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        return self
+
+    def unregister(self) -> None:
+        for remove, listener in (
+                (self._monitoring.unregister_event_listener, self._on_event),
+                (self._monitoring.unregister_event_duration_listener,
+                 self._on_duration)):
+            try:
+                remove(listener)
+            except (AssertionError, ValueError):
+                pass    # jax.monitoring.clear_event_listeners() got there first
+
+    def _on_event(self, event: str, **kwargs) -> None:
+        cache = _CACHE_EVENTS.get(event)
+        if cache is not None:
+            self._pending.cache, self._pending.read_s = cache, None
+
+    def _on_duration(self, event: str, duration: float, **kwargs) -> None:
+        if event == _CACHE_READ_EVENT:
+            self._pending.read_s = duration
+        elif event == _LOWER_EVENT:
+            note = _warming.note
+            if note is not None and note.rec is self._rec:
+                note.lower_s, note.lower_end = duration, time.perf_counter()
+            self._rec.event("compile", stage="lower", dur=round(duration, 6),
+                            fun_name=kwargs.get("fun_name"))
+        elif event == _BACKEND_EVENT:
+            pending, metrics = self._pending, self._rec.metrics
+            fields = {"cache": pending.cache}
+            if pending.cache == "hit":
+                metrics.counter("compile_cache_hits").inc()
+                if pending.read_s is not None:
+                    fields["read_s"] = round(pending.read_s, 6)
+            elif pending.cache == "miss":
+                metrics.counter("compile_cache_misses").inc()
+            metrics.counter("programs_compiled").inc()
+            note = _warming.note
+            if note is not None and note.rec is self._rec:
+                note.cache = pending.cache
+            pending.cache, pending.read_s = "off", None
+            self._rec.event("compile", stage="backend",
+                            dur=round(duration, 6),
+                            fun_name=kwargs.get("fun_name"), **fields)
+
+
 class Recorder:
     """Thread-safe JSONL event sink + metrics registry for one run.
 
@@ -263,6 +426,10 @@ class Recorder:
         #: coarse cross-host alignment ``prof.fleet`` refines with
         #: per-window dispatch indices (ISSUE 10).
         self.anchor_unix = time.time()
+        #: seconds this process had lived when the recorder opened:
+        #: interpreter, imports, backend start-up — what precedes the
+        #: stream.  None where there is no ``/proc``.
+        self.process_age_s = _process_age_s()
         if process_index is None or process_count is None:
             process_index, process_count = _process_identity()
         #: this host's slot in the fleet, stamped on the ``run`` event so
@@ -300,17 +467,23 @@ class Recorder:
         self._tracer = None
         #: optional SLO fold (slo.attach — ISSUE 20)
         self._slo = None
+        #: the ``jax.monitoring`` listeners :func:`start` registers; a
+        #: bare ``Recorder`` registers none
+        self._compile_listener: Optional[_CompileListener] = None
         self.event("run", **self._run_fields())
 
     def _run_fields(self) -> Dict[str, Any]:
         """The ``run`` event's fields — re-emitted at the head of every
         rotated segment so each file in a rotated set is
         self-describing (same run_id / anchor / host identity)."""
-        return {"run_id": self.run_id, "meta": self._meta,
-                "process_index": self.process_index,
-                "process_count": self.process_count,
-                "anchor_unix": round(self.anchor_unix, 6),
-                "segment": self._segment}
+        fields = {"run_id": self.run_id, "meta": self._meta,
+                  "process_index": self.process_index,
+                  "process_count": self.process_count,
+                  "anchor_unix": round(self.anchor_unix, 6),
+                  "segment": self._segment}
+        if self.process_age_s is not None:
+            fields["process_age_s"] = round(self.process_age_s, 3)
+        return fields
 
     # -- core sink ----------------------------------------------------------
     @property
@@ -530,6 +703,9 @@ class Recorder:
         stream.  Idempotent."""
         if self._closed:
             return
+        if self._compile_listener is not None:
+            self._compile_listener.unregister()
+            self._compile_listener = None
         if loader_stats:
             self.event("loader", final=True, stats=dict(loader_stats))
         summary_fields = {"metrics": self.metrics.snapshot()}
