@@ -3,11 +3,22 @@
 TPU-native re-design of reference ``apex/contrib/xentropy/softmax_xentropy.py``
 + ``apex/contrib/csrc/xentropy/xentropy_kernel.cu``:
 
-* forward returns per-example ``losses`` and saves only ``max_log_sum_exp``
-  (one fp32 scalar per row) instead of materialized log-probs — the memory
-  trick of the CUDA kernel (interface returns ``(losses, max_log_sum_exp)``).
-* backward is fused: ``d logits = g * (softmax - (1-s)·onehot - s/H)``,
-  recomputed from logits + mlse.
+* the primal forward returns per-example ``losses`` (and, inside,
+  ``max_log_sum_exp``, one fp32 scalar per row, as the CUDA kernel's
+  interface does) and writes no ``[N, H]`` array.
+* under differentiation the loss reads its logits once: the forward kernel,
+  which already holds the row and its log-sum-exp, also computes
+  ``r = softmax - (1-s)·onehot - s/H`` and writes it over the logits
+  (``input_output_aliases``: for float32 logits no second ``[N, H]`` array
+  exists; XLA copies only where a caller reads the logits after the loss).
+  The residuals are ``(r, labels)``; the backward is ``d logits = g * r``,
+  one broadcast multiplication in ``jax.numpy`` that XLA fuses into the
+  consumers (the head's two gradient products), with no kernel of its own;
+  it does so only where no reshape stands between, which is why the models'
+  heads multiply over flattened tokens (``models.granite_hybrid.head_logits``).
+  For float32 logits ``g * r`` is the product the old backward kernel
+  computed, bit for bit.  For logits narrower than float32 ``r`` is rounded
+  to the logits' dtype before the multiplication and the product once more.
 * positions where ``labels == padding_idx`` contribute zero loss and zero
   gradient (reference ``softmax_xentropy.py:9,23``).
 
@@ -18,7 +29,7 @@ Loss definition (reference test oracle ``test_label_smoothing.py:10-28``)::
 
 On TPU a Pallas kernel processes a block of rows per grid step (row max /
 sum-exp on the VPU, label extraction via iota-select); off TPU the same math
-runs as jnp, doubling as the oracle.
+runs as jnp and stores the same residual, doubling as the oracle.
 """
 
 from __future__ import annotations
@@ -38,8 +49,9 @@ from ...tune.space import pow2_bucket as _pow2
 
 __all__ = ["SoftmaxCrossEntropyLoss", "softmax_cross_entropy_loss"]
 
-#: config-cache version of this kernel's blocking scheme (ISSUE 14).
-TUNE_VERSION = 1
+#: config-cache version of this kernel's blocking scheme (ISSUE 14; 2 since
+#: the forward kernel under differentiation also writes ``[R, H]`` blocks).
+TUNE_VERSION = 2
 
 
 # -- reference math (jnp fallback + oracle) -----------------------------------
@@ -73,9 +85,11 @@ _VMEM_BUFFER_BUDGET = 2 * 1024 * 1024   # bytes per fp32 [R, H] working buffer
 def _row_block(n, h, row_block=None):
     """Rows per grid step, sized so the fp32 [R, H] working buffers stay
     inside the TPU's ~16MB scoped-VMEM limit even for LM-head-sized
-    vocabularies (e.g. H=30522).  The backward kernel holds up to ~6 live
-    [R, H] intermediates (logits, softmax, onehot/iota, grad-out), hence the
-    conservative per-buffer budget.  ``row_block`` overrides the 128-row
+    vocabularies (e.g. H=50257).  The forward kernel under differentiation
+    holds the logits block and the ``r`` block, both double-buffered by the
+    pipeline, and up to ~4 live [R, H] intermediates (exp(x - max), iota /
+    onehot, softmax, ``r`` before its store), hence the conservative
+    per-buffer budget.  ``row_block`` overrides the 128-row
     cap (the autotuner's knob, ISSUE 14); the budget clamp below it
     keeps any tuned value VMEM-legal."""
     rows = min(row_block or _ROW_BLOCK, _VMEM_BUFFER_BUDGET // (4 * h))
@@ -106,31 +120,33 @@ def _pallas_fits(h):
 # 2-D arrays: Mosaic requires lane-tiled ≥2-D layouts; 1-D s32 operands hit
 # an XLA/Mosaic layout mismatch on real TPUs.
 
-def _fwd_kernel(x_ref, lab_ref, loss_ref, mlse_ref, *, smoothing):
+def _block_losses(x_ref, lab_ref, smoothing):
+    """One row block's ``(xf, onehot mask, losses, mlse)``."""
     xf = x_ref[:].astype(jnp.float32)                   # [R, H]
     h = xf.shape[1]
     m = jnp.max(xf, axis=1, keepdims=True)
     mlse = m + jnp.log(jnp.sum(jnp.exp(xf - m), axis=1, keepdims=True))
     lab = lab_ref[:]                                    # [R, 1]
     col = jax.lax.broadcasted_iota(jnp.int32, xf.shape, 1)
-    picked = jnp.sum(jnp.where(col == lab, xf, 0.0), axis=1, keepdims=True)
+    hit = col == lab
+    picked = jnp.sum(jnp.where(hit, xf, 0.0), axis=1, keepdims=True)
     mean_logit = jnp.sum(xf, axis=1, keepdims=True) / h
-    loss_ref[:] = (mlse - (1.0 - smoothing) * picked
-                   - smoothing * mean_logit)
-    mlse_ref[:] = mlse
+    losses = mlse - (1.0 - smoothing) * picked - smoothing * mean_logit
+    return xf, hit, losses, mlse
 
 
-def _bwd_kernel(g_ref, x_ref, mlse_ref, lab_ref, dx_ref, *, smoothing):
-    xf = x_ref[:].astype(jnp.float32)
-    h = xf.shape[1]
-    mlse = mlse_ref[:]                                  # [R, 1]
-    g = g_ref[:]                                        # [R, 1]
-    lab = lab_ref[:]                                    # [R, 1]
+def _fwd_kernel(x_ref, lab_ref, loss_ref, mlse_ref, *, smoothing):
+    _, _, loss_ref[:], mlse_ref[:] = _block_losses(x_ref, lab_ref, smoothing)
+
+
+def _fwd_grad_kernel(x_ref, lab_ref, loss_ref, r_ref, *, smoothing):
+    """``_fwd_kernel``'s losses and, from the same ``xf`` in VMEM, the
+    gradient short of the incoming factor; ``r_ref`` is the logits' buffer."""
+    xf, hit, loss_ref[:], mlse = _block_losses(x_ref, lab_ref, smoothing)
     soft = jnp.exp(xf - mlse)
-    col = jax.lax.broadcasted_iota(jnp.int32, xf.shape, 1)
-    onehot = (col == lab).astype(jnp.float32)
-    dx = g * (soft - (1.0 - smoothing) * onehot - smoothing / h)
-    dx_ref[:] = dx.astype(dx_ref.dtype)
+    onehot = hit.astype(jnp.float32)
+    r = soft - (1.0 - smoothing) * onehot - smoothing / xf.shape[1]
+    r_ref[:] = r.astype(r_ref.dtype)
 
 
 def _fwd_pallas(logits, labels, smoothing, interpret=False,
@@ -152,22 +168,25 @@ def _fwd_pallas(logits, labels, smoothing, interpret=False,
     return loss[:, 0], mlse[:, 0]
 
 
-def _bwd_pallas(g, logits, mlse, labels, smoothing, interpret=False,
-                row_block=None):
+def _fwd_grad_pallas(logits, labels, smoothing, interpret=False,
+                     row_block=None):
+    """``(losses, r)``; ``r`` takes the logits' buffer."""
     n, h = logits.shape
     blk = _row_block(n, h, row_block)
     grid = (n + blk - 1) // blk
-    return pl.pallas_call(
-        functools.partial(_bwd_kernel, smoothing=smoothing),
+    loss, r = pl.pallas_call(
+        functools.partial(_fwd_grad_kernel, smoothing=smoothing),
         grid=(grid,),
-        in_specs=[pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-                  pl.BlockSpec((blk, h), lambda i: (i, 0)),
-                  pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec((blk, h), lambda i: (i, 0)),
                   pl.BlockSpec((blk, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((blk, h), lambda i: (i, 0)),
-        out_shape=_sds((n, h), logits.dtype, logits, g),
+        out_specs=[pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+                   pl.BlockSpec((blk, h), lambda i: (i, 0))],
+        out_shape=[_sds((n, 1), jnp.float32, logits),
+                   _sds((n, h), logits.dtype, logits)],
+        input_output_aliases={0: 1},
         interpret=interpret,
-    )(g[:, None], logits, mlse[:, None], labels[:, None])
+    )(logits, labels[:, None])
+    return loss[:, 0], r
 
 
 # -- public op with custom VJP ------------------------------------------------
@@ -196,21 +215,21 @@ def _fwd_impl(logits, labels, smoothing):
 
 def _fwd_vjp(logits, labels, smoothing, padding_idx, half_to_float):
     labels = labels.astype(jnp.int32)
-    losses, mlse = _fwd_impl(logits, labels, smoothing)
+    if _use_pallas() and _pallas_fits(logits.shape[-1]):
+        n, h = logits.shape
+        losses, r = _fwd_grad_pallas(logits, labels, smoothing,
+                                     row_block=_tuned_rows(n, h))
+    else:
+        losses, mlse = _fwd_ref(logits, labels, smoothing)
+        r = _bwd_ref(jnp.ones_like(mlse), logits, mlse, labels, smoothing)
     losses = jnp.where(labels == padding_idx, 0.0, losses)
-    return losses, (logits, mlse, labels)
+    return losses, (r, labels)
 
 
 def _bwd_vjp(smoothing, padding_idx, half_to_float, res, g):
-    logits, mlse, labels = res
-    g = jnp.where(labels == padding_idx, 0.0,
-                  g.astype(jnp.float32))
-    if _use_pallas() and _pallas_fits(logits.shape[-1]):
-        n, h = logits.shape
-        dx = _bwd_pallas(g, logits, mlse, labels, smoothing,
-                         row_block=_tuned_rows(n, h))
-    else:
-        dx = _bwd_ref(g, logits, mlse, labels, smoothing)
+    r, labels = res
+    g = jnp.where(labels == padding_idx, 0.0, g.astype(jnp.float32))
+    dx = (g[:, None] * r.astype(jnp.float32)).astype(r.dtype)
     return dx, None
 
 

@@ -158,7 +158,13 @@ class GPT(nn.Module):
                          name=f"block_{i}")(x)
         x = FusedLayerNorm(normalized_shape=self.hidden_size,
                            name="ln_f")(x)
-        return (x.astype(jnp.float32) @ wte.T).astype(jnp.float32)
+        # One product over the flattened tokens: the fused loss's ``g * r``
+        # comes back as ``[B * T, V]``, and XLA fuses it into the head's two
+        # gradient products only with no reshape between
+        # (``granite_hybrid.head_logits``).
+        b, t, d = x.shape
+        logits = x.reshape(b * t, d).astype(jnp.float32) @ wte.T
+        return logits.astype(jnp.float32).reshape(b, t, -1)
 
 
 def gpt2_small(**kw):

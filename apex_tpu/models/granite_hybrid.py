@@ -252,6 +252,23 @@ class HybridLayer(nn.Module):
         return h + (self.residual_multiplier * m).astype(h.dtype)
 
 
+def head_logits(h, w, divide_by=None):
+    """Float32 logits ``h @ w^T`` (over ``divide_by``) ``[B, T, V]``, computed
+    as one product over the ``B * T`` flattened tokens.  The fused loss
+    takes ``logits.reshape(-1, V)`` and hands back ``g * r`` in that shape
+    (``contrib.xentropy``); with the product written over ``[B, T]`` a
+    reshape stands between that multiplication and the head's two gradient
+    products, and XLA's TPU fusions take no producer through a reshape: it
+    writes ``g * r`` out.  Over flattened tokens the two reshapes cancel
+    and the multiplication rides in both products' prologues."""
+    b, t, d = h.shape
+    logits = jnp.einsum("nd,vd->nv", h.reshape(b * t, d), w.astype(h.dtype),
+                        preferred_element_type=jnp.float32)
+    if divide_by is not None:
+        logits = logits / divide_by
+    return logits.reshape(b, t, -1)
+
+
 class GraniteHybrid(nn.Module):
     """``__call__(input_ids) -> logits [B, T, V]`` (float32, tied head).
     The defaults are granite-4.0-h-micro's published ``config.json``."""
@@ -296,9 +313,7 @@ class GraniteHybrid(nn.Module):
                       self.residual_multiplier, self.eps, self.dtype,
                       name=f"layer_{i}")(h)
         h = RMSNorm(self.eps, name="norm_f")(h)
-        logits = jnp.einsum("btd,vd->btv", h, wte.astype(h.dtype),
-                            preferred_element_type=jnp.float32)
-        return logits / self.logits_scaling
+        return head_logits(h, wte, self.logits_scaling)
 
 
 def granite_hybrid_tiny(**kw):

@@ -62,6 +62,7 @@ from ..ops.flash_attention import flash_attention
 from ..ops.moe import MOE_SCOPES, ROUTED, moe_layer
 from ..ops.rope import qk_norm_rope
 from ..ops.short_conv import gated_short_conv
+from .granite_hybrid import head_logits
 
 #: the scopes of the mixers; the expert layer's are ``ops.moe.MOE_SCOPES``
 SCONV_SCOPE, ROPE_SCOPE = "apex.sconv", "apex.rope"
@@ -268,8 +269,7 @@ class Lfm2Moe(nn.Module):
                       experts if routed else dict(width=self.mlp_dim),
                       self.eps, self.dtype, name=f"layer_{i}")(h)
         h = RMSNorm(self.eps, name="norm_f")(h)
-        return jnp.einsum("btd,vd->btv", h, wte.astype(h.dtype),
-                          preferred_element_type=jnp.float32)
+        return head_logits(h, wte)
 
 
 def lfm2_moe_tiny(**kw):

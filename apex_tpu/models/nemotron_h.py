@@ -70,7 +70,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..normalization import RMSNorm
 from ..ops.moe import LATENT_SCOPES, MOE_SCOPES, ROUTED, latent_moe_layer
 from . import granite_hybrid
-from .granite_hybrid import GQAttention, Mamba2Mixer
+from .granite_hybrid import GQAttention, Mamba2Mixer, head_logits
 
 #: the published string of layer kinds: 40 ``M``, 40 ``E``, 8 ``*``
 PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
@@ -233,8 +233,7 @@ class NemotronH(nn.Module):
             h = layer(kind, parts.get(kind), self.eps, self.dtype,
                       name=f"layer_{i}")(h)
         h = RMSNorm(self.eps, name="norm_f")(h)
-        return jnp.einsum("btd,vd->btv", h, head.astype(h.dtype),
-                          preferred_element_type=jnp.float32)
+        return head_logits(h, head)
 
 
 def nemotron_h_tiny(**kw):

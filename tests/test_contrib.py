@@ -100,19 +100,24 @@ def test_groupbn_validation_errors():
 # -- tier parity (ISSUE 7 satellite): the REAL pallas kernels, interpret
 # mode on CPU, vs the _fwd_ref/_bwd_ref oracles -------------------------------
 
-from apex_tpu.contrib.xentropy import (_bwd_pallas, _bwd_ref, _fwd_pallas,
-                                       _fwd_ref)
+from apex_tpu.contrib.xentropy import (_bwd_ref, _fwd_grad_pallas,
+                                       _fwd_pallas, _fwd_ref)
 
 
 @pytest.mark.parametrize("smoothing", [0.0, 0.1])
 def test_xentropy_pallas_interpret_forward_parity(smoothing):
+    """Both forward kernels: the primal one and the one that runs under
+    differentiation give the reference's losses."""
     rng = np.random.RandomState(2)
     n, h = 48, 256
     x = jnp.asarray(rng.randn(n, h), jnp.float32)
     labels = jnp.asarray(rng.randint(0, h, n), jnp.int32)
     loss_k, mlse_k = _fwd_pallas(x, labels, smoothing, interpret=True)
+    loss_g, _ = _fwd_grad_pallas(x, labels, smoothing, interpret=True)
     loss_r, mlse_r = _fwd_ref(x, labels, smoothing)
     np.testing.assert_allclose(np.asarray(loss_k), np.asarray(loss_r),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(loss_g), np.asarray(loss_r),
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(mlse_k), np.asarray(mlse_r),
                                atol=1e-5)
@@ -120,9 +125,11 @@ def test_xentropy_pallas_interpret_forward_parity(smoothing):
 
 @pytest.mark.parametrize("smoothing", [0.0, 0.1])
 def test_xentropy_pallas_interpret_backward_parity(smoothing):
-    """Kernel-vs-reference grad parity including the padding corner: the
-    custom VJP masks padded rows' incoming grads BEFORE the kernel, so
-    the kernel itself is exercised with exactly that masked input."""
+    """Kernel-vs-reference grad parity including the padding corner.  The
+    forward kernel under differentiation leaves ``r``, the gradient short
+    of the incoming factor (``_bwd_ref`` with ``g = 1``); the custom VJP
+    masks padded rows' incoming grads and multiplies, so ``g * r`` is held
+    against ``_bwd_ref`` with exactly that masked input."""
     rng = np.random.RandomState(3)
     n, h = 40, 128
     padding_idx = 0
@@ -130,14 +137,63 @@ def test_xentropy_pallas_interpret_backward_parity(smoothing):
     labels = jnp.asarray(rng.randint(1, h, n), jnp.int32)
     labels = labels.at[::5].set(padding_idx)         # padded rows
     _, mlse = _fwd_ref(x, labels, smoothing)
+    _, r_k = _fwd_grad_pallas(x, labels, smoothing, interpret=True)
+    r_r = _bwd_ref(jnp.ones(n, jnp.float32), x, mlse, labels, smoothing)
+    np.testing.assert_allclose(np.asarray(r_k), np.asarray(r_r), atol=1e-5)
     g = jnp.asarray(rng.rand(n), jnp.float32)
     g = jnp.where(labels == padding_idx, 0.0, g)     # the vjp's mask
-    dx_k = _bwd_pallas(g, x, mlse, labels, smoothing, interpret=True)
+    dx_k = g[:, None] * r_k                          # the vjp's product
     dx_r = _bwd_ref(g, x, mlse, labels, smoothing)
     np.testing.assert_allclose(np.asarray(dx_k), np.asarray(dx_r),
                                atol=1e-5)
-    # padded rows: exactly zero through the kernel too
+    # padded rows: exactly zero through the kernel's residual too
     np.testing.assert_array_equal(np.asarray(dx_k[::5]), 0.0)
+
+
+@pytest.mark.parametrize("smoothing", [0.0, 0.1])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_xentropy_grad_equals_grad_of_the_reference_loss(smoothing, dtype):
+    """``jax.grad`` of the public op against ``jax.grad`` of a loss written
+    from ``_fwd_ref``, padded rows and uneven incoming factors included."""
+    rng = np.random.RandomState(6)
+    n, h = 24, 96
+    padding_idx = 0
+    x = jnp.asarray(rng.randn(n, h), dtype)
+    labels = jnp.asarray(rng.randint(1, h, n), jnp.int32)
+    labels = labels.at[::4].set(padding_idx)
+    weights = jnp.asarray(rng.rand(n), jnp.float32)
+
+    def fused(xx):
+        return jnp.sum(weights * softmax_cross_entropy_loss(
+            xx, labels, smoothing, padding_idx))
+
+    def ref(xx):
+        losses, _ = _fwd_ref(xx, labels, smoothing)
+        return jnp.sum(weights * jnp.where(labels == padding_idx, 0.0,
+                                           losses))
+
+    got, want = jax.grad(fused)(x), jax.grad(ref)(x)
+    assert got.dtype == want.dtype == dtype
+    # bfloat16: ``r`` is rounded before the multiplication and the product
+    # once more, two roundings of values below 1 where autodiff makes one
+    tol = 1e-5 if dtype == jnp.float32 else 2 ** -7
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol)
+    np.testing.assert_array_equal(np.asarray(got[::4], np.float32), 0.0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_xentropy_residual_is_r_and_labels_alone(dtype):
+    """Under differentiation neither the logits nor ``mlse`` is kept: the
+    residuals are one ``[N, H]`` array in the logits' dtype and the labels."""
+    x = jnp.zeros((16, 64), dtype)
+    labels = jnp.arange(16, dtype=jnp.int32)
+    _, vjp = jax.vjp(lambda xx: softmax_cross_entropy_loss(xx, labels, 0.1),
+                     x)
+    kept = sorted((leaf.shape, str(leaf.dtype))
+                  for leaf in jax.tree_util.tree_leaves(vjp))
+    assert kept == sorted([((16, 64), str(jnp.dtype(dtype))),
+                           ((16,), "int32")])
 
 
 def test_groupbn_z_add_relu_matches_oracle():

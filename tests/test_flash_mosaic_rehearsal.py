@@ -160,3 +160,87 @@ def test_latent_layer_compiles_under_mosaic_and_no_array_has_every_pair(
     routes = 2 if checkpoint == "input_only" else 1
     assert sorts.count(f"f32[{n},{e}]") == routes       # top-22 of 512
     assert sorts.count(f"s32[{n * k}]") == routes       # the pairs by expert
+
+
+# the language-model cells' heads: (batch, sequence, hidden, vocabulary, how
+# the model writes the head's product)
+HEADS = {
+    "gpt2_small_o2": (8, 1024, 768, 50257, "gpt"),
+    "granite4_h_micro_o2": (2, 4096, 2048, 50176, "granite"),
+    "lfm2_24b_a2b_o2": (4, 4096, 2048, 16384, "hybrid"),
+}
+
+
+@pytest.mark.parametrize("cell", list(HEADS))
+def test_the_loss_reads_its_logits_once_in_the_compiled_head(
+        one_chip, monkeypatch, cell):
+    """ISSUE 36 at the cells' head shapes: the head's product, the fused
+    loss and the whole backward hold ONE Mosaic call, which takes the
+    logits' buffer for ``r = softmax - target``; nothing of ``[N, V]`` is
+    written after it (XLA fuses ``g * r`` into the head's two gradient
+    products, which it does only because the heads multiply over flattened
+    tokens), so the step's temporaries hold one float32 ``[N, V]`` array."""
+    import re
+
+    xe = importlib.import_module("apex_tpu.contrib.xentropy")
+    monkeypatch.setattr(xe, "_use_pallas", lambda: True)
+    hybrid = importlib.import_module("apex_tpu.models.granite_hybrid")
+    b, t, d, v, kind = HEADS[cell]
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ids = sds((b, t), jnp.int32)
+    if kind == "gpt":      # the model itself without a block: embed, ln_f, head
+        gpt = importlib.import_module("apex_tpu.models.gpt")
+        model = gpt.GPT(vocab_size=v, hidden_size=d, num_layers=0,
+                        num_heads=12, max_len=t, dtype=jnp.bfloat16)
+        params = jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0),
+                               jnp.zeros((1, 8), jnp.int32))["params"])
+        logits_of = lambda p, ids: model.apply({"params": p}, ids)
+    else:
+        scale = 8.0 if kind == "granite" else None
+        params = {"h": sds((b, t, d)), "w": sds((v, d))}
+        logits_of = lambda p, ids: hybrid.head_logits(p["h"], p["w"], scale)
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), params)
+
+    def mean_loss(p, ids):
+        losses = xe.softmax_cross_entropy_loss(
+            logits_of(p, ids).reshape(-1, v), ids.reshape(-1))
+        return jnp.mean(losses) * 1024.0
+
+    compiled = jax.jit(jax.value_and_grad(mean_loss)).lower(
+        params, ids).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert "output_to_operand_aliasing={{1}: (0, {})}" in calls[0]
+    entry = text[text.index("ENTRY"):]
+    wide = re.compile(r"= \(?(f32|bf16)\[(%d,%d|%d,%d,%d)\]"
+                      % (b * t, v, b, t, v))
+    written = [line.split(" = ")[0].strip() for line in entry.splitlines()
+               if wide.search(line) and " fusion(" in line]
+    assert len(written) == 1, written        # the logits' product alone
+    logits_bytes = 4 * b * t * v
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * logits_bytes
+
+
+@pytest.mark.parametrize("rows,columns", [(8192, 50257), (16384, 16384)])
+def test_the_loss_kernel_under_differentiation_compiles_under_mosaic(
+        one_chip, rows, columns):
+    """The forward kernel that also writes ``r``, alone and with label
+    smoothing on (more live ``[R, H]`` intermediates than the cells'
+    smoothing of 0, which the head cases above compile), inside the 16 MB
+    of scoped VMEM at the GPT cells' and the LFM2 and Nemotron cells'
+    shapes."""
+    xe = importlib.import_module("apex_tpu.contrib.xentropy")
+    logits = jax.ShapeDtypeStruct((rows, columns), jnp.float32,
+                                  sharding=one_chip)
+    labels = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda l, y: xe._fwd_grad_pallas(l, y, 0.1),
+        donate_argnums=0).lower(logits, labels).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1

@@ -92,6 +92,40 @@ def test_automatic_dispatch_reaches_the_kernel(name, monkeypatch):
     assert "pallas_call" in traced()
 
 
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, inner jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
+
+
+def test_the_loss_under_differentiation_is_one_kernel_in_place(monkeypatch):
+    """ISSUE 36, at the GPT cells' ``[8192, 50257]``: the primal loss is one
+    Mosaic call, value and gradient together are one too (no backward
+    kernel), and that one writes ``softmax - target`` over its logits."""
+    _as_if_on_tpu(monkeypatch)
+    fn, args = _xentropy()
+    primal = _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert len(primal) == 1
+    assert not primal[0].params["input_output_aliases"]
+
+    def mean_loss(logits, labels):
+        return jnp.mean(fn(logits, labels))
+
+    both = _pallas_calls(
+        jax.make_jaxpr(jax.value_and_grad(mean_loss))(*args).jaxpr)
+    assert len(both) == 1
+    (call,) = both
+    assert tuple(call.params["input_output_aliases"]) == ((0, 1),)
+    logits, r = call.invars[0].aval, call.outvars[1].aval
+    assert (logits.shape, logits.dtype) == (r.shape, r.dtype) \
+        == ((TOKENS, VOCAB), jnp.float32)
+
+
 def _chip_smoke():
     """``chip_smoke.py`` of this checkout as a module (its import touches
     neither JAX nor the chip)."""

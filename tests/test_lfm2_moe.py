@@ -341,8 +341,11 @@ def test_the_checkpoints_keep_inputs_and_the_route():
             for shape in of_pairs] == [20, 4, 4]
     assert any(moe.ROUTED in why for _, why in routed)
     rest = [shape for shape in shapes if shape not in of_pairs]
-    # [batch, tokens, ..] of the layers and of the loss, the last norm's scale
-    assert all(len(shape) >= 3 for shape in rest), rest
+    # [batch, tokens, ..] of the layers and of the loss, the last norm's
+    # scale; [batch * tokens, ..] of the head, which multiplies over
+    # flattened tokens
+    assert all(len(shape) >= 3 or shape[0] == BATCH * SEQ
+               for shape in rest), rest
     assert rest.count((BATCH, SEQ, 64)) >= 5            # the layers' inputs
 
 

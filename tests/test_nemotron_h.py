@@ -339,8 +339,11 @@ def test_the_checkpoints_keep_inputs_the_routed_result_and_the_route():
     assert any(moe.ROUTED in why for _, why in routed)
     rest = [shape for shape in shapes
             if shape not in (pairs, (BATCH * SEQ * 4,), (4,))]
-    # [batch, tokens, ..] of the layers and of the loss, the last norm's scale
-    assert all(len(shape) >= 3 for shape in rest), rest
+    # [batch, tokens, ..] of the layers and of the loss, the last norm's
+    # scale; [batch * tokens, ..] of the head, which multiplies over
+    # flattened tokens
+    assert all(len(shape) >= 3 or shape[0] == BATCH * SEQ
+               for shape in rest), rest
     assert rest.count((BATCH, SEQ, 32)) == 5            # MIXED
     assert rest.count((BATCH, SEQ, 64)) >= 11           # the layers' inputs
 

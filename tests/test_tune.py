@@ -458,6 +458,17 @@ def test_xentropy_tuned_rows_helper(tune_cache):
     assert xe._row_block(32, 128, 4096) <= 512
 
 
+def test_xentropy_version_2_leaves_version_1_entries_unread(tune_cache):
+    """ISSUE 36: the kernel under differentiation writes ``[R, H]`` blocks
+    as well as reading them, so a ``row_block`` tuned for the old pair
+    (version 1) is not consulted."""
+    from apex_tpu.contrib import xentropy as xe
+    assert xe.TUNE_VERSION == registry.get_spec("xentropy").version == 2
+    store.put("xentropy", 1, xe.tune_bucket(32, 128), {"row_block": 64},
+              path=tune_cache)
+    assert xe._tuned_rows(32, 128) is None
+
+
 # -- telemetry ----------------------------------------------------------------
 
 def test_tune_events_and_tuned_kernel_pct_gauge(tune_cache, tmp_path):
