@@ -8,3 +8,4 @@ from .gpt import GPT, gpt2_small, gpt_tiny, init_cache      # noqa: F401
 from .granite_hybrid import GraniteHybrid, granite_hybrid_tiny  # noqa: F401
 from .lfm2_moe import Lfm2Moe, lfm2_moe_tiny                # noqa: F401
 from .nemotron_h import NemotronH, nemotron_h_tiny            # noqa: F401
+from .smallthinker import SmallThinker, smallthinker_tiny          # noqa: F401

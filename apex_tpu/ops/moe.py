@@ -1,14 +1,25 @@
 """A routed-expert (mixture of experts) layer that is told which experts it
-holds: sigmoid router, top-k with a selection bias, no capacity, no drops.
+holds: a sigmoid or a softmax router, top-k, no capacity, no drops.
 
 The layer routes over **all** ``E`` experts and computes the part of the
 result that the ``G`` experts it holds give (experts ``offset .. offset + G``
-of ``E``)::
+of ``E``).  With the sigmoid router (the default)::
 
-    s   = sigmoid(W_g u)                        # float32, [E]
+    s   = sigmoid(W_g r)                        # float32, [E]
     sel = top_k(s + b)                          # b: a selection bias, not trained
     w   = s[sel] / (sum s[sel] + 1e-6) * scale  # from the unbiased scores
-    out = sum_{e in sel, e held here} w_e W2_e (silu(W1_e u) * W3_e u)
+    out = sum_{e in sel, e held here} w_e W2_e (act(W1_e u) * W3_e u)
+
+and with the softmax router (``score="softmax"``, no bias)::
+
+    l   = W_g r                                 # float32 logits, [E]
+    sel = top_k(l);  w = softmax(l[sel]) * scale
+
+which is the softmax over all ``E`` renormalised over the ``k`` selected,
+computed from the ``k`` logits alone.  ``act`` is SiLU (SwiGLU, the default)
+or ReLU (ReGLU).  ``r`` is what the router reads, ``u`` what the experts
+read: the same array unless the caller gives the router its own
+(``router_in``).
 
 With ``G == E`` that is the whole layer; with ``G < E`` it is one chip's
 share under expert parallelism, and the shares of all the chips add up to the
@@ -33,7 +44,7 @@ over such an array (``_walk_rows``) runs over blocks of ``_ROW_BLOCK`` rows
 under a loop whose trip count is the rows held this call, rounded up to a
 block, and leaves the blocks past them as they are (not zeros: every caller
 masks the tail, as it masks the kernels' tail): the gather of the sorted
-rows, ``silu(gate) * up`` and its backward, the backward of the weighted sum
+rows, ``act(gate) * up`` and its backward, the backward of the weighted sum
 (the gather of ``dy`` by the sort, its product with the weights, the row
 dots) and the sum of the rows' two gradients through ``w1`` and ``w3``.
 Each is a custom VJP, since such a loop has no reverse-mode rule; the
@@ -47,7 +58,8 @@ on the way back and in the gradient to ``u``, the scalars gathered by
 Gradients flow through the gathers, the grouped products (input and weight
 gradients by group, the kernels' own backward rules) and the weights ``w``
 into the router; none through the selection, and none to the bias, which is
-the caller's state.
+the caller's state.  With ``router_in`` given the router's gradient goes to
+it and the rows' to ``x``.
 
 **Routing once a step.**  A caller that wraps the layer in a checkpoint saves
 its input only, and its backward then routes a second time: scores, top-k and
@@ -55,14 +67,14 @@ sort, none of which carries a gradient.  Everything the backward reads of the
 route carries the name ``ROUTED`` (``jax.ad_checkpoint.checkpoint_name``), so
 a checkpoint whose policy is ``save_only_these_names(ROUTED)`` keeps it and
 recomputes the layer from after the route: the selection and the selected
-scores, which is all ``route``'s own backward rule reads of the ``[N, E]``
-score matrix (autodiff would want all of it), the weights with the pairs
-held elsewhere zeroed, which pairs are held, the sort's permutation (and its
-inverse in ``moe_layer``) and the rows of each expert held.  That is ``N k``
-values six or seven times over and ``G`` counts: 6.1 MB a layer at 16,384
-tokens and 22 a token, 1.4 MB at 4 a token, against the 32 MB of one
-``[16384, 512]`` score matrix.  Under any other checkpoint, or none, the
-names do nothing.
+scores (logits, under the softmax), which is all ``route``'s own backward
+rule reads of the ``[N, E]`` score matrix (autodiff would want all of it),
+the weights with the pairs held elsewhere zeroed, which pairs are held, the
+sort's permutation (and its inverse in ``moe_layer``) and the rows of each
+expert held.  That is ``N k`` values six or seven times over and ``G``
+counts: 6.1 MB a layer at 16,384 tokens and 22 a token, 1.4 MB at 4 a token,
+against the 32 MB of one ``[16384, 512]`` score matrix.  Under any other
+checkpoint, or none, the names do nothing.
 
 Named scopes (metadata, like ``training.PHASE_SCOPES``): ``apex.moe.route``
 (scores, top-k, weights, sort, counts), ``apex.moe.experts`` (gather, grouped
@@ -94,8 +106,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..normalization.fused_layer_norm import _use_pallas
 
-__all__ = ["MOE_SCOPES", "LATENT_SCOPES", "ROUTED", "route", "moe_layer",
-           "latent_moe_layer"]
+__all__ = ["MOE_SCOPES", "LATENT_SCOPES", "ROUTED", "SCORES", "ACTIVATIONS",
+           "route", "moe_layer", "latent_moe_layer"]
 
 #: rows a tile of the grouped-matmul kernel: a group's edge inside a tile
 #: costs the tile twice, so smaller tiles lose less to uneven groups
@@ -142,6 +154,26 @@ def _chosen(sel, experts):
     return sel[..., None] == jnp.arange(experts, dtype=sel.dtype)
 
 
+#: the routers ``route`` has (its ``score``)
+SCORES = ("sigmoid", "softmax")
+
+
+def _top(values, ranked, top_k):
+    """``(sel, values[sel], mask)``: the top ``top_k`` of ``ranked`` a
+    token and the selected ``values``, both kept under ``ROUTED``, and
+    ``sel == expert`` as ``[N, k, E]``.  The
+    values are read through a mask: the same numbers (the other terms of a
+    sum are zeros) as one pass of compares, and its transpose another, where
+    a gather's is a scatter-add of N k scalars into [N, E] (on the v5e 4 ns
+    an element and twice that: 1.55 and 3.1 ms at 16,384 tokens, 22 of 512;
+    ``PERF.md``, PR 33)."""
+    _, sel = jax.lax.top_k(ranked, top_k)
+    mask = _chosen(sel, values.shape[1])
+    picked = jnp.where(mask, values[:, None, :], 0).sum(-1)
+    # what the backward reads of the scores: k a token, not E
+    return _kept(sel), _kept(picked), mask
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _route(x, w_gate, bias, top_k, norm_topk_prob, scaling):
     return _route_fwd(x, w_gate, bias, top_k, norm_topk_prob, scaling)[0]
@@ -149,16 +181,7 @@ def _route(x, w_gate, bias, top_k, norm_topk_prob, scaling):
 
 def _route_fwd(x, w_gate, bias, top_k, norm_topk_prob, scaling):
     scores = jax.nn.sigmoid(_logits(x, w_gate))
-    _, sel = jax.lax.top_k(scores + bias, top_k)
-    mask = _chosen(sel, w_gate.shape[1])
-    # scores[sel] through a mask: the same numbers (the other terms of a sum
-    # are zeros) as one pass of compares, and its transpose another, where a
-    # gather's is a scatter-add of N k scalars into [N, E] (on the v5e 4 ns
-    # an element and twice that: 1.55 and 3.1 ms at 16,384 tokens, 22 of 512;
-    # ``PERF.md``, PR 33)
-    picked = jnp.where(mask, scores[:, None, :], 0).sum(-1)
-    # what the backward reads of the scores: k a token, not E
-    sel, picked = _kept(sel), _kept(picked)
+    sel, picked, mask = _top(scores, scores + bias, top_k)
     return ((sel, _normalised(picked, norm_topk_prob, scaling),
              mask.sum((0, 1), dtype=jnp.int32)), (x, w_gate, sel, picked))
 
@@ -171,18 +194,53 @@ def _route_bwd(top_k, norm_topk_prob, scaling, res, cotangents):
     d_picked, = jax.vjp(lambda p: _normalised(p, norm_topk_prob, scaling),
                         picked)[1](cotangents[1])
     d_picked = d_picked * (picked * (1 - picked))       # the sigmoid's
+    return _to_router(x, w_gate, sel, d_picked) + (None,)
+
+
+def _to_router(x, w_gate, sel, d_picked):
+    """The gradients of the router's input and weight from those of the
+    selected logits: spread to ``[N, E]`` through the mask, then the two
+    transposes of the product."""
     d_logits = jnp.where(_chosen(sel, w_gate.shape[1]), d_picked[..., None],
                          0).sum(1)
     d_x, = jax.linear_transpose(lambda a: _logits(a, w_gate), x)(d_logits)
     d_w, = jax.linear_transpose(lambda w: _logits(x, w), w_gate)(d_logits)
-    return d_x, d_w, None
+    return d_x, d_w
 
 
 _route.defvjp(_route_fwd, _route_bwd)
 
 
+def _softmax_weights(picked, scaling):
+    return jax.nn.softmax(picked, axis=-1) * scaling
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _route_softmax(x, w_gate, top_k, scaling):
+    return _route_softmax_fwd(x, w_gate, top_k, scaling)[0]
+
+
+def _route_softmax_fwd(x, w_gate, top_k, scaling):
+    logits = _logits(x, w_gate)
+    sel, picked, mask = _top(logits, logits, top_k)
+    return ((sel, _softmax_weights(picked, scaling),
+             mask.sum((0, 1), dtype=jnp.int32)), (x, w_gate, sel, picked))
+
+
+def _route_softmax_bwd(top_k, scaling, res, cotangents):
+    """From the ``k`` selected logits a token: ``dl[sel] = w (dw - sum w
+    dw)`` (the softmax's own rule, by ``jax.vjp``), zero elsewhere."""
+    x, w_gate, sel, picked = res
+    d_picked, = jax.vjp(lambda p: _softmax_weights(p, scaling),
+                        picked)[1](cotangents[1])
+    return _to_router(x, w_gate, sel, d_picked)
+
+
+_route_softmax.defvjp(_route_softmax_fwd, _route_softmax_bwd)
+
+
 def route(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool = True,
-          scaling: float = 1.0):
+          scaling: float = 1.0, score: str = "sigmoid"):
     """``(sel, weights, counts)`` for tokens ``x``: ``[N, D]``.
 
     ``w_gate``: ``[D, E]`` and ``bias``: ``[E]``, float32.  The scores are
@@ -194,13 +252,28 @@ def route(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool = True,
     ``scaling``; ``counts``: ``[E]`` int32, the rows each expert was sent.
     The selection carries no gradient, and the weights' gradient is a rule
     of its own that keeps ``sel`` and the ``top_k`` selected scores a token
-    (under ``ROUTED``) where autodiff would keep all ``E``."""
-    if w_gate.dtype != jnp.float32 or bias.dtype != jnp.float32:
+    (under ``ROUTED``) where autodiff would keep all ``E``.
+
+    ``score="softmax"``: ``sel`` is the top ``top_k`` of the float32 logits
+    (the top of their softmax) and ``weights`` the softmax of the selected
+    logits times ``scaling`` (the softmax over all ``E`` renormalised over
+    the ``top_k``), its rule keeping the ``top_k`` logits a token.  No
+    selection bias (``bias`` is None) and the weights always normalised."""
+    if score not in SCORES:
+        raise ValueError(f"moe.route: score {score!r} is none of {SCORES}")
+    if score == "softmax" and (bias is not None or not norm_topk_prob):
+        raise ValueError(
+            "moe.route: the softmax router takes no selection bias and "
+            "normalises its weights over the top_k (norm_topk_prob)")
+    bias_dtype = None if bias is None else bias.dtype
+    if w_gate.dtype != jnp.float32 or bias_dtype not in (None, jnp.float32):
         raise TypeError(
             f"moe.route: the router's weight arrived as {w_gate.dtype} and "
-            f"the selection bias as {bias.dtype}; the scores are float32 "
+            f"the selection bias as {bias_dtype}; the scores are float32 "
             f"whatever the compute dtype (keep the router out of the amp "
             f"cast: models.lfm2_moe.keep_fp32)")
+    if score == "softmax":
+        return _route_softmax(x, w_gate, top_k, scaling)
     return _route(x, w_gate, bias, top_k, norm_topk_prob, scaling)
 
 
@@ -342,27 +415,33 @@ def _gate_up_bwd(res, g):
 _gate_up.defvjp(_gate_up_fwd, _gate_up_bwd)
 
 
-def _silu_gate(gate, up):
-    """``(silu(gate) * up,)`` in float32, rounded once: a tuple, as
+#: the expert's gate: SwiGLU's SiLU or ReGLU's ReLU
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _gated(gate, up, kind):
+    """``(act(gate) * up,)`` in float32, rounded once: a tuple, as
     ``_walk_rows`` takes its functions."""
-    return (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-            ).astype(gate.dtype),
+    act = ACTIVATIONS[kind](gate.astype(jnp.float32))
+    return (act * up.astype(jnp.float32)).astype(gate.dtype),
 
 
-@jax.custom_vjp
-def _activation(gate, up, n_rows):
-    """``silu(gate) * up`` in float32, rounded once, over the first ``n_rows``
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _activation(gate, up, n_rows, kind):
+    """``act(gate) * up`` in float32, rounded once, over the first ``n_rows``
     rows; backward over the same rows, from ``gate`` and ``up``."""
-    return _walk_rows(_silu_gate, n_rows, gate, up)[0]
+    return _walk_rows(functools.partial(_gated, kind=kind), n_rows, gate,
+                      up)[0]
 
 
-def _activation_fwd(gate, up, n_rows):
-    return _activation(gate, up, n_rows), (gate, up, n_rows)
+def _activation_fwd(gate, up, n_rows, kind):
+    return _activation(gate, up, n_rows, kind), (gate, up, n_rows)
 
 
-def _activation_bwd(res, d_act):
+def _activation_bwd(kind, res, d_act):
     gate, up, n_rows = res
-    block = lambda g, u, d: jax.vjp(_silu_gate, g, u)[1]((d,))
+    block = lambda g, u, d: jax.vjp(functools.partial(_gated, kind=kind),
+                                    g, u)[1]((d,))
     return _walk_rows(block, n_rows, gate, up, d_act, in_place=2) + (None,)
 
 
@@ -405,7 +484,7 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _route_and_sort(x, w_gate, bias, g, *, top_k, expert_offset,
-                    norm_topk_prob, scaling, whole=1):
+                    norm_topk_prob, scaling, whole=1, score="sigmoid"):
     """:func:`route`, then the (token, slot) pairs by expert held: ``(sel,
     weights, counts, held, order, group_sizes)`` with ``weights`` zero where
     a pair's expert is elsewhere, ``order`` the pairs by expert held, in
@@ -417,9 +496,12 @@ def _route_and_sort(x, w_gate, bias, g, *, top_k, expert_offset,
     if not 0 <= expert_offset <= e - g:
         raise ValueError(f"moe_layer: experts {expert_offset} .. "
                          f"{expert_offset + g} are not among the router's {e}")
+    # the default passes no ``score``: the controls under ``tests/benchmark``
+    # put routers of their own in ``route``'s place, by name
+    extra = {} if score == "sigmoid" else {"score": score}
     sel, weights, counts = route(x, w_gate, bias, top_k=top_k,
                                  norm_topk_prob=norm_topk_prob,
-                                 scaling=scaling)
+                                 scaling=scaling, **extra)
     local = sel - expert_offset
     held = _kept((local >= 0) & (local < g))
     order = jnp.argsort(jnp.where(held, local, g).reshape(-1), stable=True
@@ -433,26 +515,42 @@ def _route_and_sort(x, w_gate, bias, g, *, top_k, expert_offset,
 
 def moe_layer(x, w_gate, bias, w1, w3, w2, *, top_k: int,
               expert_offset: int = 0, norm_topk_prob: bool = True,
-              routed_scaling_factor: float = 1.0):
-    """The held experts' part of a routed-expert SwiGLU layer.
+              routed_scaling_factor: float = 1.0, score: str = "sigmoid",
+              activation: str = "silu", router_in=None):
+    """The held experts' part of a routed-expert gated layer (SwiGLU, or
+    ReGLU with ``activation="relu"``).
 
     ``x``: ``[..., D]`` in the compute dtype; ``w_gate``: ``[D, E]`` and
-    ``bias``: ``[E]``, float32; ``w1``, ``w3``: ``[G, D, F]`` and ``w2``:
-    ``[G, F, D]``, the experts ``expert_offset .. expert_offset + G`` of the
-    ``E`` the router knows.  Returns ``(y, counts, sel, rows_walked)``: ``y``
-    of ``x``'s shape and dtype, ``counts``: ``[E]`` int32 rows sent to each of
-    the ``E`` experts by these tokens, ``sel``: ``[N, top_k]`` the selection,
-    ``rows_walked``: int32, the rows of the ``N top_k`` the row passes went
-    over (the rows held, rounded up to a block; all of them where they are
-    within one block or not whole blocks)."""
+    ``bias``: ``[E]``, float32 (None under ``score="softmax"``: see
+    :func:`route`); ``w1``, ``w3``: ``[G, D, F]`` and ``w2``: ``[G, F, D]``,
+    the experts ``expert_offset .. expert_offset + G`` of the ``E`` the
+    router knows.  ``router_in``: ``[..., D]``, what the router reads where
+    it is not ``x`` (the same tokens; gradients flow to both).  Returns
+    ``(y, counts, sel, rows_walked)``: ``y`` of ``x``'s shape and dtype,
+    ``counts``: ``[E]`` int32 rows sent to each of the ``E`` experts by
+    these tokens, ``sel``: ``[N, top_k]`` the selection, ``rows_walked``:
+    int32, the rows of the ``N top_k`` the row passes went over (the rows
+    held, rounded up to a block; all of them where they are within one
+    block or not whole blocks)."""
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"moe_layer: activation {activation!r} is none of "
+                         f"{tuple(ACTIVATIONS)}")
     lead, d = x.shape[:-1], x.shape[-1]
     g = w1.shape[0]
     x = x.reshape(-1, d)
     n = x.shape[0]
+    if router_in is None:
+        reads = x
+    elif router_in.shape[:-1] != lead:
+        raise ValueError(f"moe_layer: the router reads {router_in.shape} and "
+                         f"the experts {lead + (d,)}: not the same tokens")
+    else:
+        reads = router_in.reshape(n, router_in.shape[-1])
     with jax.named_scope(_ROUTE):
         sel, weights, counts, held, order, group_sizes = _route_and_sort(
-            x, w_gate, bias, g, top_k=top_k, expert_offset=expert_offset,
-            norm_topk_prob=norm_topk_prob, scaling=routed_scaling_factor)
+            reads, w_gate, bias, g, top_k=top_k, expert_offset=expert_offset,
+            norm_topk_prob=norm_topk_prob, scaling=routed_scaling_factor,
+            score=score)
         pos = _kept(jnp.zeros_like(order).at[order].set(
             jnp.arange(n * top_k, dtype=jnp.int32), unique_indices=True
         ).reshape(n, top_k))
@@ -460,7 +558,8 @@ def moe_layer(x, w_gate, bias, w1, w3, w2, *, top_k: int,
     with jax.named_scope(_EXPERTS):
         rows = _sorted_rows(x, order, pos, held, n_rows)
         gate, up = _gate_up(rows, w1, w3, group_sizes, n_rows)
-        out = _grouped_matmul(_activation(gate, up, n_rows), w2, group_sizes)
+        out = _grouped_matmul(_activation(gate, up, n_rows, activation), w2,
+                              group_sizes)
     with jax.named_scope(_COMBINE):
         y = _combine(out, weights, order, pos, held, n_rows)
     return (y.reshape(lead + (d,)), counts, sel,

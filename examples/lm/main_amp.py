@@ -18,7 +18,11 @@ model state), and ``--model nemotron-h`` the Nemotron-H block
 (``apex_tpu.models.NemotronH``: layers that are each a Mamba-2 mixer, a GQA
 attention without positions or a latent expert layer alone, by the published
 string of letters; 16 ``relu ** 2`` experts in a latent space of hidden / 2,
-4 a token, beside one shared expert).
+4 a token, beside one shared expert), and ``--model smallthinker`` the
+SmallThinker block (``apex_tpu.models.SmallThinker``: a global attention
+layer without positions, then three sliding-window layers with rotary
+positions, each followed by 16 ReGLU experts, 4 a token, whose softmax router
+reads the attention's normed input).
 
 The loop runs on :class:`apex_tpu.runtime.StepPipeline`:
 ``--steps-per-call K`` chains K steps into ONE compiled program
@@ -33,6 +37,7 @@ never blocks on a scalar.
     python main_amp.py --synthetic --steps 5 --model granite-hybrid --layers 10
     python main_amp.py --synthetic --steps 5 --model lfm2-moe --layers 5
     python main_amp.py --synthetic --steps 5 --model nemotron-h --layers 11
+    python main_amp.py --synthetic --steps 5 --model smallthinker --layers 4
 """
 
 import os as _os
@@ -54,7 +59,8 @@ import numpy as np
 from apex_tpu import runtime, training
 from apex_tpu.contrib.xentropy import softmax_cross_entropy_loss
 from apex_tpu.models import (GPT, GraniteHybrid, Lfm2Moe, NemotronH,
-                             granite_hybrid, lfm2_moe, nemotron_h)
+                             SmallThinker, granite_hybrid, lfm2_moe,
+                             nemotron_h, smallthinker)
 from apex_tpu.training import make_train_step
 
 
@@ -63,7 +69,7 @@ def parse():
     p.add_argument("--synthetic", action="store_true")
     p.add_argument("--model", type=str, default="gpt",
                    choices=["gpt", "granite-hybrid", "lfm2-moe",
-                            "nemotron-h"],
+                            "nemotron-h", "smallthinker"],
                    help="gpt: GPT-2 blocks; granite-hybrid: Mamba-2 and GQA "
                         "attention layers by the published period (five "
                         "mamba, attention, four mamba), heads of "
@@ -74,7 +80,11 @@ def parse():
                         "hidden, 4 a token; nemotron-h: the first --layers "
                         "letters of the published pattern (MEMEMEM*EME...: a "
                         "Mamba-2 mixer, a latent expert layer or an attention "
-                        "alone), 16 latent experts, 4 a token, one shared")
+                        "alone), 16 latent experts, 4 a token, one shared; "
+                        "smallthinker: --layers of the published layout (a "
+                        "global layer without positions, three window layers "
+                        "of seq/4 keys with RoPE), 16 ReGLU experts of width "
+                        "hidden, 4 a token, the router before attention")
     p.add_argument("--steps", type=int, default=10)
     p.add_argument("-b", "--batch-size", type=int, default=8)
     p.add_argument("--seq-len", type=int, default=256)
@@ -221,8 +231,9 @@ def _train(args):
                          "(GQA is shard-local; ring/ulysses paths are MHA)")
     hybrid, moe = args.model == "granite-hybrid", args.model == "lfm2-moe"
     latent = args.model == "nemotron-h"
-    if (hybrid or moe or latent) and (sp > 1 or args.window is not None
-                                      or args.attention != "flash"):
+    windowed = args.model == "smallthinker"
+    if (hybrid or moe or latent or windowed) and (
+            sp > 1 or args.window is not None or args.attention != "flash"):
         raise SystemExit(f"--model {args.model} runs unsharded with "
                          f"--attention flash and no --window")
     if hybrid:
@@ -255,6 +266,15 @@ def _train(args):
             latent_size=args.hidden // 2, moe_dim=args.hidden,
             shared_dim=2 * args.hidden, num_experts=16, experts_held=16,
             top_k=4, dtype=jnp.bfloat16)
+    elif windowed:
+        layout = smallthinker.SLIDING_WINDOW_LAYOUT[:args.layers]
+        model = SmallThinker(
+            vocab_size=args.vocab, hidden_size=args.hidden,
+            sliding_window_layout=layout, rope_layout=layout,
+            sliding_window_size=max(1, args.seq_len // 4),
+            num_heads=args.heads, num_kv_heads=args.kv_heads or args.heads,
+            head_dim=args.hidden // args.heads, moe_dim=args.hidden,
+            num_experts=16, experts_held=16, top_k=4, dtype=jnp.bfloat16)
     else:
         model = GPT(vocab_size=args.vocab, hidden_size=args.hidden,
                     num_layers=args.layers, num_heads=args.heads,
@@ -318,8 +338,9 @@ def _train(args):
     # and D; the expert layers' router
     keep_fp32 = (granite_hybrid.keep_fp32 if hybrid
                  else lfm2_moe.keep_fp32 if moe
-                 else nemotron_h.keep_fp32 if latent else None)
-    moe = moe or latent         # both carry their expert layers' state
+                 else nemotron_h.keep_fp32 if latent
+                 else smallthinker.keep_fp32 if windowed else None)
+    moe = moe or latent or windowed     # carry their expert layers' state
     init_fn, step_fn = make_train_step(
         loss_fn_with_state if moe else loss_fn,
         training.adam(args.lr, weight_decay=args.weight_decay),

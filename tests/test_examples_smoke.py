@@ -242,6 +242,23 @@ def test_lm_example_nemotron_h(monkeypatch, capsys):
             "--synthetic", "--model", "nemotron-h", "--window", "8"])
 
 
+def test_lm_example_smallthinker(monkeypatch, capsys):
+    """The same loop over the SmallThinker block: a global layer without
+    positions, then three window layers with rotary positions, each with
+    ReGLU experts whose softmax router reads the attention's normed input;
+    the load counts ride through ``make_train_step`` as model state."""
+    _run_example(monkeypatch, "examples/lm/main_amp.py", [
+        "--synthetic", "--steps", "2", "-b", "2", "--seq-len", "33",
+        "--hidden", "32", "--layers", "4", "--heads", "4", "--kv-heads", "2",
+        "--vocab", "128", "--opt-level", "O2", "--loss-scale", "dynamic",
+        "--model", "smallthinker"])
+    out = capsys.readouterr().out
+    assert "SmallThinker 4L/32H" in out and "loss_scale 65536" in out
+    with pytest.raises(SystemExit, match="smallthinker runs unsharded"):
+        _run_example(monkeypatch, "examples/lm/main_amp.py", [
+            "--synthetic", "--model", "smallthinker", "--window", "8"])
+
+
 def test_lm_example_sequence_parallel(monkeypatch):
     """GPT over a 2-way sp mesh with ring attention."""
     _run_example(monkeypatch, "examples/lm/main_amp.py", [

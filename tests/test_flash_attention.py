@@ -763,3 +763,38 @@ def test_flash_backward_bounded_window_across_kv_chunks(
     g = _window_grads_match_oracle(q, k, v, window, kb=kb)
     # the band reaches every chunk: no dk rows left at their zero fill
     assert float(jnp.abs(g[1][:, -128:]).max()) > 0
+
+
+def test_flash_window_gqa_seven_to_one_matches_band_oracle():
+    """SmallThinker's window layer in small: seven query heads to each KV
+    head (14 to 2) over the bounded grid of a 200-key window (span 3 of 4
+    blocks), against the band oracle with the KV heads repeated, forward
+    and the three gradients."""
+    from apex_tpu.ops.flash_attention import NEG_INF
+
+    B, T, H, H_KV, D, W = 1, 512, 14, 2, 32, 200
+    q = _rand((B, T, H, D), 0)
+    k, v = _rand((B, T, H_KV, D), 1), _rand((B, T, H_KV, D), 2)
+    band = jnp.where(
+        (jnp.arange(T)[:, None] - jnp.arange(T)[None, :]) < W, 0.0, NEG_INF)
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=W,
+                               sm_scale=D ** -0.5, block_q=128, block_k=128,
+                               interpret=True)
+
+    def ref(q, k, v):
+        rep = lambda a: jnp.repeat(a, H // H_KV, axis=2)
+        return dot_product_attention(q, rep(k), rep(v), causal=True,
+                                     bias=band[None, None])
+
+    np.testing.assert_allclose(np.asarray(f(q, k, v)),
+                               np.asarray(ref(q, k, v)), atol=2e-5, rtol=2e-5)
+    g1 = jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a))), argnums=(0, 1, 2))(
+        q, k, v)
+    g2 = jax.grad(lambda *a: jnp.sum(jnp.sin(ref(*a))), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b in zip(g1, g2):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-4, rtol=5e-4)
