@@ -277,6 +277,46 @@ def route(x, w_gate, bias, *, top_k: int, norm_topk_prob: bool = True,
     return _route(x, w_gate, bias, top_k, norm_topk_prob, scaling)
 
 
+#: what a grouped-matmul kernel's blocks may take of the 16 MiB of scoped
+#: VMEM that Mosaic grants by default: the rest is for what the kernel body
+#: holds beside them (up to 0.94 MiB more at some widths, by rehearsal)
+_TILE_VMEM = 15 * 2**20
+
+
+def _block_bytes(tk, tn):
+    """The VMEM of one kernel's blocks at tiles ``(_ROW_TILE, tk, tn)``: the
+    bf16 blocks of rows, weights and result, two of each, and the float32
+    accumulator, which is ``tgmm``'s ``(tk, tn)`` result (``gmm``'s is
+    ``(_ROW_TILE, tn)``, so this bounds both).  Where Mosaic refuses a
+    ``tgmm`` for its VMEM, the size it reports is this at most widths."""
+    return (4 * (_ROW_TILE * tk + tk * tn + _ROW_TILE * tn)
+            + 4 * max(_ROW_TILE, tk) * tn)
+
+
+def _widths(size, fallback):
+    """The multiples of 128 that divide ``size``, widest first; the fixed
+    tile of old where none does."""
+    return [t for t in range(size - size % 128, 0, -128)
+            if size % t == 0] or [fallback]
+
+
+def _tiling(m, k, n):
+    """The tiles ``(tm, tk, tn)`` of one grouped-matmul kernel, from its own
+    shape (``gmm`` and ``tgmm`` call it with theirs): 256 rows; the whole
+    contraction where the blocks fit, else its widest tile that divides it;
+    the widest tile of the result's width that divides it and fits beside.
+    A tile that divides computes no padding, where a tile that does not is
+    multiplied in full and masked; and with the contraction in one tile
+    ``gmm`` keeps a group's weight block in VMEM from one row tile to the
+    next, where over several it reads a weight block a step.  On a v5e
+    (``PERF.md``, the grouped-matmul sweep) ``(256, 2560, 384)`` runs
+    SmallThinker's gate product in 0.73 ms where ``(256, 1280, 768)`` takes
+    0.95 and the fixed ``(256, 2048, 512)`` of old 1.75."""
+    del m
+    return next((_ROW_TILE, tk, tn) for tk in _widths(k, 2048)
+                for tn in _widths(n, 512) if _block_bytes(tk, tn) <= _TILE_VMEM)
+
+
 def _grouped_matmul(rows, weights, group_sizes):
     """``rows[r] @ weights[g]`` for the rows ``r`` of group ``g``; the rows are
     sorted by group and ``group_sizes`` says where each group ends.  Rows past
@@ -287,11 +327,11 @@ def _grouped_matmul(rows, weights, group_sizes):
     compiler lowers to a kernel of its own with 512-row tiles.  At 16 groups
     of about 1,024 uneven rows of 2,048 x 1,536 the first is ahead by a fifth
     (``PERF.md``, PR 31), and it keeps the caller's scopes in its metadata,
-    which the compiler's kernel does not."""
+    which the compiler's kernel does not.  Each of megablox's three kernels
+    takes its tiles from its own shape (:func:`_tiling`)."""
     if _use_pallas() and rows.shape[0] % _ROW_TILE == 0:
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-        return megablox.gmm(rows, weights, group_sizes, rows.dtype,
-                            (_ROW_TILE, 2048, 512))
+        return megablox.gmm(rows, weights, group_sizes, rows.dtype, _tiling)
     return jax.lax.ragged_dot(rows, weights, group_sizes)
 
 
@@ -531,7 +571,16 @@ def moe_layer(x, w_gate, bias, w1, w3, w2, *, top_k: int,
     these tokens, ``sel``: ``[N, top_k]`` the selection, ``rows_walked``:
     int32, the rows of the ``N top_k`` the row passes went over (the rows
     held, rounded up to a block; all of them where they are within one
-    block or not whole blocks)."""
+    block or not whole blocks).
+
+    On the TPU the grouped products are megablox's kernels (``gmm``, and
+    ``tgmm`` for the weights' gradients), each with tiles from its own
+    shape: 256 rows; the whole contraction where the blocks fit in 15 MiB
+    of VMEM, else its widest multiple of 128 that divides it; of the
+    result's width the widest multiple of 128 that divides it and fits
+    beside.  At the widths of SmallThinker, LFM2 and Nemotron no tile is
+    padded, where the one fixed tiling of old computed a contraction of
+    2,560 as 4,096."""
     if activation not in ACTIVATIONS:
         raise ValueError(f"moe_layer: activation {activation!r} is none of "
                          f"{tuple(ACTIVATIONS)}")
@@ -680,7 +729,9 @@ def latent_moe_layer(x, latent, w_gate, bias, w1, w2, *, top_k: int,
     ``y`` of ``latent``'s shape and dtype, ``counts``: ``[E]`` int32 rows sent
     to each of the ``E`` experts by these tokens, ``sel``: ``[N, top_k]`` the
     selection, and the rows of the ``N top_k`` that are held here and that
-    the waves went over (the rows held, rounded up to a wave), both int32."""
+    the waves went over (the rows held, rounded up to a wave), both int32.
+    The grouped products take their tiles from the shape as
+    :func:`moe_layer`'s do."""
     lead, n_lat = latent.shape[:-1], latent.shape[-1]
     if x.shape[:-1] != lead:
         raise ValueError(f"latent_moe_layer: the router reads {x.shape} and "

@@ -77,16 +77,31 @@ def test_flash_bwd_compiles_under_mosaic(one_chip, case):
         'custom_call_target="tpu_custom_call"') == calls
 
 
+# the expert cells' grouped products: (sorted rows a layer or a wave, experts
+# held, the product's contraction and width)
+GROUPED = {
+    "lfm2_24b_a2b_o2.in": (65536, 16, 2048, 1536),
+    "lfm2_24b_a2b_o2.out": (65536, 16, 1536, 2048),
+    "smallthinker_21b_a3b_o2.in": (98304, 16, 2560, 768),
+    "smallthinker_21b_a3b_o2.out": (98304, 16, 768, 2560),
+    "nemotron3_super_120b_o2.in": (16384, 8, 1024, 2688),
+    "nemotron3_super_120b_o2.out": (16384, 8, 2688, 1024),
+}
+
+
+@pytest.mark.parametrize("product", list(GROUPED))
 def test_grouped_matmul_compiles_under_mosaic_at_the_cells_widths(
-        one_chip, monkeypatch):
-    """The expert layer's grouped products at ``lfm2_24b_a2b_o2.b4_seq4096``'s
-    sizes (65,536 sorted rows, 16 experts of 2,048 x 1,536), forward and both
-    gradients: on the TPU ``ops.moe`` takes the grouped-matmul kernel of
-    ``jax.experimental`` (this file lives with the flash cases because every
-    rehearsal compile has to: one process may describe the topology)."""
+        one_chip, monkeypatch, product):
+    """The expert layers' grouped products at the three expert cells' sizes,
+    into the expert width and out of it, forward and both gradients: on the
+    TPU ``ops.moe`` takes the grouped-matmul kernel of ``jax.experimental``,
+    each of its three kernels with the tiles ``_tiling`` gives its shape, and
+    a tile whose blocks overflow the scoped VMEM is refused here (this file
+    lives with the flash cases because every rehearsal compile has to: one
+    process may describe the topology)."""
     moe = importlib.import_module("apex_tpu.ops.moe")
     monkeypatch.setattr(moe, "_use_pallas", lambda: True)
-    rows, d, f, g = 65536, 2048, 1536, 16
+    rows, g, d, f = GROUPED[product]
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

@@ -10,6 +10,7 @@ walked path against the straight one bit for bit, at six loads, with the
 blocks it does not reach poisoned, and the rows it says it walked."""
 
 import collections
+import math
 
 import jax
 import jax.numpy as jnp
@@ -813,12 +814,61 @@ def _lowered_defaults(layer):
 def test_the_defaults_lower_to_the_program_they_lowered_to_before(
         layer, monkeypatch):
     """The softmax router, ReGLU and ``router_in`` left the defaults'
-    program as it was, walked rows and all: the LFM2 and Nemotron cells
-    compile what they compiled (the benchmark's controls patch
-    ``moe.route`` by name, which the default still calls without
-    ``score``)."""
+    program as it was, walked rows and all.  What is hashed is the lowering
+    off the TPU, where the grouped products are ``lax.ragged_dot``: it says
+    nothing of the kernels' tiles, which ``_tiling`` chooses on the TPU (the
+    benchmark's controls patch ``moe.route`` by name, which the default
+    still calls without ``score``)."""
     monkeypatch.setattr(moe, "_ROW_BLOCK", 32)      # 128 rows: walked
     assert _lowered_defaults(layer) == _DEFAULTS_LOWERED[layer]
     monkeypatch.setattr(moe, "route", plain_route)
     f, args, cot = _layer_under_test("moe_layer")
     jax.grad(lambda *a: jnp.sum(f(*a) * cot))(*args)
+
+
+#: the expert cells' widths: (hidden or latent, expert) and the sorted rows a
+#: layer's (or a wave's) kernels see
+CELL_WIDTHS = {"lfm2_24b_a2b_o2": (2048, 1536, 65536),
+               "smallthinker_21b_a3b_o2": (2560, 768, 98304),
+               "nemotron3_super_120b_o2": (1024, 2688, 16384)}
+
+
+@pytest.mark.parametrize("kernel", ["forward", "dx", "tgmm"])
+@pytest.mark.parametrize("product", ["in", "out"])
+@pytest.mark.parametrize("cell", list(CELL_WIDTHS))
+def test_tiles_from_the_shape_compute_no_padding(cell, product, kernel):
+    """Each of the three kernels of both of a cell's products (into the
+    expert width and out of it) gets tiles that are multiples of 128, divide
+    its contraction ``k`` and its width ``n``, and fit the VMEM the rule
+    allows: the MXU work of its tiles is the useful work, exactly."""
+    d, f, rows = CELL_WIDTHS[cell]
+    k, n = (d, f) if product == "in" else (f, d)
+    if kernel == "dx":              # gmm against the transposed weights
+        k, n = n, k
+    tm, tk, tn = moe._tiling(rows, k, n)
+    assert tm == moe._ROW_TILE
+    assert tk % 128 == 0 and tn % 128 == 0
+    assert k % tk == 0 and n % tn == 0
+    tiled = math.ceil(k / tk) * tk * math.ceil(n / tn) * tn
+    assert tiled / (k * n) == 1.0
+    assert moe._block_bytes(tk, tn) <= moe._TILE_VMEM
+
+
+@pytest.mark.parametrize("k, n, tiles", [
+    (2560, 768, (256, 2560, 384)), (768, 2560, (256, 768, 1280)),
+    (2048, 1536, (256, 2048, 768)), (1536, 2048, (256, 1536, 1024)),
+    (1024, 2688, (256, 1024, 896)), (2688, 1024, (256, 2688, 512)),
+    # the whole contraction fits with no width: its widest tile that does
+    (8192, 1024, (256, 4096, 256)),
+    # no multiple of 128 divides either: the fixed tiles of old
+    (96, 100, (256, 2048, 512))])
+def test_the_tiling_takes_the_whole_contraction_then_the_widest_width(
+        k, n, tiles):
+    """The tiles a sweep on a v5e chose at the cells' widths (``PERF.md``):
+    the contraction in one tile where the blocks fit, and of the width the
+    widest tile that divides it and fits beside; the rows do not enter."""
+    for rows in (256, 65536, 98304):
+        assert moe._tiling(rows, k, n) == tiles
+    tk, tn = tiles[1:]
+    wider = [t for t in moe._widths(n, 512) if t > tn]
+    assert all(moe._block_bytes(tk, t) > moe._TILE_VMEM for t in wider)
