@@ -27,7 +27,9 @@ Design:
   is K-major, so no operand ever needs a VMEM relayout/transpose.
   ``delta = rowsum(do * o)`` is a cheap jnp reduction fused by XLA.  Keys
   beyond the resident-VMEM budget run the same kernel per KV chunk.
-* causal masking skips fully-masked KV blocks via ``pl.when`` predication,
+* causal masking skips fully-masked KV blocks via ``pl.when`` predication
+  and masks only the tiles the diagonal or the window's edge crosses
+  (``_tile_class``: an interior tile runs a body with no mask),
   and sliding-window local attention goes further with a BOUNDED grid:
   only ``ceil(window/bk)+1`` KV blocks per Q block are even visited
   (virtual-negative block ids clamp in the index maps and predicate off),
@@ -158,17 +160,44 @@ def _causal_block_mask(qi, ki, bq, bk, q_off=0, k_off=0, window=None):
     return mask
 
 
-def _block_live(qi, ki, bq, bk, q_off, k_off, window):
-    """Whether this (qi, ki) block intersects the causal/window band —
-    the block-skip predicate shared by all three kernels.  Blocks past the
-    diagonal AND blocks older than the window are skipped entirely, so
-    sliding-window attention costs O(T * window), not O(T^2)."""
-    run = q_off + qi * bq + bq - 1 >= k_off + ki * bk        # causal skip
+def _tile_class(qi, ki, bq, bk, q_off=0, k_off=0, window=None):
+    """``(live, edge)`` of the ``(qi, ki)`` tile on global positions — the
+    one predicate behind the three kernels' block skip and masking, and
+    behind ``band_tiles``.  ``live``: some pair of the tile is visible
+    (its newest query reaches its oldest key and, with a window, its
+    newest key is inside its oldest query's window); tiles past the
+    diagonal and older than the window are skipped, so sliding-window
+    attention costs O(T * window), not O(T^2).  ``edge``: live and not
+    every pair visible, the causal diagonal or the window's far edge
+    crosses it; a live tile that is not an edge tile is interior, its
+    newest key no later than its oldest query and its oldest key inside
+    its newest query's window, and needs no mask.  Works on traced
+    program ids inside the kernels and on Python ints outside them
+    (``&`` and ``|`` of Python bools stay bools)."""
+    q0, k0 = q_off + qi * bq, k_off + ki * bk
+    q1, k1 = q0 + bq - 1, k0 + bk - 1
+    live = q1 >= k0
+    cut = k1 > q0
     if window is not None:
-        # newest key in block still inside the oldest query's window?
-        run = jnp.logical_and(
-            run, (q_off + qi * bq) - (k_off + ki * bk + bk - 1) < window)
-    return run
+        live = live & (q0 - k1 < window)
+        cut = cut | (q1 - k0 >= window)
+    return live, live & cut
+
+
+def band_tiles(tq, tk, bq, bk, window=None, q_offset=0, k_offset=0):
+    """``(visited, edge)``: the tiles of one head's causal band that the
+    kernels run, and those of them that take the mask (the rest are
+    interior and run unmasked), from the kernels' own predicate.
+    SmallThinker's global layer at 16,384 tokens in 1,024-blocks: (136,
+    16); its window layers (4,096 keys): (70, 28)."""
+    visited = edge = 0
+    for qi in range(tq // bq):
+        for ki in range(tk // bk):
+            live, cut = _tile_class(qi, ki, bq, bk, q_offset, k_offset,
+                                    window)
+            visited += live
+            edge += cut
+    return visited, edge
 
 
 def _window_span(window, bq, bk, q_offset, k_offset, nk):
@@ -196,38 +225,59 @@ def _offsets_and_predicates(qi, ki, bq, bk, *, causal, dyn_off, qoff_ref,
                             koff_ref, q_off0, k_off0, window, window_span):
     """Shared causal-control logic: global offsets (SMEM scalars on the
     ring path, Python constants otherwise — r4, the constants let the
-    plain path's comparisons fold) and the block-skip ``run`` predicate.
-    ``run is True`` statically for non-causal kernels."""
+    plain path's comparisons fold), the block-skip ``run`` predicate and
+    the ``edge`` predicate of the tiles that take the mask (``_tile_class``).
+    ``run is True`` and ``edge is False`` statically for non-causal
+    kernels."""
     if not causal:
-        return 0, 0, True
+        return 0, 0, True, False
     if dyn_off:
         q_off, k_off = qoff_ref[0, 0], koff_ref[0, 0]
     else:
         q_off, k_off = q_off0, k_off0
-    run = _block_live(qi, ki, bq, bk, q_off, k_off, window)
+    run, edge = _tile_class(qi, ki, bq, bk, q_off, k_off, window)
     if window_span is not None:
         run = jnp.logical_and(run, ki >= 0)
-    return q_off, k_off, run
+    return q_off, k_off, run, edge
 
 
-def _masked_split(run, body, mask_fn):
-    """Run ``body(mask_fn())`` under the ``run`` block-skip predicate;
-    ``run is True`` statically (non-causal) runs the unmasked body
-    directly.
+def _masked_split(run, edge, body, mask_fn):
+    """Run ``body(mask_fn())`` on the edge tiles and ``body(None)`` on the
+    interior ones, which run no mask arithmetic and no select, under the
+    ``run`` block-skip predicate; ``run is True`` statically (non-causal)
+    runs the unmasked body directly.
 
-    r4 lesson (measured on chip, seq 8k causal): splitting into an
-    unmasked interior branch + masked edge branch under complementary
-    ``pl.when``s REGRESSED 17% (17.2 -> 20.1 ms fwd+bwd) — duplicating
-    the matmul body across predicated regions defeats Mosaic's loop
-    pipelining, which outweighs the saved per-element mask work.  One
-    body, always masked on causal paths."""
+    Sweep of the forms (TPU v5e, bf16, causal, 1024 x 1024 blocks, random
+    normal inputs; per-call ms of the forward / ``flash_bwd``, min of 3
+    passes of back-to-back calls; each form patched into these kernels):
+                                        SmallThinker   SmallThinker
+                                        global         window
+      every live tile masked, p and s
+        selected (the kernels before)   19.12 / 31.55  10.31 / 16.59
+      every live tile masked, once      17.90 / 31.60   8.38 / 16.18
+      edge tiles only, two bodies       17.58 / 31.50   8.26 / 16.11
+        the same, the mask from a
+        [bq, 1] and a [1, bk] iota      17.57 / 31.46   8.25 / 16.10
+      edge tiles only, one body,
+        ``lax.cond`` on the scores      29.90 / 41.89  15.54 / 22.47
+        the same, [bq, 1]/[1, bk] mask  29.89 / 41.88  14.98 / 21.96
+      edge tiles only, one body, the
+        scores through VMEM, pl.when    24.13 / 36.01  11.87 / 18.83
+    (SmallThinker: B 1, H 28 over 4 KV heads, T 16,384, d 128, the window
+    4,096.)  GPT-2 small's layer (B 8, H 12, T 1,024, d 64), one diagonal
+    tile a head: 0.524 / 1.274 before, 0.525 / 1.274 with two bodies.
+    Two bodies cost Mosaic's pipelining nothing here; an older reading of
+    17% lost to them (seq 8k, an installation since replaced, before the
+    1024-blocks) does not hold.  ``lax.cond`` and the VMEM round trip
+    also need more scoped VMEM than ``flash_bwd`` has at 16,384 keys.
+    ``tools/attention_sweep.py --kernels`` times the kept form against
+    another checkout's kernels."""
     if run is True:
         body(None)
         return
-
-    @pl.when(run)
-    def _():
-        body(mask_fn())
+    pl.when(jnp.logical_and(run, edge))(lambda: body(mask_fn()))
+    pl.when(jnp.logical_and(run, jnp.logical_not(edge)))(
+        lambda: body(None))
 
 
 def _opt_refs(refs, has_bias, has_bias2, dyn_off):
@@ -268,7 +318,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale, causal, has_bias,
     # Causal: fully-masked KV blocks above the diagonal are skipped (on
     # global positions, so a ring shard entirely in the future runs no
     # block at all).
-    q_off, k_off, run = _offsets_and_predicates(
+    q_off, k_off, run, edge = _offsets_and_predicates(
         qi, ki, bq, bk, causal=causal, dyn_off=dyn_off, qoff_ref=qoff_ref,
         koff_ref=koff_ref, q_off0=q_off0, k_off0=k_off0, window=window,
         window_span=window_span)
@@ -282,13 +332,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale, causal, has_bias,
         if has_bias2:
             s = s + b2_ref[0].astype(jnp.float32)        # [bq, bk] block
         if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
+            # -inf, not NEG_INF: the running max starts at NEG_INF and so
+            # never takes a masked score, and exp(-inf - m) is 0 for the
+            # finite m every row has, a row that has seen no key
+            # included, so p needs no second select
+            s = jnp.where(mask, s, -jnp.inf)
         m_prev = m_scr[:]                                # [bq, 1]
         l_prev = l_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)                  # [bq, 1]
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         pv = _mm(p.astype(v_ref.dtype), v_ref[0, 0],
@@ -297,7 +349,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale, causal, has_bias,
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-    _masked_split(run, body,
+    _masked_split(run, edge, body,
                   lambda: _causal_block_mask(qi, ki, bq, bk, q_off, k_off,
                                              window))
 
@@ -423,7 +475,11 @@ def _flash_fwd_pallas(q, k, v, kbias, *, sm_scale, causal, block_q, block_k,
 def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kb_ref,
                     b2_ref, mask, *, sm_scale, has_bias, has_bias2):
     """Shared bwd recompute: returns (p, ds), both [bq, bk] fp32.
-    ``mask`` is None on interior blocks (the r4 mask-free fast path)."""
+    ``mask`` is None on interior tiles (``_masked_split``).  Nothing here
+    needs the masked scores, so an edge tile selects once, on ``p``: a
+    masked pair gets 0 whatever its ``exp`` was (a row that sees no key
+    has ``lse == NEG_INF``), as a select of the scores and another of
+    ``p`` gave."""
     q = q_ref[0, 0]
     k = k_ref[0, 0]
     s = _mm(q, k, ((1,), (1,))) * sm_scale       # [bq, bk]
@@ -431,13 +487,8 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kb_ref,
         s = s + kb_ref[0].astype(jnp.float32)
     if has_bias2:
         s = s + b2_ref[0].astype(jnp.float32)
-    if mask is not None:
-        s = jnp.where(mask, s, NEG_INF)
     p = jnp.exp(s - lse_ref[0, 0])                           # lse: [bq, 1]
     if mask is not None:
-        # A fully-masked row has lse == NEG_INF, making exp(NEG_INF -
-        # NEG_INF) = 1 on masked entries; the forward kernel zeroes these,
-        # so the recompute must too.
         p = jnp.where(mask, p, 0.0)
     dp = _mm(do_ref[0, 0], v_ref[0, 0], ((1,), (1,)))        # [bq, bk]
     ds = p * (dp - delta_ref[0, 0]) * sm_scale               # delta: [bq, 1]
@@ -499,7 +550,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    q_off, k_off, run = _offsets_and_predicates(
+    q_off, k_off, run, edge = _offsets_and_predicates(
         qi, ki, bq, bk, causal=causal, dyn_off=dyn_off, qoff_ref=qoff_ref,
         koff_ref=koff_ref, q_off0=q_off0, k_off0=k_off0, window=window,
         window_span=window_span)
@@ -526,7 +577,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             # the caller divides back out.
             db_scr[ki] = db_scr[ki] + jnp.sum(ds, axis=0, keepdims=True)
 
-    _masked_split(run, body,
+    _masked_split(run, edge, body,
                   lambda: _causal_block_mask(qi, ki, bq, bk, q_off, k_off,
                                              window))
 
@@ -565,7 +616,7 @@ def _bwd_db2_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         db2_scr[:] = jnp.zeros_like(db2_scr)
 
-    q_off, k_off, run = _offsets_and_predicates(
+    q_off, k_off, run, edge = _offsets_and_predicates(
         qi, ki, bq, bk, causal=causal, dyn_off=dyn_off, qoff_ref=qoff_ref,
         koff_ref=koff_ref, q_off0=q_off0, k_off0=k_off0, window=window,
         window_span=window_span)
@@ -577,7 +628,7 @@ def _bwd_db2_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                 has_bias2=True)
         db2_scr[:] = db2_scr[:] + ds
 
-    _masked_split(run, body,
+    _masked_split(run, edge, body,
                   lambda: _causal_block_mask(qi, ki, bq, bk, q_off, k_off,
                                              window))
 

@@ -7,6 +7,8 @@ Mirrors the fallback-vs-kernel strategy of the reference's L0 kernel
 tests (``tests/L0/run_fused_layer_norm``).
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -547,6 +549,28 @@ def test_attention_sweep_tool_quick(tmp_path, monkeypatch, capsys):
         "n_rows"] == 1
 
 
+def test_attention_sweep_tool_kernels_quick(tmp_path, monkeypatch):
+    """``--kernels --quick``: the forward and ``flash_bwd`` alone at one
+    tiny windowed GQA shape in interpret mode, timed, with the digests of
+    the five outputs that two checkouts' rows are compared by."""
+    import json
+    import os
+    import runpy
+    import sys
+
+    tool = os.path.join(os.path.dirname(__file__), os.pardir, "tools",
+                        "attention_sweep.py")
+    out = tmp_path / "kernels.json"
+    monkeypatch.setattr(sys, "argv", [tool, "--kernels", "--quick",
+                                      "--iters", "1", "--out", str(out)])
+    runpy.run_path(tool, run_name="__main__")
+    (row,) = json.loads(out.read_text())["kernels"]
+    assert row["shape"] == "tiny"
+    assert row["fwd_ms"] > 0 and row["bwd_ms"] > 0
+    assert all(len(row[k + "_sha256"]) == 64
+               for k in ("out", "lse", "dq", "dk", "dv"))
+
+
 # -- the fused backward kernel (ISSUE 30) --------------------------------------
 # dq, dk and dv come from one `flash_bwd` pallas_call: dk/dv accumulate in
 # whole-key-length scratch at row offsets ki * bk, so these cases force
@@ -798,3 +822,232 @@ def test_flash_window_gqa_seven_to_one_matches_band_oracle():
         assert a.shape == b.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-4, rtol=5e-4)
+
+
+# -- bit-for-bit against the kernels that masked every live tile ---------------
+# Interior tiles (every pair visible) run a body with no mask, and an edge
+# tile selects once where the kernels that masked every live tile selected
+# twice.  The results have to be the very bits those kernels computed: these
+# are sha256 digests of out, lse, dq, dk, dv (and the bias gradients) as they
+# computed them in interpret mode.  XLA:CPU contracts a multiply and a
+# subtract into one fused multiply-add where the two meet in a fusion, as
+# `s * sm_scale - m` does once no select stands between them, and the TPU's
+# vector unit does not: the digests come from a child process whose XLA:CPU
+# is held to AVX, which has no FMA (x86 only).  Like the lowered text
+# `test_moe.py` pins, a new jax may move them: regenerate them from the
+# kernels before edge masking, with the same child, as
+#   git archive 7ff7648 apex_tpu | tar -x -C DIR
+#   JAX_PLATFORMS=cpu XLA_FLAGS=--xla_cpu_max_isa=AVX PYTHONPATH=DIR \
+#       python tests/test_flash_attention.py
+# and if they move, compare the kernels' own bits before and after.
+
+#: case -> (tq, tk, heads, kv heads, bq, bk, window, q_offset, k_offset,
+#: key-padding bias of the first three keys or None, [B, T, S] bias,
+#: traced offsets)
+_BITWISE_CASES = {
+    # 4 x 4 tiles: 6 interior, 4 diagonal
+    "causal": (512, 512, 2, 2, 128, 128, None, 0, 0, None, False, False),
+    # span 3 of 4 blocks; rows 71 to 127 of a band's first tile see no key
+    "window_gqa7": (512, 512, 14, 2, 128, 128, 200, 0, 0, None, False,
+                    False),
+    "bq_lt_bk": (512, 512, 2, 1, 128, 256, None, 0, 0, None, False, False),
+    # the window on the full masked grid (bq != bk)
+    "bq_gt_bk_window": (512, 512, 2, 2, 256, 128, 200, 0, 0, None, False,
+                        False),
+    "key_bias": (512, 512, 4, 2, 128, 128, None, 0, 0, -np.inf, False,
+                 False),
+    # the module's own NEG_INF as a bias: rows 0..2 hold their max at it
+    # over keys they see, and those keys keep their weight
+    "neg_inf_bias": (512, 512, 2, 2, 128, 128, None, 0, 0, -1e30, False,
+                     False),
+    "qk_bias": (256, 256, 2, 2, 128, 128, None, 0, 0, None, True, False),
+    # queries 128..639 against keys 192..703: rows 0..63 see no key at all
+    "static_offsets": (512, 512, 2, 2, 128, 128, None, 128, 192, None,
+                       False, False),
+    # the ring's path: the offsets are traced and ride SMEM; rows 0..31
+    # see no key
+    "ring_offsets": (512, 512, 2, 2, 128, 128, None, 384, 416, None, False,
+                     True),
+}
+
+#: the digests of the kernels that masked every live tile twice, XLA:CPU
+#: held to AVX
+_BITWISE_DIGESTS = {
+    "causal":
+        "3c7de9bf4608bdb9f02b8dc780d43799992116e2fdaa6626fd2d5d64ae5bbe8e",
+    "window_gqa7":
+        "56f9489be36a3606a3cfbec41bc9b6b36ddb9e8d72de0f73b1cdaaf75b86d2ed",
+    "bq_lt_bk":
+        "f8d83140263f8fae264ec5b61e129e0ad4d74cf0818f2031922e07d94517561b",
+    "bq_gt_bk_window":
+        "6eb40a81d00f88db53c86aeddf626ec9c248d30ff8bb15446702951f24df7ab3",
+    "key_bias":
+        "0febd44ebd4913cd20fe99c881d718b8ea1f9128f2d43e22f1a76845b3e76e56",
+    "neg_inf_bias":
+        "677bdedc78764998524737239edf875a83678578c140feecee8ff0651206773c",
+    "qk_bias":
+        "074036396bb79cc306065c976095dce067de426b388a8bd567ec6ea83af037c5",
+    "static_offsets":
+        "5a28ab7ac8b9038cf0272038c943e71f82d118cc216633a67ea6ba98180c4a7d",
+    "ring_offsets":
+        "3254ec4be42597743bec92ec1ef001573865b03cc3c25fe17a811a7bb0d24b87",
+}
+
+
+def _bitwise_outputs(case):
+    """out, lse, dq, dk, dv (and dbias, dbias2 where there is a bias) of
+    the head-major kernels, bf16 operands as the models run them."""
+    fa = _fa_module()
+    (tq, tk, h, h_kv, bq, bk, window, q_off, k_off, first_kb, with_qk,
+     traced) = _BITWISE_CASES[case]
+    rng = np.random.RandomState(7)
+    bf = lambda *s: jnp.asarray(rng.randn(*s), jnp.bfloat16)
+    q, do = bf(1, h, tq, 32), bf(1, h, tq, 32)
+    k, v = bf(1, h_kv, tk, 32), bf(1, h_kv, tk, 32)
+    kb = None
+    if first_kb is not None:    # padded keys at -1e9 beside a small random
+        # bias, and the first three at first_kb: rows 0..2 see only them
+        kb = rng.randn(1, tk) * 0.3 - 1e9 * (rng.rand(1, tk) < 0.1)
+        kb[:, :3] = first_kb
+        kb = jnp.asarray(kb, jnp.float32)
+    qkb = jnp.asarray(rng.randn(1, tq, tk) * 0.3, jnp.float32) if with_qk \
+        else None
+    kw = dict(sm_scale=32 ** -0.5, causal=True, block_q=bq, block_k=bk,
+              window=window, interpret=True)
+
+    def run(q, k, v, do, kb, qkb, q_off, k_off):
+        out, lse = fa._flash_fwd_pallas(q, k, v, kb, qk_bias=qkb,
+                                        q_offset=q_off, k_offset=k_off, **kw)
+        grads = fa._flash_bwd_pallas(q, k, v, kb, out, lse, do, qk_bias=qkb,
+                                     q_offset=q_off, k_offset=k_off, **kw)
+        return (out, lse) + tuple(g for g in grads if g is not None)
+
+    if traced:
+        return jax.jit(run)(q, k, v, do, kb, qkb, jnp.int32(q_off),
+                            jnp.int32(k_off))
+    return jax.jit(functools.partial(run, q_off=q_off, k_off=k_off))(
+        q, k, v, do, kb, qkb)
+
+
+def _digest(arrays):
+    import hashlib
+    h = hashlib.sha256()
+    for a in arrays:
+        a = np.asarray(a)
+        assert np.isfinite(a.astype(np.float32)).all()
+        h.update(f"{a.dtype}{a.shape}".encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+def _digests():
+    """Every case's digest, from the kernels of the ``apex_tpu`` this
+    process imports."""
+    return {c: _digest(_bitwise_outputs(c)) for c in _BITWISE_CASES}
+
+
+@pytest.fixture(scope="module")
+def avx_digests():
+    """Every case's digest from this file run as a child process on the
+    CPU, its XLA held to AVX (no FMA contraction)."""
+    import json
+    import os
+    import platform
+    import subprocess
+    import sys
+    if platform.machine().lower() not in ("x86_64", "amd64"):
+        pytest.skip("--xla_cpu_max_isa=AVX, which keeps XLA:CPU from "
+                    "contracting into FMAs, exists on x86 only")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
+                          + " --xla_cpu_max_isa=AVX").strip(),
+               PYTHONPATH=os.pathsep.join(
+                   [root, os.environ.get("PYTHONPATH", "")]))
+    done = subprocess.run([sys.executable, os.path.abspath(__file__)],
+                          env=env, cwd=root, capture_output=True, text=True,
+                          timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    return json.loads(done.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("case", list(_BITWISE_CASES))
+def test_flash_edge_masking_is_bit_for_bit(case, avx_digests):
+    """Forward outputs and every gradient equal, bit for bit, what the
+    kernels that masked every live tile twice computed, on each masking
+    path: causal interior and diagonal tiles, a window band whose first
+    tile holds rows that see no key (GQA 7:1), unequal blocks both ways,
+    both biases, NEG_INF itself as the bias of the only keys a row sees,
+    static offsets with rows that see nothing, traced offsets."""
+    assert avx_digests[case] == _BITWISE_DIGESTS[case]
+
+
+@pytest.mark.parametrize("shape,want", [
+    ((16384, 1024, None), (136, 16)),     # SmallThinker's global layer
+    ((16384, 1024, 4096), (70, 28)),      # its window layers
+    ((1024, 1024, None), (1, 1)),         # gpt2_small_o2.seq1024
+    ((4096, 1024, None), (10, 4)),        # granite's and LFM2's attention
+    ((8192, 1024, None), (36, 8)),        # Nemotron's
+])
+def test_band_tiles_at_the_cells_shapes(shape, want):
+    """Tiles a head visits, and those of them that take the mask, at the
+    flash cells' shapes: the share the interior tiles skip."""
+    t, blk, window = shape
+    assert _fa_module().band_tiles(t, t, blk, blk, window) == want
+
+
+@pytest.mark.parametrize("tq,tk,bq,bk,window,q_off,k_off", [
+    (64, 64, 16, 16, None, 0, 0),
+    (64, 64, 16, 8, 20, 0, 0),            # the window's far edge
+    (48, 64, 8, 16, 13, 16, 0),           # queries as the keys' suffix
+    (64, 64, 16, 16, None, 8, 40),        # rows before the first key
+    (64, 64, 16, 16, 7, 40, 0),           # a window under one block
+])
+def test_tile_class_agrees_with_the_mask(tq, tk, bq, bk, window, q_off,
+                                         k_off):
+    """Brute force over a small grid: a live tile's mask has a true pair
+    and a dead one none, and an interior tile's mask is all true (so the
+    body that skips it computes what the masked one did); ``band_tiles``
+    counts the same tiles, and the predicate gives the same answer on
+    traced ids."""
+    fa = _fa_module()
+    visited = edge = 0
+    for qi in range(tq // bq):
+        for ki in range(tk // bk):
+            m = np.asarray(fa._causal_block_mask(qi, ki, bq, bk, q_off,
+                                                 k_off, window))
+            live, cut = fa._tile_class(qi, ki, bq, bk, q_off, k_off, window)
+            assert (live, cut) == (bool(m.any()), bool(m.any() and
+                                                       not m.all()))
+            traced = jax.jit(lambda qi, ki: fa._tile_class(
+                qi, ki, bq, bk, q_off, k_off, window))(qi, ki)
+            assert tuple(map(bool, traced)) == (live, cut)
+            visited += live
+            edge += cut
+    assert fa.band_tiles(tq, tk, bq, bk, window, q_off, k_off) == (
+        visited, edge)
+
+
+@pytest.mark.parametrize("window", [None, 5, 11])
+def test_tile_class_over_every_offset(window):
+    """Every pair of static offsets from 0 to 23 over 32 x 32 positions in
+    8 x 8 and 8 x 16 tiles, against the visible pairs counted directly:
+    the off-by-one edges of the diagonal and of the window included."""
+    fa = _fa_module()
+    pos = np.arange(32)
+    for bk in (8, 16):
+        for q_off in range(24):
+            for k_off in range(24):
+                d = (q_off + pos)[:, None] - (k_off + pos)[None, :]
+                vis = (d >= 0) & ((d < window) if window else True)
+                for qi in range(32 // 8):
+                    for ki in range(32 // bk):
+                        m = vis[qi * 8:(qi + 1) * 8, ki * bk:(ki + 1) * bk]
+                        assert fa._tile_class(qi, ki, 8, bk, q_off, k_off,
+                                              window) == (
+                            m.any(), m.any() and not m.all())
+
+
+if __name__ == "__main__":
+    import json
+    print(json.dumps(_digests()))

@@ -17,6 +17,15 @@ Timing policy: min-of-3 passes of ``iters`` fwd+bwd calls, execution
 forced by a scalar fetch of the last output (dispatch is asynchronous).
 No sweep of the current installation is on file: the rows the dispatch
 threshold rests on are quoted in ``docs/attention.md``.
+
+``--kernels`` times the causal flash forward and ``flash_bwd`` alone, per
+call, at the flash cells' layer shapes (``KERNEL_SHAPES``), with the
+sha256 of out, lse, dq, dk, dv and the compiled kernels' code bytes.  Run
+it against two checkouts in one call to compare their kernels on one
+chip, bit for bit as well as in time::
+
+    PYTHONPATH=OTHER_CHECKOUT python tools/attention_sweep.py --kernels
+    python tools/attention_sweep.py --kernels
 """
 
 from __future__ import annotations
@@ -115,13 +124,92 @@ def sweep(configs, iters=10):
     return rows
 
 
+#: name -> (batch, heads, kv heads, seq, head_dim, window): the layers of
+#: the flash cells, timed one kernel at a time by ``--kernels``
+KERNEL_SHAPES = {
+    "smallthinker_global": (1, 28, 4, 16384, 128, None),
+    "smallthinker_window": (1, 28, 4, 16384, 128, 4096),
+    "gpt2_small_seq1024": (8, 12, 12, 1024, 64, None),
+}
+
+
+def kernel_rows(shapes, iters=10, block=1024, interpret=False):
+    """Per-call ms of the causal forward kernel and of ``flash_bwd`` on
+    head-major bf16 operands (min of 3 passes), the sha256 of each
+    output, and the compiled kernels' code bytes (None where the backend
+    does not say)."""
+    import hashlib
+    import importlib
+    fa = importlib.import_module("apex_tpu.ops.flash_attention")
+
+    def best_ms(f, args, n):
+        _force(f(*args)[0])
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                out = f(*args)
+            _force(out[0])
+            best = min(best, (time.perf_counter() - t0) / n)
+        return best * 1e3
+
+    def code_bytes(f, args):
+        ma = f.lower(*args).compile().memory_analysis()
+        return getattr(ma, "generated_code_size_in_bytes", None)
+
+    def kernels(**kw):
+        fwd = jax.jit(lambda q, k, v: fa._flash_fwd_pallas(q, k, v, None,
+                                                           **kw))
+        bwd = jax.jit(lambda q, k, v, o, lse, do: fa._flash_bwd_pallas(
+            q, k, v, None, o, lse, do, **kw)[:3])
+        return fwd, bwd
+
+    rows = []
+    for name, (b, h, h_kv, t, d, window) in shapes.items():
+        fwd, bwd = kernels(sm_scale=d ** -0.5, causal=True, block_q=block,
+                           block_k=block, window=window, interpret=interpret)
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
+        q, do = (jax.random.normal(keys[i], (b, h, t, d), jnp.bfloat16)
+                 for i in (0, 3))
+        k, v = (jax.random.normal(keys[i], (b, h_kv, t, d), jnp.bfloat16)
+                for i in (1, 2))
+        o, lse = fwd(q, k, v)
+        grads = bwd(q, k, v, o, lse, do)
+        n = iters if t > 4096 else 20 * iters
+        row = {"shape": name, "fwd_ms": best_ms(fwd, (q, k, v), n),
+               "bwd_ms": best_ms(bwd, (q, k, v, o, lse, do), n),
+               "fwd_code_bytes": code_bytes(fwd, (q, k, v)),
+               "bwd_code_bytes": code_bytes(bwd, (q, k, v, o, lse, do))}
+        for key, x in zip(("out", "lse", "dq", "dk", "dv"), (o, lse) + grads):
+            # jaxlint: disable=J001 -- each output is read once a shape, after its timing, to be digested
+            host = np.asarray(x)
+            row[key + "_sha256"] = hashlib.sha256(host.tobytes()).hexdigest()
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    return rows
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None)
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--quick", action="store_true",
-                    help="CPU smoke: tiny shapes, interpret-free jnp only")
+                    help="CPU smoke: tiny shapes, interpret-free jnp only "
+                    "(with --kernels: one tiny shape in interpret mode)")
+    ap.add_argument("--kernels", action="store_true",
+                    help="time the flash kernels alone at KERNEL_SHAPES")
     args = ap.parse_args()
+
+    if args.kernels:
+        shapes, block = KERNEL_SHAPES, 1024
+        if args.quick:
+            shapes, block = {"tiny": (1, 4, 2, 256, 32, 160)}, 128
+        rows = kernel_rows(shapes, args.iters, block, interpret=args.quick)
+        if args.out:
+            with open(args.out, "w") as f:
+                json.dump({"device_kind": jax.devices()[0].device_kind,
+                           "kernels": rows}, f, indent=1)
+        return
 
     if args.quick:
         configs = [dict(batch=2, seq=128, heads=2, head_dim=64,
